@@ -47,15 +47,9 @@ serial reference (the host-offload cell gets a wider threshold for its
 documented CPU io_callback overhead).
 """
 
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-
 import argparse
 import json
+import os
 import sys
 import time
 
@@ -516,7 +510,14 @@ def check_ledger(out: dict, smoke: bool) -> None:
         sys.exit(1)
 
 
-if __name__ == "__main__":
+def main() -> None:
+    # Virtual CPU devices for the host mesh; set before JAX first touches
+    # a backend, and only when run as a script, so importing this module
+    # leaves the device set alone.
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=8 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run: fewer timing steps, same coverage")
@@ -534,3 +535,7 @@ if __name__ == "__main__":
     print(json.dumps(out, indent=1))
     if args.check:
         check_ledger(out, args.smoke)
+
+
+if __name__ == "__main__":
+    main()

@@ -69,15 +69,9 @@ the saturation cell (``rate=inf`` — every request offered at tick 0).
 Output JSON is saved as BENCH_serve.json (BENCH_serve_smoke.json in CI).
 """
 
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-
 import argparse
 import json
+import os
 import sys
 import time
 
@@ -648,7 +642,14 @@ def check(out: dict, smoke: bool) -> None:
         sys.exit(1)
 
 
-if __name__ == "__main__":
+def main() -> None:
+    # Virtual CPU devices for the host mesh; set before JAX first touches
+    # a backend, and only when run as a script, so importing this module
+    # leaves the device set alone.
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=8 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run: fewer requests and rates")
@@ -659,3 +660,7 @@ if __name__ == "__main__":
     print(json.dumps(result, indent=1))
     if args.check:
         check(result, args.smoke)
+
+
+if __name__ == "__main__":
+    main()

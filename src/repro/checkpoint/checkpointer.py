@@ -194,6 +194,26 @@ class Checkpointer:
         callers (and tests) see exactly which half of the optimizer state
         survived the world change.
         """
+        state_host, meta = self.load_host(model, step,
+                                          offload_opt=offload_opt)
+        if offload_opt:
+            path = self.dir / f"step_{meta['step']:08d}"
+            meta["host_stash"] = self._restore_stash(path, meta, topo)
+
+        shardings = state_shardings(model, topo, offload_opt=offload_opt)
+        with topo.mesh:
+            state = jax.tree.map(
+                lambda a, s: jax.device_put(jnp.asarray(a), s),
+                state_host, shardings,
+                is_leaf=lambda x: isinstance(x, np.ndarray),
+            )
+        return state, meta
+
+    def load_host(self, model: ModelDef, step: int | None = None, *,
+                  offload_opt: bool = False):
+        """(state, meta) of a checkpoint as global host numpy arrays, in the
+        pytree structure of ``init_state_shapes`` — no device is touched.
+        ``step=None`` loads the newest complete checkpoint."""
         if step is None:
             step = self.latest_step()
         if step is None:
@@ -206,9 +226,6 @@ class Checkpointer:
         meta = json.loads((path / MANIFEST).read_text())
         data = np.load(path / STATE_BLOB)
         leaves = [data[f"leaf_{i:04d}"] for i in range(len(meta["leaves"]))]
-
-        if offload_opt:
-            meta["host_stash"] = self._restore_stash(path, meta, topo)
 
         # rebuild the pytree structure from a template
         from repro.core.mics import init_state_shapes
@@ -224,16 +241,7 @@ class Checkpointer:
                     f"leaf shape mismatch {got.shape} vs {want.shape}: elastic "
                     f"restore reshards pods/partition/replication freely but "
                     f"the TP degree is fixed (flat layouts are TP-local)")
-        state_host = jax.tree_util.tree_unflatten(treedef, leaves)
-
-        shardings = state_shardings(model, topo, offload_opt=offload_opt)
-        with topo.mesh:
-            state = jax.tree.map(
-                lambda a, s: jax.device_put(jnp.asarray(a), s),
-                state_host, shardings,
-                is_leaf=lambda x: isinstance(x, np.ndarray),
-            )
-        return state, meta
+        return jax.tree_util.tree_unflatten(treedef, leaves), meta
 
     def _restore_stash(self, path: pathlib.Path, meta: dict,
                        topo: MiCSTopology) -> dict:

@@ -293,7 +293,12 @@ class CommEngine:
     def _policy_reduce_scatter(self, g: jax.Array) -> jax.Array:
         gp = self.gather_policy
         if self.topo.partition_size == 1:
-            return g
+            # Nothing to scatter, but keep the flat cotangent a buffer of its
+            # own: without the barrier XLA folds unflatten's transpose and
+            # the fp32 accumulate into one [rows, cols] -> [1, 1, n]
+            # relayout, which the TPU backend emits unrolled per row (minutes
+            # of compile for a vocab-sized table).
+            return jax.lax.optimization_barrier(g)
         if gp.topology == "flat":
             return C.hop1_reduce_scatter(g, self.topo)
         return C.hierarchical_reduce_scatter(

@@ -118,20 +118,26 @@ class FlatLayout:
 
     # -- init ----------------------------------------------------------------
     def init_flat(self, key: jax.Array, dtype=jnp.float32) -> jax.Array:
-        """Full flat vector init (used under jit with sharded out_shardings)."""
+        """Full flat vector init (used under jit with sharded out_shardings).
+
+        Each segment is drawn already flat: JAX's random values do not
+        depend on the shape they are drawn in, and a [rows, cols] -> flat
+        reshape here costs the TPU compiler minutes for a vocab-sized
+        table (it emits the relayout unrolled per row)."""
         tensors = {}
         for s in self.segments:
             key, sub = jax.random.split(key)
+            shape = (s.size,)
             if s.init == "normal":
-                t = jax.random.normal(sub, s.shape, dtype) * jnp.asarray(s.std, dtype)
+                t = jax.random.normal(sub, shape, dtype) * jnp.asarray(s.std, dtype)
             elif s.init == "zeros":
-                t = jnp.zeros(s.shape, dtype)
+                t = jnp.zeros(shape, dtype)
             elif s.init == "ones":
-                t = jnp.ones(s.shape, dtype)
+                t = jnp.ones(shape, dtype)
             elif s.init == "lru":
                 # RG-LRU Λ such that the per-channel decay a = sigmoid(Λ) is
                 # uniform in [0.9, 0.999] (Griffin appendix initialization).
-                u = jax.random.uniform(sub, s.shape, dtype, 0.9, 0.999)
+                u = jax.random.uniform(sub, shape, dtype, 0.9, 0.999)
                 t = jnp.log(u) - jnp.log1p(-u)
             else:
                 raise ValueError(f"unknown init {s.init!r}")

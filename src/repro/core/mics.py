@@ -35,10 +35,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.autotune import resolve_config
 from repro.core.comm import CommEngine
 from repro.core.schedule import (
@@ -250,9 +249,12 @@ def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0, *,
             stack, tp, _ = shapes[pool.name]
             pool_key = jax.random.fold_in(
                 key, zlib.crc32(pool.name.encode()) % (2**31))
-            keys = jax.random.split(pool_key, stack * tp).reshape(stack, tp)
-            rows = jax.vmap(jax.vmap(pool.layout.init_flat))(keys)
-            flat[pool.name] = rows
+            # One flat row per (layer, tp rank), each kept a 1-D buffer of
+            # its own: drawn straight into the [stack, tp, n] layout, the
+            # random fusion takes the TPU compiler half a minute per pool.
+            rows = [lax.optimization_barrier(pool.layout.init_flat(k))
+                    for k in jax.random.split(pool_key, stack * tp)]
+            flat[pool.name] = jnp.stack(rows).reshape(stack, tp, -1)
         out = {"params": flat, "step": jnp.int32(0)}
         if not offload_opt:
             # Offloaded moments zero-init lazily in the host stash instead

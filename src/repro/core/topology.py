@@ -22,9 +22,8 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import AxisType, make_mesh, mesh_from_devices
 from repro.core.linkmodel import V5E
 
 # Mesh axis names, fixed across the framework.
@@ -60,7 +59,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """The assignment's production mesh: 16x16 per pod, 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (POD_AXIS, "data", MODEL_AXIS) if multi_pod else ("data", MODEL_AXIS)
-    return make_mesh(shape, axes, axis_types=_auto(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mics_mesh(base: Mesh, partition_size: int, tp: int | None = None) -> Mesh:
@@ -91,7 +90,7 @@ def make_mics_mesh(base: Mesh, partition_size: int, tp: int | None = None) -> Me
         raise ValueError(f"tp {tp} does not divide model axis {model}")
     repl = data // partition_size
     devs = devices.reshape(pods, repl, partition_size, model // tp, tp)
-    return mesh_from_devices(devs, MICS_AXES, axis_types=_auto(5))
+    return Mesh(devs, MICS_AXES, axis_types=_auto(5))
 
 
 def make_host_mesh(
@@ -100,7 +99,7 @@ def make_host_mesh(
     """Small mesh over however many (virtual) devices exist — for tests."""
     n = pods * repl * shard * dp2 * model
     devs = np.array(jax.devices()[:n]).reshape(pods, repl, shard, dp2, model)
-    return mesh_from_devices(devs, MICS_AXES, axis_types=_auto(5))
+    return Mesh(devs, MICS_AXES, axis_types=_auto(5))
 
 
 def elastic_host_topology(n_devices: int, partition_size: int,

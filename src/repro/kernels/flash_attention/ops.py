@@ -21,7 +21,7 @@ def flash_attention_gqa(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, tq, hkv, g, dh = q.shape
     tk = k.shape[1]

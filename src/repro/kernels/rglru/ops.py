@@ -11,7 +11,7 @@ from repro.kernels.rglru.kernel import rglru
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_c", "interpret"))
 def rglru_scan(a, b, *, block_t: int = 256, block_c: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     bt, bc = block_t, block_c
     while a.shape[1] % bt:
         bt //= 2

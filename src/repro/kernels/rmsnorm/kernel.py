@@ -13,8 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -31,7 +31,7 @@ def rmsnorm(
     *,
     eps: float = 1e-6,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     n, d = x.shape
     block_rows = min(block_rows, n)
@@ -47,7 +47,7 @@ def rmsnorm(
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

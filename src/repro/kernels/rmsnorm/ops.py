@@ -11,7 +11,7 @@ from repro.kernels.rmsnorm.kernel import rmsnorm
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm_nd(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
-               interpret: bool = True):
+               interpret: bool = False):
     lead = x.shape[:-1]
     n = 1
     for s in lead:

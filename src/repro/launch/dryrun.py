@@ -34,13 +34,10 @@ Usage:
   python -m repro.launch.dryrun --all [--mesh both]
 """
 
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import sys
 import time
@@ -271,9 +268,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
         record["memplan"]["compiled_total_bytes"] = meas
         record["memplan"]["plan_vs_compiled_ratio"] = (
             mem_plan.total_bytes / meas if meas else None)
-    from repro.compat import cost_analysis
-
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     # NB: XLA's cost analysis visits while bodies ONCE (no trip weighting);
     # kept raw for reference.  The roofline uses the trip-weighted stats.
     record["cost_analysis_raw"] = {
@@ -317,6 +312,10 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
 
 def main():
     global TRAIN_MICRO_STEPS
+    # The production meshes need 512 virtual CPU devices; set before JAX
+    # first touches a backend, and only from the entry point, so importing
+    # this module leaves the device set alone.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape", choices=list(SHAPES))

@@ -7,7 +7,7 @@ CommEngine (core/comm.py): the flags below select its GatherPolicy
 (core/autotune.py) over ``--link-profile``.
 
 Examples:
-  # runnable on this host (reduced config, 1 device):
+  # runnable on this host (reduced config, every device found):
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --smoke \
       --steps 50
 
@@ -23,20 +23,88 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import logging
 
+import jax
+
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, smoke_variant
+from repro.configs.base import ArchConfig
 from repro.core import memplan
 from repro.core.autotune import cost_hop2_schedule, resolve_config
 from repro.core.comm import CommEngine, policies_from_config
 from repro.core.linkmodel import get_profile
 from repro.core.mics import MiCSConfig
 from repro.core.schedule import plan_boundary
-from repro.core.topology import MiCSTopology, make_host_mesh
+from repro.core.topology import MiCSTopology, elastic_host_topology
 from repro.data.pipeline import DataConfig
 from repro.models.build import build_model
+from repro.models.lm import ModelDef
 from repro.optim.adamw import OptConfig
-from repro.runtime.train_loop import LoopConfig, train
+from repro.runtime.train_loop import LoopConfig, LoopStats, train
+
+
+@dataclasses.dataclass(frozen=True)
+class TrainRun:
+    """Everything ``runtime.train_loop.train`` takes, built in one place."""
+
+    model: ModelDef
+    topo: MiCSTopology
+    mcfg: MiCSConfig
+    oc: OptConfig
+    dc: DataConfig
+    lc: LoopConfig
+
+    def train(self) -> LoopStats:
+        return train(self.model, self.topo, self.mcfg, self.oc, self.dc,
+                     self.lc)
+
+
+def build_training(cfg: ArchConfig, topo: MiCSTopology, mcfg: MiCSConfig, *,
+                   steps: int, global_batch: int, seq: int, lr: float,
+                   checkpoint_dir: str, checkpoint_every: int,
+                   max_step_retries: int = LoopConfig.max_step_retries
+                   ) -> TrainRun:
+    """The one construction path of a training run (this launcher and
+    ``chip_smoke.py``): model, resolved comm policy, optimizer, data and
+    loop configs.  Prints the autotune table (``policy='auto'``), the
+    boundary plan and the memory plan."""
+    model = build_model(cfg, tp=topo.model_size)
+    mcfg, plan = resolve_config(mcfg, model, topo, mode="train")
+    if plan is not None:
+        print(plan.table())
+    bplan = plan_boundary(model, topo, mode=mcfg.boundary_schedule,
+                          bucket_mb=mcfg.hop2_bucket_mb,
+                          clip_mode=mcfg.clip_mode)
+    profile = get_profile(mcfg.link_profile)  # name or instance
+    hop2 = cost_hop2_schedule(
+        model, topo, profile,
+        CommEngine.from_config(topo, mcfg).sync_policy,
+        boundary=mcfg.boundary_schedule, bucket_mb=mcfg.hop2_bucket_mb,
+        clip_mode=mcfg.clip_mode)
+    print(f"boundary: {mcfg.boundary_schedule} x {bplan.n_buckets} buckets "
+          f"({mcfg.hop2_bucket_mb:g} MB, clip={bplan.clip_mode}) — "
+          f"modeled hop-2 {hop2['t_exposed_s']*1e6:.0f}us exposed / "
+          f"{hop2['t_total_s']*1e6:.0f}us total on {profile.name}")
+    gp, sp = policies_from_config(mcfg)
+    lb = max((global_batch // mcfg.micro_steps) // topo.data_parallel_size, 0)
+    mem = memplan.predict_footprint(
+        model, topo, gp, sp, micro_steps=mcfg.micro_steps, mode="train",
+        local_batch=lb, seq=seq, boundary=mcfg.boundary_schedule,
+        hop2_bucket_mb=mcfg.hop2_bucket_mb, offload_opt=mcfg.offload_opt)
+    print(f"memplan: {mem.total_gb:.3f} GiB predicted per device "
+          f"(prefetch_carry={mcfg.prefetch_carry}, "
+          f"carry_offload={mcfg.carry_offload}, "
+          f"offload_opt={mcfg.offload_opt})")
+    oc = OptConfig(lr_max=lr, total_steps=steps,
+                   warmup_steps=max(steps // 20, 1))
+    dc = DataConfig(vocab=cfg.vocab, seq=seq, global_batch=global_batch,
+                    micro_steps=mcfg.micro_steps)
+    lc = LoopConfig(total_steps=steps, checkpoint_every=checkpoint_every,
+                    checkpoint_dir=checkpoint_dir,
+                    max_step_retries=max_step_retries)
+    return TrainRun(model, topo, mcfg, oc, dc, lc)
 
 
 def main():
@@ -121,12 +189,10 @@ def main():
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)
-
-    topo = MiCSTopology(make_host_mesh(1, 1, 1, 1))
-    model = build_model(cfg, tp=topo.model_size)
     mcfg = MiCSConfig(micro_steps=args.micro_steps,
                       hierarchical=not args.no_hierarchical,
                       gather_order=args.gather_order,
@@ -142,42 +208,13 @@ def main():
                       boundary_schedule=args.boundary_schedule,
                       hop2_bucket_mb=args.hop2_bucket_mb,
                       hbm_budget_gb=args.hbm_budget_gb or None)
-    mcfg, plan = resolve_config(mcfg, model, topo, mode="train")
-    if plan is not None:
-        print(plan.table())
-    bplan = plan_boundary(model, topo, mode=mcfg.boundary_schedule,
-                          bucket_mb=mcfg.hop2_bucket_mb,
-                          clip_mode=mcfg.clip_mode)
-    profile = get_profile(mcfg.link_profile)  # name or instance
-    hop2 = cost_hop2_schedule(
-        model, topo, profile,
-        CommEngine.from_config(topo, mcfg).sync_policy,
-        boundary=mcfg.boundary_schedule, bucket_mb=mcfg.hop2_bucket_mb,
-        clip_mode=mcfg.clip_mode)
-    print(f"boundary: {mcfg.boundary_schedule} x {bplan.n_buckets} buckets "
-          f"({mcfg.hop2_bucket_mb:g} MB, clip={bplan.clip_mode}) — "
-          f"modeled hop-2 {hop2['t_exposed_s']*1e6:.0f}us exposed / "
-          f"{hop2['t_total_s']*1e6:.0f}us total on {profile.name}")
-    gp, sp = policies_from_config(mcfg)
-    lb = max((args.global_batch // args.micro_steps)
-             // topo.data_parallel_size, 0)
-    mem = memplan.predict_footprint(
-        model, topo, gp, sp, micro_steps=args.micro_steps, mode="train",
-        local_batch=lb, seq=args.seq, boundary=mcfg.boundary_schedule,
-        hop2_bucket_mb=mcfg.hop2_bucket_mb, offload_opt=mcfg.offload_opt)
-    print(f"memplan: {mem.total_gb:.3f} GiB predicted per device "
-          f"(prefetch_carry={mcfg.prefetch_carry}, "
-          f"carry_offload={mcfg.carry_offload}, "
-          f"offload_opt={mcfg.offload_opt})")
-    oc = OptConfig(lr_max=args.lr, total_steps=args.steps,
-                   warmup_steps=max(args.steps // 20, 1))
-    dc = DataConfig(vocab=cfg.vocab, seq=args.seq,
-                    global_batch=args.global_batch,
-                    micro_steps=args.micro_steps)
-    lc = LoopConfig(total_steps=args.steps,
-                    checkpoint_every=args.checkpoint_every,
-                    checkpoint_dir=args.checkpoint_dir)
-    stats = train(model, topo, mcfg, oc, dc, lc)
+    n = len(jax.devices())   # one partition group over every device found
+    run = build_training(
+        cfg, elastic_host_topology(n, n), mcfg, steps=args.steps,
+        global_batch=args.global_batch, seq=args.seq, lr=args.lr,
+        checkpoint_dir=args.checkpoint_dir,
+        checkpoint_every=args.checkpoint_every)
+    stats = run.train()
     print(f"final loss {stats.losses[-1]:.4f} over {len(stats.losses)} steps; "
           f"restarts={stats.restarts}")
 

@@ -48,9 +48,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import quant as Q
 from repro.core.autotune import resolve_config
 from repro.core.comm import CommEngine

@@ -14,9 +14,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.autotune import resolve_config
 from repro.core.comm import CommEngine
 from repro.core.mics import MiCSConfig, state_pspecs

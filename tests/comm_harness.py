@@ -31,9 +31,9 @@ import traceback
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs import get_config, smoke_variant
 from repro.core import collectives as C
 from repro.core.comm import CommEngine, GatherPolicy, SyncPolicy

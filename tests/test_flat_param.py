@@ -77,3 +77,17 @@ def test_init_kinds(dims):
             assert np.all(t == 1)
         else:
             assert np.std(t) > 0 or t.size < 4
+
+
+@given(shapes)
+def test_init_flat_draws_equal_shaped_draws(dims):
+    """init_flat draws each segment flat; the values are those of a draw in
+    the segment's own shape, so the flat draw changes no initial weight."""
+    layout = _layout(dims)
+    key = jax.random.key(2)
+    tensors = layout.unflatten(layout.init_flat(key))
+    for s in layout.segments:
+        key, sub = jax.random.split(key)
+        if s.init == "normal":
+            want = jax.random.normal(sub, s.shape) * s.std
+            np.testing.assert_array_equal(tensors[s.name], want)

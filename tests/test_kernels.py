@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps against the pure-jnp oracles
-(interpret=True executes the Pallas kernel body on CPU)."""
+(every call passes interpret=True, which executes the Pallas kernel body
+on CPU; the kernels default to compiling for the TPU)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +27,7 @@ def test_flash_attention(bh, t, d, dtype, causal, window):
     k = jnp.asarray(RNG.normal(size=(bh, t, d)), dtype)
     v = jnp.asarray(RNG.normal(size=(bh, t, d)), dtype)
     got = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64, interpret=True)
     want = attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -39,7 +40,8 @@ def test_flash_attention_gqa_layout(g, hkv):
     q = jnp.asarray(RNG.normal(size=(b, t, hkv, g, dh)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(b, t, hkv, dh)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(b, t, hkv, dh)), jnp.float32)
-    got = flash_attention_gqa(q, k, v, block_q=64, block_k=64)
+    got = flash_attention_gqa(q, k, v, block_q=64, block_k=64,
+                              interpret=True)
     # oracle via the model-layer attention (same [b,t,hkv,g,dh] layout)
     from repro.models.layers import attention
     want = attention(q, k, v, causal=True)
@@ -52,7 +54,7 @@ def test_flash_attention_gqa_layout(g, hkv):
 def test_rmsnorm(shape, dtype):
     x = jnp.asarray(RNG.normal(size=shape), dtype)
     s = jnp.asarray(RNG.normal(size=shape[-1]) * 0.2, jnp.float32)
-    got = rmsnorm_nd(x, s)
+    got = rmsnorm_nd(x, s, interpret=True)
     want = rmsnorm_ref(x, s)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -64,12 +66,23 @@ def test_rmsnorm(shape, dtype):
 def test_rglru(b, t, c, dtype):
     a = jnp.asarray(RNG.uniform(0.7, 0.999, size=(b, t, c)), dtype)
     bb = jnp.asarray(RNG.normal(size=(b, t, c)) * 0.1, dtype)
-    got = rglru_scan(a, bb)
+    got = rglru_scan(a, bb, interpret=True)
     want = rglru_ref(a, bb)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=5e-2 if dtype == jnp.bfloat16 else 1e-5,
         atol=5e-2 if dtype == jnp.bfloat16 else 1e-5)
+
+
+def test_rglru_multi_block_carry():
+    """Several time blocks, each walked in several slabs: the state carried
+    across slabs and across the sequential time-grid axis matches the
+    oracle."""
+    a = jnp.asarray(RNG.uniform(0.7, 0.999, size=(2, 256, 256)), jnp.float32)
+    b = jnp.asarray(RNG.normal(size=(2, 256, 256)) * 0.1, jnp.float32)
+    got = rglru_scan(a, b, block_t=64, block_c=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(rglru_ref(a, b)),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_rglru_ref_matches_sequential_loop():

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from hypothesis_compat import given, settings, strategies as st
-from repro.compat import cost_analysis
 from repro.core.topology import (
     HBM_BYTES_PER_CHIP, MiCSTopology, choose_partition_size, make_host_mesh,
 )
@@ -62,7 +61,7 @@ def test_hlo_analyzer_matches_xla_on_loop_free_program():
     b = jnp.ones((128, 256), jnp.float32)
     comp = jax.jit(lambda a, b: (a @ b) @ (a @ b).T).lower(a, b).compile()
     got = analyze(comp.as_text(), {"d": 1})
-    ca = cost_analysis(comp)
+    ca = comp.cost_analysis()
     np.testing.assert_allclose(got["dot_flops"], ca["flops"], rtol=1e-6)
 
 
@@ -78,7 +77,7 @@ def test_hlo_analyzer_weights_scan_trip_counts():
     xs = jnp.ones((7, 32, 32), jnp.float32)
     comp = jax.jit(f).lower(xs).compile()
     got = analyze(comp.as_text(), {"d": 1})
-    ca = cost_analysis(comp)
+    ca = comp.cost_analysis()
     # XLA counts the body once; the analyzer must count it 7 times.
     assert got["dot_flops"] == pytest.approx(7 * 2 * 32 * 32 * 32, rel=1e-6)
     assert ca["flops"] < got["dot_flops"]
