@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes
 from repro.core import collectives as C
 from repro.core import quant as Q
 from repro.core.flat_param import model_gather_fn_for
@@ -305,6 +306,7 @@ class CommEngine:
             g, self.topo, order=gp.topology, inner=gp.inner)
 
     # -- centralized custom-VJP gathers -------------------------------------
+    @jax.named_scope(scopes.HOP1)
     def _adjoint(self, ct: jax.Array, seed=None) -> jax.Array:
         """Hop-1 of §3.4 — or the Fig-14 alternative schedule's full
         all-reduce + slice when the ablation is selected.
@@ -396,6 +398,7 @@ class CommEngine:
         return gather
 
     # -- public gather API --------------------------------------------------
+    @jax.named_scope(scopes.GATHER)
     def gather_flat(self, row, *, seed=None) -> jax.Array:
         """Gather one layer's flat shard into the full flat buffer.
 
@@ -455,11 +458,13 @@ class CommEngine:
         return self._adjoint(ct, seed=seed).astype(jnp.float32)
 
     # -- gradient synchronization ------------------------------------------
+    @jax.named_scope(scopes.HOP1)
     def hop1_reduce_scatter(self, g: jax.Array) -> jax.Array:
         """Explicit hop-1 (tests / alternative schedules); normally this
         arises as the VJP of :meth:`gather_flat`."""
         return self._policy_reduce_scatter(g)
 
+    @jax.named_scope(scopes.HOP2)
     def hop2(self, g: jax.Array, *, salt: int = 0, seed=None) -> jax.Array:
         """Replication-group all-reduce at the gradient-accumulation
         boundary (§3.4 hop 2), with optional bf16 or int8 wire compression.

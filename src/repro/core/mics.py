@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import scopes
 from repro.core.autotune import resolve_config
 from repro.core.comm import CommEngine
 from repro.core.schedule import (
@@ -311,12 +312,14 @@ def build_train_step(
             grads_acc, loss_acc, aux_acc = carry
             (loss, metrics), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 params, mb, step_ctx)
-            grads_acc = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
+            with jax.named_scope(scopes.GRAD_ACCUM):
+                grads_acc = jax.tree.map(
+                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
             return (grads_acc, loss_acc + metrics["loss"],
                     aux_acc + metrics["aux"]), None
 
-        zeros = jax.tree.map(jnp.zeros_like, params)
+        with jax.named_scope(scopes.GRAD_ACCUM):
+            zeros = jax.tree.map(jnp.zeros_like, params)
         (grads, loss_sum, aux_sum), _ = lax.scan(
             micro, (zeros, jnp.float32(0.0), jnp.float32(0.0)), batch)
 
@@ -328,11 +331,13 @@ def build_train_step(
             seed=state["step"], offload_opt=mcfg.offload_opt)
         step = state["step"]
 
-        metrics = {
-            "loss": lax.pmean(loss_sum / s, topo.data_axes),
-            "aux": lax.pmean(aux_sum / s, topo.data_axes),
-            "grad_norm": gnorm,
-        }
+        # The boundary's one other reduction: the step's mean loss.
+        with jax.named_scope(scopes.OPTIMIZER):
+            metrics = {
+                "loss": lax.pmean(loss_sum / s, topo.data_axes),
+                "aux": lax.pmean(aux_sum / s, topo.data_axes),
+                "grad_norm": gnorm,
+            }
         new_state = {"params": new_params, "step": step + 1}
         if not mcfg.offload_opt:
             new_state["m"], new_state["v"] = new_m, new_v

@@ -95,6 +95,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.core.flat_param import partition_buckets
 from repro.core.hostoffload import TAG_M, TAG_V
 from repro.core.topology import MODEL_AXIS, MiCSTopology
@@ -402,6 +403,7 @@ def _apply_boundary_approx(plan, comm, model, topo, oc, state, grads,
     return new_params, new_m, new_v, gnorm
 
 
+@jax.named_scope(scopes.OPTIMIZER)
 def apply_boundary(
     plan: BoundaryPlan,
     comm,

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.configs.base import ArchConfig
 from repro.core import quant as Q
 from repro.core.flat_param import LayoutBuilder
@@ -141,6 +142,7 @@ def _paged_kv_read(cache, pages, compute_dtype):
     return k, v
 
 
+@jax.named_scope(scopes.ATTENTION)
 def self_attention(
     t, x, ctx: L.Ctx, ad: AttnDims, cfg: ArchConfig, *,
     prefix: str = "attn.", causal: bool = True, window: int = 0,
@@ -229,6 +231,7 @@ def self_attention(
     return attn_out(t, out, ad, ctx, prefix, bias=bias), new_cache
 
 
+@jax.named_scope(scopes.ATTENTION)
 def cross_attention(
     t, x, kv_src, ctx: L.Ctx, ad: AttnDims, cfg: ArchConfig, *,
     prefix: str = "xattn.", bias: bool = False, cache=None,
@@ -312,6 +315,7 @@ def mlp_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder, prefix: str = "mlp.",
               model_gather=tp, model_gather_dim=0)
 
 
+@jax.named_scope(scopes.MLP)
 def mlp_apply(cfg: ArchConfig, t, x, ctx: L.Ctx, prefix: str = "mlp."):
     if cfg.mlp == "swiglu":
         out = L.mlp_swiglu(x, t[prefix + "wg"], t[prefix + "wu"], t[prefix + "wd"])
@@ -493,6 +497,7 @@ def _moe_dispatch_tokens(x2d, t, cfg: ArchConfig, ctx: L.Ctx):
     return y, aux
 
 
+@jax.named_scope(scopes.MLP)
 def moe_ffn(t, x, cfg: ArchConfig, ctx: L.Ctx):
     """Token-parallel MoE: activations are replicated across the model axis,
     so each rank routes only its 1/tp slice of the tokens (otherwise every

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.configs.base import ArchConfig
 from repro.core.flat_param import FlatLayout
 from repro.models import layers as L
@@ -149,7 +150,8 @@ def _apply_pool_serial(pool, flat_rows, x, ctx, comm, caches):
             x, aux, _ = inner(x, row, None)
             return (x, aux_tot + aux), None
 
-        (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), flat_rows)
+        with jax.named_scope(scopes.CARRY):
+            (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), flat_rows)
         return x, aux, None
 
     def body(carry, xs):
@@ -158,7 +160,9 @@ def _apply_pool_serial(pool, flat_rows, x, ctx, comm, caches):
         x, aux, new_cache = inner(x, row, cache)
         return (x, aux_tot + aux), new_cache
 
-    (x, aux), new_caches = lax.scan(body, (x, jnp.float32(0.0)), (flat_rows, caches))
+    with jax.named_scope(scopes.CARRY):
+        (x, aux), new_caches = lax.scan(
+            body, (x, jnp.float32(0.0)), (flat_rows, caches))
     return x, aux, new_caches
 
 
@@ -176,7 +180,8 @@ def _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches):
     so the backward pass recomputes unflatten+compute from the carried
     buffer (and the lookahead gather) instead of storing activations.
     """
-    nxt_rows = jax.tree.map(lambda a: jnp.roll(a, -1, axis=0), flat_rows)
+    with jax.named_scope(scopes.CARRY):
+        nxt_rows = jax.tree.map(lambda a: jnp.roll(a, -1, axis=0), flat_rows)
     cur0 = comm.gather_flat(_row(flat_rows, (0, 0)), seed=ctx.step_seed)
 
     def inner(x, cur_full, nxt_row, cache):
@@ -195,7 +200,9 @@ def _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches):
             x, aux, nxt, _ = inner(x, cur, nxt_row, None)
             return (x, aux_tot + aux, nxt), None
 
-        (x, aux, _), _ = lax.scan(body, (x, jnp.float32(0.0), cur0), nxt_rows)
+        with jax.named_scope(scopes.CARRY):
+            (x, aux, _), _ = lax.scan(
+                body, (x, jnp.float32(0.0), cur0), nxt_rows)
         return x, aux, None
 
     def body(carry, xs):
@@ -204,8 +211,9 @@ def _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches):
         x, aux, nxt, new_cache = inner(x, cur, nxt_row, cache)
         return (x, aux_tot + aux, nxt), new_cache
 
-    (x, aux, _), new_caches = lax.scan(
-        body, (x, jnp.float32(0.0), cur0), (nxt_rows, caches))
+    with jax.named_scope(scopes.CARRY):
+        (x, aux, _), new_caches = lax.scan(
+            body, (x, jnp.float32(0.0), cur0), (nxt_rows, caches))
     return x, aux, new_caches
 
 
@@ -251,7 +259,9 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
 
     def fwd_scan(x, flat_rows):
         """The double-buffered forward; also stacks per-layer inputs."""
-        nxt_rows = jax.tree.map(lambda a: jnp.roll(a, -1, axis=0), flat_rows)
+        with jax.named_scope(scopes.CARRY):
+            nxt_rows = jax.tree.map(
+                lambda a: jnp.roll(a, -1, axis=0), flat_rows)
         cur0 = comm.gather_flat(_row(flat_rows, (0, 0)), seed=seed)
 
         def body(carry, nxt_row):
@@ -261,8 +271,9 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
             (x_out, aux), _ = pool.apply(tensors, xc, ctx, None)
             return (x_out, aux_tot + aux, nxt), xc            # stash input
 
-        (x_out, aux, _), x_ins = lax.scan(
-            body, (x, jnp.float32(0.0), cur0), nxt_rows)
+        with jax.named_scope(scopes.CARRY):
+            (x_out, aux, _), x_ins = lax.scan(
+                body, (x, jnp.float32(0.0), cur0), nxt_rows)
         return (x_out, aux), x_ins
 
     @jax.custom_vjp
@@ -283,8 +294,9 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
             d_row, d_x = vjp((ct_x, ct_aux))
             return d_x, d_row
 
-        ct_x, d_rows = lax.scan(body, ct_x, (flat_rows, x_ins),
-                                reverse=True)
+        with jax.named_scope(scopes.CARRY):
+            ct_x, d_rows = lax.scan(body, ct_x, (flat_rows, x_ins),
+                                    reverse=True)
         return ct_x, d_rows
 
     scan_fn.defvjp(scan_fwd, scan_bwd)
@@ -330,7 +342,9 @@ def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
         return x_out, aux
 
     def fwd_scan(x, flat_rows, store):
-        nxt_rows = jax.tree.map(lambda a: jnp.roll(a, -1, axis=0), flat_rows)
+        with jax.named_scope(scopes.CARRY):
+            nxt_rows = jax.tree.map(
+                lambda a: jnp.roll(a, -1, axis=0), flat_rows)
         cur0 = comm.gather_flat(_row(flat_rows, (0, 0)), seed=seed)
 
         def body(carry, xs):
@@ -343,9 +357,10 @@ def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
             (x_out, aux), _ = pool.apply(tensors, xc, ctx, None)
             return (x_out, aux_tot + aux, nxt, tok), xc       # stash input
 
-        (x_out, aux, _, tok), x_ins = lax.scan(
-            body, (x, jnp.float32(0.0), cur0, jnp.int32(0)),
-            (jnp.arange(pool.stack), nxt_rows))
+        with jax.named_scope(scopes.CARRY):
+            (x_out, aux, _, tok), x_ins = lax.scan(
+                body, (x, jnp.float32(0.0), cur0, jnp.int32(0)),
+                (jnp.arange(pool.stack), nxt_rows))
         return (x_out, aux), tok, x_ins
 
     @jax.custom_vjp
@@ -375,8 +390,9 @@ def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
             d_row = comm.gather_flat_adjoint(d_full, seed=seed)
             return d_x, d_row[None, :]
 
-        ct_x, d_rows = lax.scan(
-            body, ct_x, (jnp.arange(pool.stack), x_ins), reverse=True)
+        with jax.named_scope(scopes.CARRY):
+            ct_x, d_rows = lax.scan(
+                body, ct_x, (jnp.arange(pool.stack), x_ins), reverse=True)
         return ct_x, d_rows
 
     scan_fn.defvjp(scan_fwd, scan_bwd)
@@ -384,6 +400,7 @@ def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
     return x, aux, None
 
 
+@jax.named_scope(scopes.EMBED)
 def embed_tokens(model: ModelDef, t_embed, tokens, ctx: L.Ctx, *, pos=None):
     cfg = model.cfg
     x = L.embed_lookup(t_embed["emb.table"], tokens, ctx)
@@ -408,6 +425,7 @@ def encode_audio(model: ModelDef, t_embed, audio, ctx: L.Ctx):
     return (audio + pe).astype(ctx.compute_dtype)
 
 
+@jax.named_scope(scopes.HEAD)
 def lm_logits(model: ModelDef, t_head, x, ctx: L.Ctx):
     cfg = model.cfg
     if cfg.norm == "ln":
@@ -476,10 +494,12 @@ def loss_fn(
     """Token cross-entropy + MoE aux.  batch: tokens/targets/mask [b, T]."""
     hidden, aux, _, t_head = forward(model, flat, comm, ctx, batch)
     logits = lm_logits(model, t_head, hidden, ctx)
-    ce = L.tp_cross_entropy(
-        logits, batch["targets"], batch["mask"].astype(jnp.float32),
-        vocab_real=model.cfg.vocab, vocab_padded=model.vocab_padded, ctx=ctx,
-    )
+    with jax.named_scope(scopes.HEAD):
+        ce = L.tp_cross_entropy(
+            logits, batch["targets"], batch["mask"].astype(jnp.float32),
+            vocab_real=model.cfg.vocab, vocab_padded=model.vocab_padded,
+            ctx=ctx,
+        )
     loss = ce + model.cfg.router_aux_weight * aux
     return loss, {"loss": ce, "aux": aux}
 

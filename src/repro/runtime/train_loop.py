@@ -45,6 +45,7 @@ from typing import Callable
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core.autotune import resolve_world
@@ -117,9 +118,10 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
         retry keeps the checkpoint cadence after a crashed writer."""
         for attempt in (0, 1):
             try:
-                ckpt.save(state, step, topo=topo_cur, data_cursor=cursor,
-                          blocking=blocking, emergency=emergency,
-                          host_stash=_stash_snapshot(mcfg_cur))
+                with TraceAnnotation("train.save"):
+                    ckpt.save(state, step, topo=topo_cur, data_cursor=cursor,
+                              blocking=blocking, emergency=emergency,
+                              host_stash=_stash_snapshot(mcfg_cur))
                 return True
             except Exception as e:  # noqa: BLE001 - failure domain boundary
                 stats.save_failures += 1
@@ -143,106 +145,111 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
     step = int(np.asarray(state["step"]))
     retries = 0
     while step < lc.total_steps:
-        batch = jax.tree.map(
-            jax.numpy.asarray, source.global_step_batch(cursor))
-        t0 = time.time()
-        try:
-            if fault_injector is not None:
-                fault_injector(step)
-            state, metrics = step_fn(state, batch)
-            loss = float(metrics["loss"])  # blocks; surfaces device errors
-        except WorldChangeError as e:
-            stats.restarts += 1
-            if elastic is None:
-                raise
-            if len(stats.world_changes) >= elastic.max_world_changes:
-                log.error("world changed %d times; giving up",
-                          len(stats.world_changes))
-                raise
-            new_world = world - e.lost + e.gained
-            fired_step = step
-            log.warning("world change at step %d (%s): %d -> %d devices",
-                        step, e, world, new_world)
-            if e.notice:
-                # the old world is still intact (preemption notice / grow
-                # announcement): emergency-save so zero steps are lost.
-                if _try_save(state, step, cursor, blocking=True,
-                             emergency=True):
-                    stats.emergency_saves += 1
-            else:
-                try:
-                    ckpt.wait()   # let an in-flight periodic save land
-                except FaultError as we:
-                    stats.save_failures += 1
-                    log.warning("in-flight save lost to the crash (%s)", we)
-            if elastic.backoff_s:
-                time.sleep(elastic.backoff_s * (len(stats.world_changes) + 1))
-            topo_cur, mcfg_cur, info = resize_for_world(
-                model, mcfg, new_world, tp=tp,
-                partition_size=topo_cur.partition_size)
-            step_fn = build_train_step(model, topo_cur, mcfg_cur, oc)
-            if ckpt.latest_step() is not None:
-                state, meta = ckpt.restore(model, topo_cur,
-                                           offload_opt=mcfg_cur.offload_opt)
-                cursor = meta["data_cursor"]
-            else:
-                state = init_state(model, topo_cur, seed=lc.seed,
-                                   offload_opt=mcfg_cur.offload_opt)
-                cursor = 0
-            step = int(np.asarray(state["step"]))
-            world = new_world
-            stats.world_changes.append({
-                "at_step": int(fired_step),
-                "kind": "grow" if e.gained else "preempt",
-                "lost": e.lost, "gained": e.gained, "notice": e.notice,
-                "world": new_world, "resumed_step": step, **info,
-            })
-            log.warning("resumed at step %d on %d devices (p=%d, %s)",
-                        step, new_world, topo_cur.partition_size,
-                        info["rule"])
-            ewma = None
-            measured = 0   # the rebuilt step_fn recompiles on first use
-            retries = 0
-            continue
-        except Exception as e:  # noqa: BLE001 - failure domain boundary
-            stats.restarts += 1
-            retries += 1
-            if retries > lc.max_step_retries:
-                raise
-            log.warning("step %d failed (%s); rolling back", step, e)
-            prev = ckpt.latest_step()
-            if prev is not None:
-                state, meta = ckpt.restore(model, topo_cur,
-                                           offload_opt=mcfg_cur.offload_opt)
-                cursor = meta["data_cursor"]
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with TraceAnnotation("train.batch"):
+                batch = jax.tree.map(
+                    jax.numpy.asarray, source.global_step_batch(cursor))
+            t0 = time.time()
+            try:
+                if fault_injector is not None:
+                    fault_injector(step)
+                with TraceAnnotation("train.step"):
+                    state, metrics = step_fn(state, batch)
+                with TraceAnnotation("train.loss_read"):
+                    loss = float(metrics["loss"])  # blocks; surfaces device errors
+            except WorldChangeError as e:
+                stats.restarts += 1
+                if elastic is None:
+                    raise
+                if len(stats.world_changes) >= elastic.max_world_changes:
+                    log.error("world changed %d times; giving up",
+                              len(stats.world_changes))
+                    raise
+                new_world = world - e.lost + e.gained
+                fired_step = step
+                log.warning("world change at step %d (%s): %d -> %d devices",
+                            step, e, world, new_world)
+                if e.notice:
+                    # the old world is still intact (preemption notice / grow
+                    # announcement): emergency-save so zero steps are lost.
+                    if _try_save(state, step, cursor, blocking=True,
+                                 emergency=True):
+                        stats.emergency_saves += 1
+                else:
+                    try:
+                        ckpt.wait()   # let an in-flight periodic save land
+                    except FaultError as we:
+                        stats.save_failures += 1
+                        log.warning("in-flight save lost to the crash (%s)", we)
+                if elastic.backoff_s:
+                    time.sleep(elastic.backoff_s * (len(stats.world_changes) + 1))
+                topo_cur, mcfg_cur, info = resize_for_world(
+                    model, mcfg, new_world, tp=tp,
+                    partition_size=topo_cur.partition_size)
+                step_fn = build_train_step(model, topo_cur, mcfg_cur, oc)
+                if ckpt.latest_step() is not None:
+                    state, meta = ckpt.restore(model, topo_cur,
+                                               offload_opt=mcfg_cur.offload_opt)
+                    cursor = meta["data_cursor"]
+                else:
+                    state = init_state(model, topo_cur, seed=lc.seed,
+                                       offload_opt=mcfg_cur.offload_opt)
+                    cursor = 0
                 step = int(np.asarray(state["step"]))
-            else:
-                state = init_state(model, topo_cur, seed=lc.seed,
-                                   offload_opt=mcfg_cur.offload_opt)
-                cursor = 0
-                step = 0
-            continue
-        retries = 0
-        dt = time.time() - t0
-        measured += 1
-        if measured > 1:
-            # the first step after a (re)compile pays tracing+compilation;
-            # seeding the EWMA with it would mask real stragglers for many
-            # steps, so the detector warms up from the second step on.
-            ewma = dt if ewma is None else 0.9 * ewma + 0.1 * dt
-        if ewma is not None and dt > lc.straggler_factor * ewma \
-                and len(stats.step_times) > 3:
-            stats.straggler_steps.append(step)
-            log.warning("straggler: step %d took %.2fs (ewma %.2fs)",
-                        step, dt, ewma)
-        stats.losses.append(loss)
-        stats.step_times.append(dt)
-        cursor += 1
-        step += 1
-        if lc.log_every and step % lc.log_every == 0:
-            log.info("step %d loss %.4f (%.2fs)", step, loss, dt)
-        if lc.checkpoint_every and step % lc.checkpoint_every == 0:
-            _try_save(state, step, cursor, blocking=False)
+                world = new_world
+                stats.world_changes.append({
+                    "at_step": int(fired_step),
+                    "kind": "grow" if e.gained else "preempt",
+                    "lost": e.lost, "gained": e.gained, "notice": e.notice,
+                    "world": new_world, "resumed_step": step, **info,
+                })
+                log.warning("resumed at step %d on %d devices (p=%d, %s)",
+                            step, new_world, topo_cur.partition_size,
+                            info["rule"])
+                ewma = None
+                measured = 0   # the rebuilt step_fn recompiles on first use
+                retries = 0
+                continue
+            except Exception as e:  # noqa: BLE001 - failure domain boundary
+                stats.restarts += 1
+                retries += 1
+                if retries > lc.max_step_retries:
+                    raise
+                log.warning("step %d failed (%s); rolling back", step, e)
+                prev = ckpt.latest_step()
+                if prev is not None:
+                    state, meta = ckpt.restore(model, topo_cur,
+                                               offload_opt=mcfg_cur.offload_opt)
+                    cursor = meta["data_cursor"]
+                    step = int(np.asarray(state["step"]))
+                else:
+                    state = init_state(model, topo_cur, seed=lc.seed,
+                                       offload_opt=mcfg_cur.offload_opt)
+                    cursor = 0
+                    step = 0
+                continue
+            retries = 0
+            dt = time.time() - t0
+            measured += 1
+            if measured > 1:
+                # the first step after a (re)compile pays tracing+compilation;
+                # seeding the EWMA with it would mask real stragglers for many
+                # steps, so the detector warms up from the second step on.
+                ewma = dt if ewma is None else 0.9 * ewma + 0.1 * dt
+            if ewma is not None and dt > lc.straggler_factor * ewma \
+                    and len(stats.step_times) > 3:
+                stats.straggler_steps.append(step)
+                log.warning("straggler: step %d took %.2fs (ewma %.2fs)",
+                            step, dt, ewma)
+            stats.losses.append(loss)
+            stats.step_times.append(dt)
+            cursor += 1
+            step += 1
+            if lc.log_every and step % lc.log_every == 0:
+                log.info("step %d loss %.4f (%.2fs)", step, loss, dt)
+            if lc.checkpoint_every and step % lc.checkpoint_every == 0:
+                _try_save(state, step, cursor, blocking=False)
+
     try:
         ckpt.wait()
     except Exception as e:  # noqa: BLE001
