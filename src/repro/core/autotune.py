@@ -23,8 +23,8 @@ module closes the loop: given a model, a MiCS topology and a link profile it
 4. **gates on memory** (``hbm_budget_gb``): every candidate is priced per
    device by the analytical HBM footprint model (core/memplan.py, the
    same predicted-vs-compiled discipline as the wire-byte census),
-   infeasible candidates are filtered from selection, the
-   ``prefetch_carry='remat'`` mitigation joins the grid, and
+   infeasible candidates are filtered from selection, the host-offloaded
+   carry joins the grid beside the re-gathering default, and
    :func:`resolve_scale` implements the paper's §3.1 rule — the minimal
    partition-group size whose aggregate memory holds the model states.
 
@@ -198,31 +198,40 @@ def gather_stages(topology: str, topo: MiCSTopology,
     raise ValueError(f"unknown topology {topology!r}")
 
 
+def _carry(policy) -> str:
+    """The training carry a GatherPolicy or MiCSConfig asks for:
+    ``'host'`` under ``carry_offload='host'``, else ``'remat'``."""
+    return "host" if getattr(policy, "carry_offload", "none") == "host" \
+        else "remat"
+
+
 # ---------------------------------------------------------------------------
 # collective event counts per schedule
 # ---------------------------------------------------------------------------
 
 def _event_counts(stack: int, s: int, *, scanned: bool, prefetch: bool,
-                  mode: str, carry: str = "stored") -> dict[str, float]:
+                  mode: str, carry: str = "remat") -> dict[str, float]:
     """How many gather / reduce-scatter events one pool contributes per step.
 
     Derived from the schedules in models/lm.py + core/mics.py and verified
     instruction-exactly against the measured census by
     tests/autotune_harness.py:
 
+    ``carry`` is the pool's route (models/lm.py ``train_route``).
+
     * scanned pools run under ``jax.checkpoint``: the serial schedule
-      re-gathers every layer in the backward pass (``2·s·stack`` gathers);
-      the double-buffered prefetch schedule instead *carries* the gathered
-      buffer as a backward residual — no backward re-gather — at the price
-      of one wrap-around lookahead per micro-step, and its loop-invariant
-      prologue gather (layer 0) is hoisted out of the micro loop by XLA
-      (``s·stack + 1`` gathers total, DESIGN.md §4).
-    * ``carry='remat'`` keeps the prefetch forward but re-issues every
-      layer's gather in the backward (``2·s·stack + 1`` total) — the
-      memory-planner knob trading one all-gather per layer for the
-      O(layers x flat_len) carry residual; its adjoints come only from the
-      backward re-gathers (``s·stack``), the forward lookahead gathers are
-      outside the differentiated region (models/lm.py custom VJP).
+      re-gathers every layer in the backward pass (``2·s·stack`` gathers).
+    * ``carry='remat'`` (every training pool of the prefetch schedule)
+      gathers one wrap-around lookahead per micro-step, its loop-invariant
+      prologue gather (layer 0) is hoisted out of the micro loop by XLA,
+      and the backward re-issues every layer's gather (``2·s·stack + 1``
+      total, DESIGN.md §4); its adjoints come only from the backward
+      re-gathers (``s·stack``), the forward lookahead gathers are outside
+      the differentiated region (models/lm.py custom VJP).
+    * ``carry='stored'`` (enc-dec decoder pools) *carries* the gathered
+      buffer as a backward residual — no backward re-gather
+      (``s·stack + 1`` gathers) — and adds the prologue gather's adjoint
+      per micro-step (``s·(stack+1)`` adjoints).
     * ``carry='host'`` (``GatherPolicy.carry_offload='host'``) keeps the
       stored forward's gather count (``s·stack + 1``) — the carry streams
       to host memory instead of re-gathering — while its hand-rolled
@@ -234,8 +243,7 @@ def _event_counts(stack: int, s: int, *, scanned: bool, prefetch: bool,
       loop-invariant across micro-steps, so XLA hoists it out of the micro
       loop entirely: ONE gather per step, however many micro-steps.
     * every gather whose cotangent is needed contributes one adjoint
-      reduce-scatter per micro-step — per layer plus, under the stored
-      prefetch carry, the prologue gather's adjoint (``s·(stack+1)``).
+      reduce-scatter per micro-step.
     """
     if mode == "serve":
         ag = stack + 1 if (prefetch and scanned and stack > 1) else stack
@@ -333,14 +341,15 @@ def predict_traffic(
     reorder = (gather.topology == "outer_first"
                and any(st.label == "outer" for st in stages))
 
+    from repro.models.lm import train_route
+
     scanned = {pl.name for pl in model.pools}
-    carry = "host" if getattr(gather, "carry_offload", "none") == "host" \
-        else gather.prefetch_carry
+    cfg = getattr(model, "cfg", None)
     for pool in model.all_pools():
         stack, _tp, flat_len = model.global_flat_shapes()[pool.name]
         n = _event_counts(stack, s, scanned=pool.name in scanned,
                           prefetch=gather.prefetch, mode=mode,
-                          carry=carry)
+                          carry=train_route(cfg, pool.name, stack, gather))
         m_gather = flat_len * wire_b
         m_grad = flat_len * grad_b
         for st in stages:
@@ -578,7 +587,7 @@ class Plan:
                 f"{'mem_GB':>7}") if serve else (
                 f"  {'rank':>4} {'topology':<12} {'inner':>5} {'wire':>5} "
                 f"{'hop1':>5} {'hop2':>5} {'sched':>6} {'bkt_MB':>6} "
-                f"{'clip':>6} {'carry':>6} {'off':>4} "
+                f"{'clip':>6} {'carry':>6} "
                 f"{'t_comm_ms':>10} {'h2_exp_ms':>9} {'inter_MB':>9} "
                 f"{'mem_GB':>7}")
         rows = [f"autotune[{self.profile.name}] mode={self.mode}{budget} "
@@ -601,13 +610,12 @@ class Plan:
                 continue
             sched = "bucket" if c.boundary == "bucketed" else "serial"
             bkt = f"{c.hop2_bucket_mb:g}" if c.boundary == "bucketed" else "-"
-            off = "host" if c.gather.carry_offload == "host" else "-"
             rows.append(
                 f" {mark}{i:>4} {c.gather.topology:<12} "
                 f"{str(c.gather.inner or '-'):>5} {c.gather.wire_dtype:>5} "
                 f"{c.sync.hop1_wire_dtype:>5} "
                 f"{c.sync.hop2_wire_dtype:>5} {sched:>6} {bkt:>6} "
-                f"{c.clip_mode:>6} {c.gather.prefetch_carry:>6} {off:>4} "
+                f"{c.clip_mode:>6} {_carry(c.gather):>6} "
                 f"{c.t_comm_s * 1e3:>10.3f} "
                 f"{c.t_hop2_exposed_s * 1e3:>9.3f} "
                 f"{c.inter_wire_bytes / 1e6:>9.2f} "
@@ -855,9 +863,10 @@ def rank_policies(
     the full ranking (including lossy rows) is kept for the dry-run table
     and BENCH artifacts.
 
-    ``hbm_budget_gb`` adds the memory planner's gate (core/memplan.py):
-    every candidate is priced per device, the ``prefetch_carry='remat'``
-    and ``carry_offload='host'`` mitigations join the grid, infeasible
+    Without ``hbm_budget_gb`` every training row re-gathers in the
+    backward (the ``remat`` carry).  ``hbm_budget_gb`` adds the memory
+    planner's gate (core/memplan.py): every candidate is priced per
+    device, the host-offloaded carry joins the grid, infeasible
     candidates are excluded from selection (they stay in the ranking,
     marked by their ``mem_bytes``), and
     :class:`repro.core.memplan.MemoryBudgetError` is raised — never a
@@ -874,8 +883,7 @@ def rank_policies(
     pricing; it is not a ranked axis (it has no policy interaction).
     """
     profile = get_profile(profile)
-    carries = ("stored",) if hbm_budget_gb is None \
-        else ("stored", "remat", "host")
+    carries = ("remat",) if hbm_budget_gb is None else ("remat", "host")
     serve = mode == "serve"
     # serving ranks the prefetch toggle itself (overlap vs serial gathers
     # changes the decode roofline); training takes it as a caller input.
@@ -889,14 +897,12 @@ def rank_policies(
                 and topo.replication_degree > 1) else ("exact",)
             for clip in clips:
                 for carry in carries:
-                    if carry != "stored" and not (
+                    if carry == "host" and not (
                             g.prefetch and mode == "train"):
                         continue   # carries only differ with a backward
-                    if carry == "host":
-                        g2 = dataclasses.replace(
-                            g, prefetch_carry="stored", carry_offload="host")
-                    else:
-                        g2 = dataclasses.replace(g, prefetch_carry=carry)
+                    g2 = dataclasses.replace(
+                        g, carry_offload="host" if carry == "host"
+                        else "none")
                     c = cost_candidate(model, topo, profile, g2, s,
                                        micro_steps=micro_steps, mode=mode,
                                        boundary=boundary,
@@ -947,10 +953,9 @@ def rank_policies(
                         offload_opt=offload_opt and mode == "train")
                     cands.append(dataclasses.replace(
                         c, mem_bytes=mem.total_bytes))
-    # modeled time first; among time-ties the smaller footprint wins (which
-    # is what makes remat the tie-break choice at p=1, where the extra
-    # backward re-gather moves zero wire bytes).  Exact clip and the
-    # in-HBM carry sort before approx/host on full ties — reference
+    # modeled time first; among time-ties the smaller footprint wins.
+    # Exact clip and the re-gathering carry sort before approx/host on
+    # full ties — reference
     # numerics and no host traffic unless they buy something.  Serving
     # sorts by the decode roofline instead (throughput breaks ties).
     if serve:
@@ -964,7 +969,7 @@ def rank_policies(
                               c.sync.hop2_wire_dtype,
                               c.boundary, c.hop2_bucket_mb,
                               c.clip_mode != "exact",
-                              c.mem_bytes, c.gather.prefetch_carry,
+                              c.mem_bytes,
                               c.gather.carry_offload != "none"))
 
     def hop2_ok(c: Candidate) -> bool:
@@ -992,7 +997,7 @@ def rank_policies(
             f"no eligible policy fits hbm_budget_gb={hbm_budget_gb} on "
             f"p={topo.partition_size}: the smallest candidate "
             f"({smallest.gather.topology}/{smallest.gather.wire_dtype}, "
-            f"prefetch_carry={smallest.gather.prefetch_carry!r}) needs "
+            f"carry={_carry(smallest.gather)!r}) needs "
             f"{smallest.mem_bytes / 1024**3:.3f} GiB per device; grow the "
             f"partition group (memplan.min_partition_size) or the budget")
     pool = feasible or eligible or cands
@@ -1022,9 +1027,8 @@ def resolve_config(mcfg, model, topo: MiCSTopology, *,
 
     With ``mcfg.hbm_budget_gb`` set, the memory planner gates the ranking
     (core/memplan.py): infeasible candidates are filtered out, the
-    ``prefetch_carry='remat'`` mitigation joins the grid (chosen only when
-    the stored carry does not fit — it costs one extra all-gather per
-    layer), and a clear :class:`repro.core.memplan.MemoryBudgetError` is
+    host-offloaded carry joins the re-gathering default in the grid, and
+    a clear :class:`repro.core.memplan.MemoryBudgetError` is
     raised when nothing fits on this topology's partition group.  Use
     :func:`resolve_scale` to pick the partition-group *size* itself — the
     paper's §3.1 minimal-group rule.
@@ -1071,7 +1075,6 @@ def resolve_config(mcfg, model, topo: MiCSTopology, *,
         compress_hop2=(s.hop2_wire_dtype
                        if s.hop2_wire_dtype != "fp32" else False),
         hop1_wire_dtype=s.hop1_wire_dtype,
-        prefetch_carry=g.prefetch_carry,
         carry_offload=getattr(g, "carry_offload", "none"),
         boundary_schedule=plan.chosen.boundary,
         hop2_bucket_mb=plan.chosen.hop2_bucket_mb,
@@ -1098,12 +1101,11 @@ def resolve_scale(model, mcfg, *, data_extent: int, mode: str = "train",
     Returns ``(partition_size, carry, mem_plan)`` — the *minimal*
     partition-group size over a data axis of ``data_extent`` whose
     predicted per-device footprint fits ``mcfg.hbm_budget_gb`` GiB, trying
-    the stored carry first, the remat mitigation second and the
+    the re-gathering default (``carry == "remat"``) first and the
     host-offloaded carry (``carry == "host"`` ->
-    ``MiCSConfig(carry_offload="host")``) third at every size (a smaller
-    group rescued by remat or host offload beats a larger stored one:
-    smaller groups keep collectives on faster tiers, which is the whole
-    point of scale-aware partitioning).  With ``mcfg.offload_opt`` the
+    ``MiCSConfig(carry_offload="host")``) second at every size (a smaller
+    group beats a larger one: smaller groups keep collectives on faster
+    tiers, which is the whole point of scale-aware partitioning).  With ``mcfg.offload_opt`` the
     m/v shards leave the footprint too, shrinking the minimal group
     further.  Raises
     :class:`repro.core.memplan.MemoryBudgetError` when even the full data
@@ -1116,8 +1118,8 @@ def resolve_scale(model, mcfg, *, data_extent: int, mode: str = "train",
     if getattr(mcfg, "hbm_budget_gb", None) is None:
         raise ValueError("resolve_scale needs MiCSConfig.hbm_budget_gb")
     gp, sp = policies_from_config(mcfg)
-    carries = ("stored", "remat", "host") if gp.prefetch and mode == "train" \
-        else ("stored",)
+    carries = ("remat", "host") if gp.prefetch and mode == "train" \
+        else ("remat",)
     return M.min_partition_size(
         model, data_extent=data_extent, hbm_budget_gb=mcfg.hbm_budget_gb,
         gather=gp, sync=sp, micro_steps=mcfg.micro_steps, mode=mode,
@@ -1156,19 +1158,15 @@ def resolve_world(model, mcfg, *, n_devices: int, tp: int = 1,
         p, carry, mem_plan = resolve_scale(
             model, mcfg, data_extent=data_extent, mode=mode,
             local_batch=local_batch, seq=seq)
-        if carry == "host":
-            mcfg2 = dataclasses.replace(
-                mcfg, prefetch_carry="stored", carry_offload="host")
-        else:
-            mcfg2 = dataclasses.replace(
-                mcfg, prefetch_carry=carry, carry_offload="none")
+        mcfg2 = dataclasses.replace(
+            mcfg, carry_offload="host" if carry == "host" else "none")
         info = {"rule": "resolve_scale", "carry": carry,
                 "hbm_budget_gb": mcfg.hbm_budget_gb,
                 "mem_gib": mem_plan.total_bytes / GIB}
     else:
         prefer = min(partition_size or data_extent, data_extent)
         p = max(d for d in range(1, prefer + 1) if data_extent % d == 0)
-        mcfg2, info = mcfg, {"rule": "keep", "carry": mcfg.prefetch_carry}
+        mcfg2, info = mcfg, {"rule": "keep", "carry": _carry(mcfg)}
     info.update(partition_size=p, data_extent=data_extent, tp=tp,
                 n_devices=n_devices)
     return p, mcfg2, info

@@ -47,7 +47,6 @@ from repro.core.topology import MODEL_AXIS, MiCSTopology, hierarchy_factors
 
 GATHER_TOPOLOGIES = ("flat", "inner_first", "outer_first")
 WIRE_DTYPES = ("fp32", "bf16", "int8")
-PREFETCH_CARRIES = ("stored", "remat")
 CARRY_OFFLOADS = ("none", "host")
 SYNC_MODES = ("2hop", "allreduce_slice")
 HOP1_WIRE_DTYPES = ("fp32", "bf16", "int8")
@@ -61,29 +60,20 @@ _WIRE_JNP = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 class GatherPolicy:
     """How a flat-param pool is all-gathered across its partition group.
 
-    ``prefetch_carry`` decides what the double-buffered schedule keeps for
-    the backward pass (only meaningful with ``prefetch=True``):
-    ``'stored'`` carries the gathered flat buffer as a per-layer scan
-    residual (no backward re-gather — the seed behavior, O(layers x
-    flat_len) HBM); ``'remat'`` drops the carried buffer and re-issues the
-    gather inside the backward instead (one extra all-gather per layer,
-    O(layers x shard) HBM — the memory-planner mitigation knob,
-    models/lm.py).
-
-    ``carry_offload='host'`` is the third residual strategy: keep the
-    stored carry's schedule (no backward re-gather) but stream each
-    layer's gathered buffer to host memory in the forward and back to
-    device in the backward (core/hostoffload.py) — O(layers x shard) HBM
-    like remat, priced as the link model's host tier instead of an extra
-    all-gather.  It composes with the *stored* carry only (it replaces
-    the stored residual's residency, not remat's re-gather).
+    Under ``prefetch=True`` a training step keeps no gathered buffer for
+    the backward pass: the backward re-issues each layer's gather
+    (models/lm.py ``pool_route``, O(layers x shard) HBM).
+    ``carry_offload='host'`` instead keeps the stored carry's schedule (no
+    backward re-gather) and streams each layer's gathered buffer to host
+    memory in the forward and back to device in the backward
+    (core/hostoffload.py) — O(layers x shard) HBM too, priced as the link
+    model's host tier instead of an extra all-gather.
     """
 
     topology: str = "inner_first"  # 'flat' | 'inner_first' | 'outer_first'
     wire_dtype: str = "bf16"       # 'fp32' | 'bf16' | 'int8' (ZeRO++ qwZ)
     inner: int | None = None       # intra-"node" factor for staged gathers
     prefetch: bool = True          # one-slot lookahead layer scan
-    prefetch_carry: str = "stored"  # 'stored' | 'remat' backward residual
     carry_offload: str = "none"    # 'none' | 'host' (d2h/h2d carry stream)
 
     def __post_init__(self):
@@ -91,20 +81,14 @@ class GatherPolicy:
             raise ValueError(f"unknown gather topology {self.topology!r}")
         if self.wire_dtype not in WIRE_DTYPES:
             raise ValueError(f"unknown wire dtype {self.wire_dtype!r}")
-        if self.prefetch_carry not in PREFETCH_CARRIES:
-            raise ValueError(
-                f"unknown prefetch_carry {self.prefetch_carry!r} "
-                f"(expected one of {PREFETCH_CARRIES})")
         if self.carry_offload not in CARRY_OFFLOADS:
             raise ValueError(
                 f"unknown carry_offload {self.carry_offload!r} "
                 f"(expected one of {CARRY_OFFLOADS})")
-        if self.carry_offload == "host" and not (
-                self.prefetch and self.prefetch_carry == "stored"):
+        if self.carry_offload == "host" and not self.prefetch:
             raise ValueError(
-                "carry_offload='host' requires prefetch=True and "
-                "prefetch_carry='stored' (it offloads the stored carry's "
-                "residual; remat has no carried buffer to offload)")
+                "carry_offload='host' requires prefetch=True (it offloads "
+                "the prefetch schedule's carried buffer)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,7 +148,6 @@ def policies_from_config(mcfg) -> tuple[GatherPolicy, SyncPolicy]:
         wire_dtype=wire,
         inner=mcfg.hierarchy_inner,
         prefetch=getattr(mcfg, "prefetch", True),
-        prefetch_carry=getattr(mcfg, "prefetch_carry", "stored"),
         carry_offload=getattr(mcfg, "carry_offload", "none"),
     )
     hop2 = mcfg.compress_hop2  # bool (legacy) or wire-dtype string
@@ -224,10 +207,6 @@ class CommEngine:
     @property
     def prefetch(self) -> bool:
         return self.gather_policy.prefetch
-
-    @property
-    def prefetch_carry(self) -> str:
-        return self.gather_policy.prefetch_carry
 
     @property
     def carry_offload(self) -> str:

@@ -354,30 +354,26 @@ def predict_footprint(
     # -- backward: the largest full-buffer cotangent (fp32 adjoint input) --
     add("gather_adjoint", max_flat * 4)
 
-    # -- prefetch-carry backward residual (GatherPolicy.prefetch_carry) ----
-    # stored: the stacked carried buffer persists at fp32 (observed: the
-    # adjoint accumulation dtype) + the rotated shard copy.  remat: only
-    # the rotated shard copy + one transient re-gathered buffer.  Mirrors
-    # models/lm.py's routing: enc-dec *decoder* pools consume the encoder
-    # output and fall back to the stored carry even under remat (a custom
-    # VJP may not close over a gradient-carrying enc_out), so they are
-    # priced as stored — the budget gate must not under-predict them.
-    # Host offload ('carry_offload') prices like remat — the stacked
-    # residual streams to host memory, leaving the rolled shard copy and
-    # one transient full buffer — and shares remat's enc-dec fallback
-    # (decoder pools keep the stored carry, models/lm.py routing).
+    # -- prefetch-carry backward residual, per pool route (models/lm.py) --
+    # remat: the rotated shard copy + one transient re-gathered buffer.
+    # Host offload prices the same: the stacked residual streams to host
+    # memory.  stored (enc-dec decoder pools, which read the encoder
+    # output): the stacked carried buffer persists at fp32 (observed: the
+    # adjoint accumulation dtype) + the rotated shard copy.
+    from repro.models.lm import train_route
+
     cfg = getattr(model, "cfg", None)
-    family = getattr(cfg, "family", None)
-    offload_carry = getattr(gather, "carry_offload", "none") == "host"
     for name, (stack, _tp, flat_len) in shapes.items():
-        if not (prefetching and name in scanned and stack > 1):
+        if name not in scanned:
+            continue
+        route = train_route(cfg, name, stack, gather)
+        if route == "serial":
             continue
         rolled = stack * math.ceil(flat_len / p) * 4
-        eligible = not (family == "encdec" and not name.startswith("enc"))
-        if eligible and (gather.prefetch_carry == "remat" or offload_carry):
-            add("prefetch_carry", rolled + flat_len * cb)
-        else:
+        if route == "stored":
             add("prefetch_carry", stack * flat_len * 4 + rolled)
+        else:
+            add("prefetch_carry", rolled + flat_len * cb)
 
     # -- activation checkpoints + logits/CE workspace ----------------------
     if local_batch and seq and cfg is not None:
@@ -441,7 +437,7 @@ def min_partition_size(
     seq: int = 0,
     boundary: str = "bucketed",
     hop2_bucket_mb: float = 32.0,
-    carries: tuple = ("stored",),
+    carries: tuple = ("remat",),
     offload_opt: bool = False,
     extra_replication: int = 1,
 ) -> tuple[int, str, MemPlan]:
@@ -451,12 +447,10 @@ def min_partition_size(
     the mesh axis the partition group is carved from) and returns the
     first ``(p, carry, plan)`` whose predicted per-device footprint fits
     ``hbm_budget_gb`` GiB — the *minimal* group that fits, trying each
-    entry of ``carries`` in order at every size (pass
-    ``("stored", "remat", "host")`` to let the remat and host-offload
-    mitigations rescue a smaller group before growing it; ``"host"``
-    means the stored carry streamed to host memory,
-    ``GatherPolicy.carry_offload='host'``, and is skipped when the gather
-    policy does not prefetch).  ``extra_replication`` multiplies the
+    entry of ``carries`` in order at every size (``"remat"``, the
+    training default, and ``"host"``, the carry streamed to host memory,
+    ``GatherPolicy.carry_offload='host'``, skipped when the gather policy
+    does not prefetch).  ``extra_replication`` multiplies the
     replication degree for data-parallel axes the group cannot span (the
     pod axis of a multi-pod mesh, the dp2 leftover of tp < model axis) so
     hop-2 staging is priced even when p == data_extent.  Raises
@@ -470,14 +464,10 @@ def min_partition_size(
             partition_size=p,
             replication_degree=(data_extent // p) * max(extra_replication, 1))
         for carry in carries:
-            if carry == "host":
-                if not gather.prefetch:
-                    continue
-                g2 = dataclasses.replace(
-                    gather, prefetch_carry="stored", carry_offload="host")
-            else:
-                g2 = dataclasses.replace(
-                    gather, prefetch_carry=carry, carry_offload="none")
+            if carry == "host" and not gather.prefetch:
+                continue
+            g2 = dataclasses.replace(
+                gather, carry_offload="host" if carry == "host" else "none")
             plan = predict_footprint(
                 model, grid, g2, sync, micro_steps=micro_steps, mode=mode,
                 local_batch=local_batch, seq=seq, boundary=boundary,
@@ -489,7 +479,7 @@ def min_partition_size(
     assert best is not None
     raise MemoryBudgetError(
         f"no partition group fits hbm_budget_gb={hbm_budget_gb}: the "
-        f"smallest candidate (p={best[0]}, prefetch_carry={best[1]!r}) "
+        f"smallest candidate (p={best[0]}, carry={best[1]!r}) "
         f"needs {best[2].total_gb:.3f} GiB per device "
         f"(args {best[2].args_bytes / GIB:.3f} + "
         f"temp {best[2].temp_bytes / GIB:.3f}); raise the budget, shrink "

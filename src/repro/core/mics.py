@@ -83,8 +83,6 @@ class MiCSConfig:
     #                                     block-quantized hop-1 reduce-scatter)
     grad_rounding: str = "stochastic"   # int8 gradient quantizer rounding
     prefetch: bool = True               # double-buffered lookahead gathers
-    prefetch_carry: str = "stored"      # 'stored' carry residual | 'remat'
-    #                                     backward re-gather (memplan knob)
     policy: str = "manual"              # 'manual' | 'auto' (link-model tuner)
     link_profile: Any = "v5e"           # profile name or LinkProfile instance
     boundary_schedule: str = "bucketed"  # 'serial' (reference) | 'bucketed'
@@ -106,7 +104,7 @@ class MiCSConfig:
     def __post_init__(self):
         from repro.core.comm import (
             CARRY_OFFLOADS, GRAD_ROUNDINGS, HOP1_WIRE_DTYPES,
-            HOP2_WIRE_DTYPES, PREFETCH_CARRIES,
+            HOP2_WIRE_DTYPES,
         )
 
         if self.policy not in ("manual", "auto"):
@@ -130,15 +128,10 @@ class MiCSConfig:
             raise ValueError(
                 f"unknown carry_offload {self.carry_offload!r} "
                 f"(expected one of {CARRY_OFFLOADS})")
-        if self.carry_offload == "host" and not (
-                self.prefetch and self.prefetch_carry == "stored"):
+        if self.carry_offload == "host" and not self.prefetch:
             raise ValueError(
-                "carry_offload='host' requires prefetch=True and "
-                "prefetch_carry='stored' (it offloads the stored carry)")
-        if self.prefetch_carry not in PREFETCH_CARRIES:
-            raise ValueError(
-                f"unknown prefetch_carry {self.prefetch_carry!r} "
-                f"(expected one of {PREFETCH_CARRIES})")
+                "carry_offload='host' requires prefetch=True (it offloads "
+                "the prefetch schedule's carried buffer)")
         if self.hbm_budget_gb is not None and self.hbm_budget_gb <= 0:
             raise ValueError(
                 f"hbm_budget_gb must be > 0, got {self.hbm_budget_gb}")

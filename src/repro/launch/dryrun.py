@@ -7,7 +7,7 @@ collective census (roofline inputs) to artifacts/dryrun/<cell>.json.
 
 Communication policy: all collectives run through the CommEngine
 (core/comm.py).  The manual flags (--gather-order, --quant-gather,
---prefetch, --prefetch-carry, ...) map 1:1 onto its
+--prefetch, --carry-offload, ...) map 1:1 onto its
 GatherPolicy/SyncPolicy; ``--policy auto`` instead hands the choice to the
 link-model autotuner (core/autotune.py), which prints the ranked candidate
 table for the ``--link-profile`` and records the chosen plan — plus a
@@ -19,8 +19,8 @@ footprint next to XLA's compiled ``memory_analysis()``
 (plan-vs-compiled, core/memplan.py).  ``--hbm-budget-gb`` additionally
 applies the paper's §3.1 rule — the minimal partition group whose
 predicted footprint fits — when no --partition-size is pinned, and gates
-``--policy auto`` candidates on feasibility (with the
-``prefetch_carry='remat'`` mitigation joining the grid).  Training cells
+``--policy auto`` candidates on feasibility (with the host-offloaded
+carry joining the grid).  Training cells
 additionally record the boundary scheduler's bucket plan
 (``--boundary-schedule`` / ``--hop2-bucket-mb``, core/schedule.py) with
 the link model's predicted exposed-vs-hidden hop-2 time and the measured
@@ -122,7 +122,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
             and not zero3:
         # the paper's §3.1 rule, analytically: minimal partition group
         # whose predicted per-device footprint fits the budget
-        # (core/memplan.py); the chosen prefetch carry rides along.
+        # (core/memplan.py); the chosen carry rides along.
         sizing_model = build_model(cfg, tp=tp or 16)
         # the partition group is carved from the 16-wide data axis; pods
         # and the dp2 leftover of a narrow tp replicate on top of it
@@ -131,11 +131,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
             sizing_model, mcfg, data_extent=16,
             mode="train" if spec["kind"] == "train" else "serve",
             extra_replication=extra_repl)
-        if carry == "host":   # third strategy: stored carry streamed to host
-            mcfg = dataclasses.replace(mcfg, prefetch_carry="stored",
-                                       carry_offload="host")
-        else:
-            mcfg = dataclasses.replace(mcfg, prefetch_carry=carry)
+        mcfg = dataclasses.replace(
+            mcfg, carry_offload="host" if carry == "host" else "none")
         print(f"memplan: p={partition_size} carry={carry} "
               f"({scale_plan.total_gb:.2f} GiB predicted vs budget "
               f"{mcfg.hbm_budget_gb:g} GiB)", flush=True)
@@ -366,21 +363,13 @@ def main():
                     help="1 = double-buffered lookahead gathers (layer i+1 "
                          "gathered during layer i's compute; the default), "
                          "0 = serial reference schedule")
-    ap.add_argument("--prefetch-carry", default="stored",
-                    choices=["stored", "remat"],
-                    help="backward residual of the prefetch schedule: "
-                         "'stored' carries the gathered buffer (no backward "
-                         "re-gather, O(layers x flat_len) HBM), 'remat' "
-                         "re-issues the gather in the backward (one extra "
-                         "all-gather per layer, O(layers x shard) HBM — the "
-                         "memory planner's mitigation knob)")
     ap.add_argument("--carry-offload", default="none",
                     choices=["none", "host"],
-                    help="third residual strategy: stream the stored carry "
-                         "through host memory over the link model's host "
-                         "tier (d2h forward / h2d backward, "
-                         "core/hostoffload.py) — no backward re-gather and "
-                         "no O(layers x flat_len) HBM residency")
+                    help="'host' keeps each layer's gathered buffer for the "
+                         "backward in host memory over the link model's "
+                         "host tier (d2h forward / h2d backward, "
+                         "core/hostoffload.py) instead of re-gathering it "
+                         "in the backward; 'none' re-gathers")
     ap.add_argument("--offload-opt", action="store_true",
                     help="host-offload the AdamW m/v shards around the "
                          "boundary update: the on-device state keeps only "
@@ -429,7 +418,6 @@ def main():
         compress_hop2=(False if args.compress_hop2 == "off"
                        else args.compress_hop2),
         prefetch=bool(args.prefetch),
-        prefetch_carry=args.prefetch_carry,
         carry_offload=args.carry_offload,
         offload_opt=args.offload_opt,
         clip_mode=args.clip_mode,

@@ -39,6 +39,7 @@ from repro.core.mics import MiCSConfig
 from repro.core.schedule import plan_boundary
 from repro.core.topology import MiCSTopology, elastic_host_topology
 from repro.data.pipeline import DataConfig
+from repro.models import lm
 from repro.models.build import build_model
 from repro.models.lm import ModelDef
 from repro.optim.adamw import OptConfig
@@ -93,10 +94,11 @@ def build_training(cfg: ArchConfig, topo: MiCSTopology, mcfg: MiCSConfig, *,
         model, topo, gp, sp, micro_steps=mcfg.micro_steps, mode="train",
         local_batch=lb, seq=seq, boundary=mcfg.boundary_schedule,
         hop2_bucket_mb=mcfg.hop2_bucket_mb, offload_opt=mcfg.offload_opt)
+    routes = " ".join(f"{name}={route}" for name, route
+                      in lm.train_routes(model, gp).items())
     print(f"memplan: {mem.total_gb:.3f} GiB predicted per device "
-          f"(prefetch_carry={mcfg.prefetch_carry}, "
-          f"carry_offload={mcfg.carry_offload}, "
-          f"offload_opt={mcfg.offload_opt})")
+          f"(carry_offload={mcfg.carry_offload}, "
+          f"offload_opt={mcfg.offload_opt}) routes: {routes}")
     oc = OptConfig(lr_max=lr, total_steps=steps,
                    warmup_steps=max(steps // 20, 1))
     dc = DataConfig(vocab=cfg.vocab, seq=seq, global_batch=global_batch,
@@ -145,20 +147,13 @@ def main():
     ap.add_argument("--prefetch", type=int, default=1,
                     help="1 = double-buffered lookahead gathers (default), "
                          "0 = serial reference schedule")
-    ap.add_argument("--prefetch-carry", default="stored",
-                    choices=["stored", "remat"],
-                    help="prefetch backward residual: 'stored' carries the "
-                         "gathered buffer (O(layers x flat_len) HBM), "
-                         "'remat' re-gathers in the backward — one extra "
-                         "all-gather per layer buys the residual down to "
-                         "O(layers x shard); core/memplan.py prices both")
     ap.add_argument("--carry-offload", default="none",
                     choices=["none", "host"],
-                    help="third residual strategy: stream the stored carry "
-                         "through host memory (d2h in the forward, h2d in "
-                         "the backward, core/hostoffload.py) — no backward "
-                         "re-gather AND no O(layers x flat_len) HBM; priced "
-                         "on the link model's host tier")
+                    help="'host' keeps each layer's gathered buffer for the "
+                         "backward in host memory (d2h in the forward, h2d "
+                         "in the backward, core/hostoffload.py) instead of "
+                         "re-gathering it in the backward; priced on the "
+                         "link model's host tier")
     ap.add_argument("--offload-opt", action="store_true",
                     help="host-offload the AdamW m/v shards: the state dict "
                          "keeps only params+step, moments stream through "
@@ -199,7 +194,6 @@ def main():
                       quant_gather=args.quant_gather,
                       hop1_wire_dtype=args.hop1_wire_dtype,
                       prefetch=bool(args.prefetch),
-                      prefetch_carry=args.prefetch_carry,
                       carry_offload=args.carry_offload,
                       offload_opt=args.offload_opt,
                       clip_mode=args.clip_mode,

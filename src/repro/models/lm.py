@@ -19,29 +19,43 @@ Two schedules exist (``CommEngine.prefetch`` selects):
   style prefetch MiCS assumes.  Loss is bitwise identical to the serial
   schedule (same gathers, same compute, same order of adds).
 
-The prefetch schedule's backward residual is selected by
-``GatherPolicy.prefetch_carry``:
+A pool's route (:func:`pool_route`) follows from what it can observe:
 
-* ``'stored'`` (the seed behaviour) — the carried gathered buffer becomes a
-  per-layer scan residual, so the backward never re-gathers; costs
-  O(layers x flat_len) HBM per scanned pool (DESIGN.md §4).
-* ``'remat'`` — the whole pool scan runs under a custom VJP
-  (:func:`_apply_pool_prefetch_remat`): the forward is the *identical*
-  double-buffered scan (bitwise-equal losses), but only the layer-input
-  activations and the parameter shards are kept; the backward re-issues
-  each layer's all-gather (through the same CommEngine gather and its
-  exact adjoint) and re-linearizes the layer on the fly.  Costs one extra
-  all-gather per layer per micro-step and only O(layers x shard) HBM —
-  the memory planner's first mitigation knob (core/memplan.py).
+* ``'remat'``, every training pool of the prefetch schedule — the whole
+  pool scan runs under a custom VJP (:func:`_apply_pool_prefetch_remat`):
+  the forward is the *identical* double-buffered scan (bitwise-equal
+  losses and gradients), but only the layer-input activations and the
+  parameter shards are kept; the backward re-issues each layer's
+  all-gather (through the same CommEngine gather and its exact adjoint)
+  and re-linearizes the layer on the fly.  Costs one extra all-gather per
+  layer per micro-step and only O(layers x shard) HBM.
+* ``'stored'`` (:func:`_apply_pool_prefetch`) — the carried gathered
+  buffer becomes a per-layer scan residual, so the backward never
+  re-gathers; costs O(layers x flat_len) HBM per scanned pool
+  (DESIGN.md §4), and the time to write that residual, read it back and
+  carry its cotangent through the transposed scan.  Only the pools the
+  remat VJP cannot run take it: serving pools (caches, no backward) and
+  enc-dec decoder pools (their encoder output carries gradient that a
+  custom VJP closure would drop).
+* ``'host'`` (``GatherPolicy.carry_offload='host'``,
+  :func:`_apply_pool_prefetch_offload`) — the stored carry's schedule,
+  with each layer's gathered buffer streamed down to host memory
+  (core/hostoffload.py) as soon as the next layer's gather is in flight
+  and back right before that layer's recompute: no re-gather, no
+  O(layers x flat_len) HBM residual, at the price of 2 x layers x
+  flat_len bytes over the host link per micro-step (the ``host`` tier of
+  the link model, core/linkmodel.py).
+* ``'serial'`` — no prefetch, or a one-layer pool.
 
-A third residency for the stored carry is ``GatherPolicy.carry_offload =
-'host'`` (:func:`_apply_pool_prefetch_offload`): the forward streams each
-layer's gathered buffer down to host memory (core/hostoffload.py) as soon
-as the next layer's gather is in flight, and the backward streams it back
-right before that layer's recompute — no re-gather (unlike remat), no
-O(layers x flat_len) HBM residual (unlike stored), at the price of
-2 x layers x flat_len bytes over the host link per micro-step (priced as
-the ``host`` tier of the link model, core/linkmodel.py).
+Why training re-gathers rather than keeping the stored residual: on a
+TPU v5e the residual's data movement costs more than the re-gather it
+spares.  For bert-10b at 4 layers (hidden 2560, 8 x 512 tokens a chip,
+two micro-steps) the remat step trains 14310 tokens/s a chip against
+10906 stored on one chip (+31%), where a re-gather is a local bf16 cast
+(8 of them, 12.8 ms a step), and 11972 against 9993 on a repl=2 x
+shard=2 mesh (+20%), where the backward's re-gathers add about 40 ms a
+step (PERF.md §5, §6).  The stored residual also always takes more HBM
+(core/memplan.py), so no memory budget needs it either.
 """
 
 from __future__ import annotations
@@ -103,32 +117,63 @@ def _row(x, idx=(0,)):
     return jax.tree.map(lambda a: a[idx], x)
 
 
+def pool_route(stack: int, comm, *, serving: bool = False,
+               enc_out: bool = False) -> str:
+    """The schedule :func:`_apply_pool` runs a pool of ``stack`` layers
+    on: ``'serial'``, ``'stored'``, ``'remat'`` or ``'host'`` (the module
+    docstring says what each keeps for the backward).
+
+    ``comm`` is the CommEngine or its GatherPolicy (``prefetch``,
+    ``carry_offload``); ``serving`` says the pool scans its caches (no
+    backward), ``enc_out`` that it reads the encoder output (gradient a
+    custom VJP closure would drop).  Both keep the stored carry.
+    """
+    if not (getattr(comm, "prefetch", False) and stack > 1):
+        return "serial"
+    if serving or enc_out:
+        return "stored"
+    if getattr(comm, "carry_offload", "none") == "host":
+        return "host"
+    return "remat"
+
+
+def is_encoder_pool(cfg, name: str) -> bool:
+    """An enc-dec model's encoder pool: :func:`forward` runs it first, and
+    its output is every other pool's ``ctx.enc_out``."""
+    return getattr(cfg, "family", None) == "encdec" and name.startswith("enc")
+
+
+def train_route(cfg, name: str, stack: int, comm) -> str:
+    """The route of pool ``name`` in a training step: what :func:`forward`
+    hands :func:`pool_route` (no caches; an enc-dec model's non-encoder
+    pools read the encoder output)."""
+    enc_out = (getattr(cfg, "family", None) == "encdec"
+               and not is_encoder_pool(cfg, name))
+    return pool_route(stack, comm, enc_out=enc_out)
+
+
+def train_routes(model: ModelDef, comm) -> dict[str, str]:
+    """:func:`train_route` of each scanned pool of ``model``."""
+    return {pool.name: train_route(model.cfg, pool.name, pool.stack, comm)
+            for pool in model.pools}
+
+
 def _apply_pool(
     pool: Pool, flat_rows, x: jax.Array, ctx: L.Ctx,
     comm, caches=None,
 ):
     """Scan a pool over its stack.  flat_rows: [stack, 1, S_local] leaves.
 
-    ``comm`` is the CommEngine owning every gather collective; its
-    ``prefetch`` policy selects the serial or double-buffered schedule, and
-    ``prefetch_carry`` the stored-vs-remat backward residual of the latter.
+    ``comm`` is the CommEngine owning every gather collective;
+    :func:`pool_route` picks the schedule from its policy.
     """
-    if getattr(comm, "prefetch", False) and pool.stack > 1:
-        if (getattr(comm, "carry_offload", "none") == "host"
-                and caches is None and ctx.enc_out is None
-                and not isinstance(flat_rows, dict)):
-            # Host-offloaded stored carry: same custom-VJP restrictions as
-            # remat (no serving caches, no encoder output), plus a plain
-            # fp32 shard layout (quantized {'q','s'} pools keep the
-            # in-HBM carry — their gathered buffer is already compact).
-            return _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm)
-        if (getattr(comm, "prefetch_carry", "stored") == "remat"
-                and caches is None and ctx.enc_out is None):
-            # remat needs a backward pass to pay off and a custom VJP to
-            # run; the cached (serving) path has no backward, and a
-            # cross-attended encoder output may not be closed over by a
-            # custom VJP (it carries gradient) — both fall back to stored.
-            return _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm)
+    route = pool_route(pool.stack, comm, serving=caches is not None,
+                       enc_out=ctx.enc_out is not None)
+    if route == "host":
+        return _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm)
+    if route == "remat":
+        return _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm)
+    if route == "stored":
         return _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches)
     return _apply_pool_serial(pool, flat_rows, x, ctx, comm, caches)
 
@@ -219,7 +264,7 @@ def _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches):
 
 def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
     """Double-buffered prefetch with a rematerialized backward residual
-    (``GatherPolicy.prefetch_carry='remat'``).
+    (the ``'remat'`` route of :func:`pool_route`).
 
     The forward is the *same* double-buffered scan as
     :func:`_apply_pool_prefetch` — same gathers on the same shards in the
@@ -238,9 +283,9 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
     flat_len) carry residual (DESIGN.md §4, core/memplan.py).
 
     Cache-carrying (serving) and encoder-output-consuming pools never take
-    this path (:func:`_apply_pool` falls back): serving has no backward,
-    and ``ctx.enc_out`` carries gradient that a custom VJP closure would
-    silently drop.
+    this path (:func:`pool_route` sends them to the stored carry): serving
+    has no backward, and ``ctx.enc_out`` carries gradient that a custom VJP
+    closure would silently drop.
     """
     seed = ctx.step_seed
 
@@ -320,7 +365,7 @@ def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
     *identical* bytes the forward computed, and pushes the full-buffer
     cotangent through :meth:`CommEngine.gather_flat_adjoint` — the exact
     same staged hop-1 reduce-scatter adjoint the stored schedule's VJP
-    runs, so gradients too are bitwise identical to ``'stored'``.
+    runs, so gradients too are bitwise identical to the stored carry's.
 
     Versus the alternatives: no re-gather per layer (unlike ``'remat'``),
     no O(layers x flat_len) HBM residual (unlike ``'stored'``); the cost
@@ -458,7 +503,7 @@ def forward(
         enc_x = encode_audio(model, t_embed, batch["audio"], ctx)
         enc_ctx = dataclasses.replace(ctx, mode="train", pos=None)
         for pool in model.pools:
-            if not pool.name.startswith("enc"):
+            if not is_encoder_pool(cfg, pool.name):
                 continue
             enc_x, aux, _ = _apply_pool(
                 pool, flat[pool.name], enc_x, enc_ctx, comm, None)
@@ -470,7 +515,7 @@ def forward(
 
     x = embed_tokens(model, t_embed, batch["tokens"], ctx, pos=ctx.pos)
     for pool in model.pools:
-        if cfg.family == "encdec" and pool.name.startswith("enc"):
+        if is_encoder_pool(cfg, pool.name):
             continue
         pool_cache = caches.get(pool.name) if caches is not None else None
         x, aux, nc = _apply_pool(

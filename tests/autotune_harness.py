@@ -15,8 +15,13 @@ Checks:
                         per-stage wire bytes within 2% (padding is already
                         in flat_len, so in practice they match exactly),
                         collective counts exactly equal
-  census_match_prefetch the double-buffered schedule's counts
-                        (s*stack + 1 gathers, s*(stack+1) adjoints)
+  census_match_prefetch the double-buffered schedule's counts under each
+                        carry: remat, the training default (2*s*stack + 1
+                        gathers, s*stack adjoints), stored, which enc-dec
+                        decoder pools take (s*stack + 1 gathers,
+                        s*(stack+1) adjoints; forced here by
+                        harness_util.stored_carry), and host
+                        (s*stack + 1 gathers, s*stack adjoints)
   census_match_multi    multi-axis ('pod','shard') partition group: the
                         outer stage is the pod hop, bytes match both stage
                         orders
@@ -41,11 +46,13 @@ os.environ["XLA_FLAGS"] = (
     + os.environ.get("XLA_FLAGS", "")
 )
 
+import contextlib
 import json
 import traceback
 
 import jax.numpy as jnp
 
+from harness_util import stored_carry
 from repro.configs import get_config, smoke_variant
 from repro.core.autotune import compare_census, predict_traffic, resolve_config
 from repro.core.comm import GatherPolicy, SyncPolicy
@@ -84,13 +91,14 @@ def check(name):
 
 
 def _mcfg(topology: str, wire: str, prefetch: bool = False,
-          hop1: str = "fp32") -> MiCSConfig:
+          hop1: str = "fp32", carry_offload: str = "none") -> MiCSConfig:
     return MiCSConfig(
         micro_steps=MICRO,
         hierarchical=topology != "flat",
         gather_order=topology if topology != "flat" else "inner_first",
         prefetch=prefetch,
         hop1_wire_dtype=hop1,
+        carry_offload=carry_offload,
         **_WIRE_MCFG[wire],
     )
 
@@ -108,12 +116,12 @@ def _measure(model, topo, mcfg, *, global_batch=16, seq=16):
 
 
 def _assert_match(model, topo, topology, wire, *, prefetch=False,
-                  hop1="fp32", tag=""):
-    mcfg = _mcfg(topology, wire, prefetch, hop1)
+                  hop1="fp32", carry_offload="none", tag=""):
+    mcfg = _mcfg(topology, wire, prefetch, hop1, carry_offload)
     measured = _measure(model, topo, mcfg)["by_stage"]
     pred = predict_traffic(
         model, topo,
-        GatherPolicy(topology, wire, None, prefetch),
+        GatherPolicy(topology, wire, None, prefetch, carry_offload),
         SyncPolicy(hop1_wire_dtype=hop1),
         micro_steps=MICRO, upcast_float_collectives=True,
     )["by_stage"]
@@ -154,8 +162,14 @@ def _census_single():
 @check("census_match_prefetch")
 def _census_prefetch():
     model, topo = _single_axis()
-    detail = _assert_match(model, topo, "inner_first", "bf16",
-                           prefetch=True, tag="prefetch")
+    detail = {}
+    for carry in ("remat", "stored", "host"):
+        with stored_carry() if carry == "stored" \
+                else contextlib.nullcontext():
+            detail[carry] = _assert_match(
+                model, topo, "inner_first", "bf16", prefetch=True,
+                carry_offload="host" if carry == "host" else "none",
+                tag=f"prefetch/{carry}")
     RESULTS["census_match_prefetch_detail"] = detail
 
 

@@ -129,22 +129,18 @@ def _tree_equal(a, b, msg=""):
 
 # ---------------------------------------------------------------------------
 # budget: picked so the §3.1 rule has a real decision to make — p=2 with the
-# stored carry fits in BOTH worlds (8 and 4 devices at tp=2), while p=1
-# overflows under every carry mitigation.  Computed from the footprint
-# model, never hardcoded.
+# default carry fits in BOTH worlds (8 and 4 devices at tp=2), while p=1
+# overflows under every carry.  Computed from the footprint model, never
+# hardcoded.
 # ---------------------------------------------------------------------------
 
 def _pick_budget(model, mcfg, extents):
     gp, sp = policies_from_config(mcfg)
-    carries = ("stored", "remat", "host") if gp.prefetch else ("stored",)
+    carries = ("remat", "host") if gp.prefetch else ("remat",)
 
     def fp(p, extent, carry):
-        if carry == "host":
-            g2 = dataclasses.replace(
-                gp, prefetch_carry="stored", carry_offload="host")
-        else:
-            g2 = dataclasses.replace(
-                gp, prefetch_carry=carry, carry_offload="none")
+        g2 = dataclasses.replace(
+            gp, carry_offload="host" if carry == "host" else "none")
         grid = M.DeviceGrid(partition_size=p, replication_degree=extent // p)
         return M.predict_footprint(
             model, grid, g2, sp, micro_steps=mcfg.micro_steps,
@@ -152,7 +148,7 @@ def _pick_budget(model, mcfg, extents):
             hop2_bucket_mb=mcfg.hop2_bucket_mb,
             offload_opt=mcfg.offload_opt).total_bytes
 
-    need = max(fp(2, e, "stored") for e in extents)          # p=2 must fit
+    need = max(fp(2, e, "remat") for e in extents)           # p=2 must fit
     cap = min(fp(1, e, c) for e in extents for c in carries)  # p=1 must not
     assert need < cap, f"no separating budget: p2={need} p1={cap}"
     return (need + cap) / 2 / GIB, need / GIB, cap / GIB

@@ -279,10 +279,8 @@ def test_clip_and_carry_axes_ranked():
     assert {c.clip_mode for c in plan.candidates} == {"exact", "approx"}
     assert all(c.boundary == "bucketed" for c in plan.candidates
                if c.clip_mode == "approx")
-    carries = {(c.gather.prefetch_carry, c.gather.carry_offload)
-               for c in plan.candidates}
-    assert {("stored", "none"), ("remat", "none"),
-            ("stored", "host")} <= carries
+    carries = {c.gather.carry_offload for c in plan.candidates}
+    assert carries == {"none", "host"}
     # pairing each bucketed candidate with its approx twin: pipelining
     # AdamW under hop-2 can only shrink the exposed time, and does shrink
     # it somewhere in the grid
@@ -303,7 +301,7 @@ def test_clip_and_carry_axes_ranked():
     # both axes are visible columns in the ranked table
     txt = plan.table(top=None)
     head = txt.splitlines()[1]
-    assert "clip" in head and "carry" in head and "off" in head
+    assert "clip" in head and "carry" in head
     assert "approx" in txt and "host" in txt and "remat" in txt
 
 
@@ -321,7 +319,6 @@ def test_resolve_roundtrips_clip_and_offload():
     assert resolved.policy == "manual"
     assert resolved.clip_mode == plan.chosen.clip_mode
     assert resolved.carry_offload == plan.chosen.gather.carry_offload
-    assert resolved.prefetch_carry == plan.chosen.gather.prefetch_carry
     assert resolved.boundary_schedule == plan.chosen.boundary
     if resolved.clip_mode == "approx":
         assert resolved.boundary_schedule == "bucketed"

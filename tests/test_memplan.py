@@ -7,8 +7,8 @@ predicted-vs-compiled property runs through the 8-virtual-device subprocess
 harness (tests/memplan_harness.py), which is also the CI smoke gate.
 
 Degenerate cases covered per the ISSUE: a single-device mesh, a partition
-group spanning the whole world, ``prefetch_carry='remat'`` bitwise-equal
-losses vs ``'stored'`` (harness), and a budget smaller than any candidate
+group spanning the whole world, the remat carry's bitwise-equal losses vs
+the stored carry (harness), and a budget smaller than any candidate
 (a clear :class:`MemoryBudgetError`, never a silent empty plan).
 """
 
@@ -17,7 +17,7 @@ import pathlib
 
 import pytest
 
-from harness_util import run_harness
+from harness_util import run_harness, stored_carry
 from repro.core import memplan as M
 from repro.core.autotune import rank_policies, resolve_config, resolve_scale
 from repro.core.comm import GatherPolicy, SyncPolicy
@@ -92,15 +92,17 @@ def topo_single(p=16, repl=2):
 
 def test_footprint_components_and_ordering():
     model = StubModel()
-    gp_stored = GatherPolicy(prefetch=True)
-    gp_remat = GatherPolicy(prefetch=True, prefetch_carry="remat")
+    gp = GatherPolicy(prefetch=True)
     gp_serial = GatherPolicy(prefetch=False)
     sp = SyncPolicy()
     grid = DeviceGrid(partition_size=4, replication_degree=2)
+    with stored_carry():
+        stored = predict_footprint(model, grid, gp, sp, micro_steps=2)
     plans = {
-        name: predict_footprint(model, grid, g, sp, micro_steps=2)
-        for name, g in (("stored", gp_stored), ("remat", gp_remat),
-                        ("serial", gp_serial))
+        "stored": stored,
+        "remat": predict_footprint(model, grid, gp, sp, micro_steps=2),
+        "serial": predict_footprint(model, grid, gp_serial, sp,
+                                    micro_steps=2),
     }
     # the carry ordering the planner exists to price
     assert plans["stored"].total_bytes > plans["remat"].total_bytes \
@@ -145,10 +147,10 @@ def test_footprint_degenerate_grids():
 
 
 def test_footprint_encdec_decoder_pools_price_stored_carry():
-    """models/lm.py falls back to the stored carry for enc-dec *decoder*
-    pools even under remat (a custom VJP may not close over the
-    gradient-carrying encoder output); the planner must price them as
-    stored so the budget gate never under-predicts."""
+    """models/lm.py routes enc-dec *decoder* pools to the stored carry (a
+    custom VJP may not close over the gradient-carrying encoder output);
+    the planner must price them as stored so the budget gate never
+    under-predicts."""
     class EncDecModel:
         class cfg:  # noqa: D106 - duck-typed ArchConfig surface
             family = "encdec"
@@ -172,11 +174,11 @@ def test_footprint_encdec_decoder_pools_price_stored_carry():
             return dict(self._shapes)
 
     grid, sp = DeviceGrid(4, 2), SyncPolicy()
-    stored = predict_footprint(EncDecModel(), grid,
-                               GatherPolicy(prefetch=True), sp)
-    remat = predict_footprint(
-        EncDecModel(), grid,
-        GatherPolicy(prefetch=True, prefetch_carry="remat"), sp)
+    with stored_carry():
+        stored = predict_footprint(EncDecModel(), grid,
+                                   GatherPolicy(prefetch=True), sp)
+    remat = predict_footprint(EncDecModel(), grid,
+                              GatherPolicy(prefetch=True), sp)
     s_carry = stored.components["prefetch_carry"]
     r_carry = remat.components["prefetch_carry"]
     # remat only relieves the encoder pool; the decoder half stays stored
@@ -223,7 +225,7 @@ def test_min_partition_size_picks_minimal():
     assert need[2] > need[4] + 1  # the budget really excludes p=2
     p, carry, plan = min_partition_size(
         model, data_extent=16, hbm_budget_gb=budget_gb)
-    assert p == 4 and carry == "stored"
+    assert p == 4 and carry == "remat"
     assert plan.total_bytes <= budget_gb * GIB
 
 
@@ -235,19 +237,18 @@ def test_min_partition_size_remat_rescues_smaller_group():
     model = StubModel()
     gp = GatherPolicy(prefetch=True)
     sp = SyncPolicy()
-    stored4 = predict_footprint(model, DeviceGrid(4, 4), gp, sp).total_bytes
-    remat4 = predict_footprint(
-        model, DeviceGrid(4, 4),
-        dataclasses.replace(gp, prefetch_carry="remat"), sp).total_bytes
+    with stored_carry():
+        stored4 = predict_footprint(
+            model, DeviceGrid(4, 4), gp, sp).total_bytes
+        p_stored_only, _, _ = min_partition_size(
+            model, data_extent=16, hbm_budget_gb=(stored4 - 1) / GIB)
+    remat4 = predict_footprint(model, DeviceGrid(4, 4), gp, sp).total_bytes
     assert remat4 < stored4
     budget_gb = (remat4 + stored4) / 2 / GIB
     p, carry, _plan = min_partition_size(
-        model, data_extent=16, hbm_budget_gb=budget_gb,
-        carries=("stored", "remat"))
-    p_stored_only, carry_stored, _ = min_partition_size(
         model, data_extent=16, hbm_budget_gb=budget_gb)
     assert (p, carry) == (4, "remat")
-    assert carry_stored == "stored" and p_stored_only > p
+    assert p_stored_only > p
 
 
 def test_min_partition_size_budget_too_small_is_clear_error():
@@ -269,25 +270,25 @@ def test_rank_policies_prices_memory():
     assert all(c.mem_bytes > 0 for c in plan.candidates)
     assert "mem_GB" in plan.table()
     assert plan.hbm_budget_gb is None
-    # without a budget the grid has no remat rows (pure cost, never wins)
-    assert {c.gather.prefetch_carry for c in plan.candidates} == {"stored"}
+    # without a budget every row re-gathers (no host-offloaded rows)
+    assert {c.gather.carry_offload for c in plan.candidates} == {"none"}
 
 
 def test_rank_policies_budget_filters_and_falls_back_to_remat():
     model, topo = StubModel(), topo_single(p=4, repl=2)
-    free = rank_policies(model, topo, "v5e", micro_steps=2)
-    stored_best = free.chosen
-    # a budget below the stored footprint but above remat's forces the
-    # mitigation knob: remat is slower (one extra gather per layer) but fits
+    with stored_carry():
+        stored_best = rank_policies(model, topo, "v5e", micro_steps=2).chosen
+    # a budget below the stored footprint but above remat's: remat is
+    # slower (one extra gather per layer) but fits
     remat_plan = rank_policies(model, topo, "v5e", micro_steps=2,
                                hbm_budget_gb=1e6)  # effectively unlimited
     remat_rows = [c for c in remat_plan.candidates
-                  if c.gather.prefetch_carry == "remat"]
-    assert remat_rows, "budgeted ranking must include the remat axis"
+                  if c.gather.carry_offload == "none"]
+    assert remat_rows, "budgeted ranking must include the remat rows"
     budget_gb = (min(c.mem_bytes for c in remat_rows) + 1) / GIB
     gated = rank_policies(model, topo, "v5e", micro_steps=2,
                           hbm_budget_gb=budget_gb)
-    assert gated.chosen.gather.prefetch_carry == "remat"
+    assert gated.chosen.gather.carry_offload == "none"
     assert gated.chosen.mem_bytes <= budget_gb * GIB
     assert stored_best.mem_bytes > budget_gb * GIB
     assert gated.chosen.t_comm_s >= stored_best.t_comm_s
@@ -304,18 +305,18 @@ def test_resolve_config_applies_budget(topo1):
     remat_plan = rank_policies(model, topo, "v5e", micro_steps=2,
                                hbm_budget_gb=1e6)
     remat_rows = [c for c in remat_plan.candidates
-                  if c.gather.prefetch_carry == "remat"]
+                  if c.gather.carry_offload == "none"]
     budget_gb = (min(c.mem_bytes for c in remat_rows) + 1) / GIB
     mcfg = MiCSConfig(micro_steps=2, policy="auto", link_profile="v5e",
                       hbm_budget_gb=budget_gb)
     resolved, plan = resolve_config(mcfg, model, topo)
     assert plan.hbm_budget_gb == budget_gb
-    assert resolved.prefetch_carry == "remat"
+    assert resolved.carry_offload == "none"
     # and the resolved config reconstructs the chosen policy end to end
     from repro.core.comm import CommEngine
 
     eng = CommEngine.from_config(topo1, resolved)
-    assert eng.gather_policy.prefetch_carry == "remat"
+    assert eng.gather_policy.carry_offload == "none"
 
 
 def test_resolve_scale_minimal_group():
@@ -325,7 +326,7 @@ def test_resolve_scale_minimal_group():
         SyncPolicy()).total_bytes
     mcfg = MiCSConfig(micro_steps=1, hbm_budget_gb=(need4 + 1) / GIB)
     p, carry, plan = resolve_scale(model, mcfg, data_extent=16)
-    assert p == 4 and carry == "stored"
+    assert p == 4 and carry == "remat"
     with pytest.raises(ValueError):
         resolve_scale(model, MiCSConfig(), data_extent=16)
     with pytest.raises(MemoryBudgetError):
@@ -335,11 +336,11 @@ def test_resolve_scale_minimal_group():
 
 def test_config_validation():
     with pytest.raises(ValueError):
-        MiCSConfig(prefetch_carry="offload")
+        MiCSConfig(carry_offload="offload")
     with pytest.raises(ValueError):
         MiCSConfig(hbm_budget_gb=0.0)
     with pytest.raises(ValueError):
-        GatherPolicy(prefetch_carry="none")
+        GatherPolicy(carry_offload="nvme")
 
 
 # ---------------------------------------------------------------------------

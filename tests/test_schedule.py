@@ -52,9 +52,7 @@ def test_clip_offload_config_validated():
         MiCSConfig(carry_offload="nvme")
     with pytest.raises(ValueError):   # host carry offloads the stored carry
         MiCSConfig(carry_offload="host", prefetch=False)
-    with pytest.raises(ValueError):
-        MiCSConfig(carry_offload="host", prefetch_carry="remat")
-    MiCSConfig(carry_offload="host", prefetch=True, prefetch_carry="stored")
+    MiCSConfig(carry_offload="host", prefetch=True)
     with pytest.raises(ValueError):
         BoundaryPlan(mode="bucketed", bucket_mb=1.0, shard_elems={},
                      buckets=(), clip_mode="stale")
