@@ -4,6 +4,7 @@ from repro.configs.base import ArchConfig, smoke_variant
 from repro.configs.bert_paper import PAPER_CONFIGS
 from repro.configs.dbrx_132b import CONFIG as DBRX_132B
 from repro.configs.deepseek_moe_16b import CONFIG as DEEPSEEK_MOE_16B
+from repro.configs.deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 from repro.configs.granite_8b import CONFIG as GRANITE_8B
 from repro.configs.llama3_2_1b import CONFIG as LLAMA3_2_1B
 from repro.configs.llama_3_2_vision_90b import CONFIG as LLAMA_3_2_VISION_90B
@@ -28,6 +29,9 @@ ASSIGNED = (
 
 REGISTRY: dict[str, ArchConfig] = {c.name: c for c in ASSIGNED}
 REGISTRY.update(PAPER_CONFIGS)
+# trains on the chip (chipbench); not an assignment cell, whose serve shapes
+# MLA does not run yet
+REGISTRY[DEEPSEEK_V2_LITE.name] = DEEPSEEK_V2_LITE
 
 
 def get_config(name: str) -> ArchConfig:

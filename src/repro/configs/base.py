@@ -32,11 +32,28 @@ class ArchConfig:
     tie_embeddings: bool = False
 
     # --- MoE ---------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0           # routed experts the router scores
+    experts_held: int = 0        # of them, experts 0..held-1 live here (0 = all)
     n_shared_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
     router_aux_weight: float = 0.01
+    first_dense_layers: int = 0  # leading dense layers (first_k_dense_replace)
+    dense_d_ff: int = 0          # their MLP width (0 -> d_ff)
+
+    # --- latent attention (MLA; kv_lora_rank 0 = standard attention) --------
+    kv_lora_rank: int = 0        # width of the joint k/v latent
+    qk_nope_head_dim: int = 0    # per-head query/key width without rotation
+    qk_rope_head_dim: int = 0    # rotated width, the key part shared by heads
+    v_head_dim: int = 0
+
+    # --- YaRN rope scaling (yarn_factor 0 = plain rope) ----------------------
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- griffin / local attention ------------------------------------------
     window: int = 0              # local-attention window (0 = full)
@@ -67,6 +84,16 @@ class ArchConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this model holds (the first ones)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def moe_layers(self) -> int:
+        """Layers with routed experts: all but the leading dense ones."""
+        return self.n_layers - self.first_dense_layers if self.n_experts else 0
+
     def param_count(self) -> int:
         """Approximate total parameter count (for partition-size heuristic and
         MODEL_FLOPS).  Computed from the layout builders, so exact: see
@@ -90,7 +117,14 @@ def smoke_variant(cfg: ArchConfig) -> ArchConfig:
         max_seq=128,
     )
     if cfg.family == "moe":
-        kw.update(n_experts=8, top_k=2, n_shared_experts=cfg.n_shared_experts, d_ff=32)
+        # half of the 8 experts held, as a chip holds its share of them
+        kw.update(n_experts=8, experts_held=4, top_k=2, d_ff=32,
+                  first_dense_layers=min(cfg.first_dense_layers, 1),
+                  dense_d_ff=128 if cfg.dense_d_ff else 0)
+    if cfg.kv_lora_rank:
+        # the published MLA widths' ratios (512 : 128 : 64 : 128), at 1/16
+        kw.update(kv_lora_rank=32, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                  v_head_dim=8, head_dim=12)
     if cfg.family == "griffin":
         kw.update(window=32, lru_width=64, n_layers=min(cfg.n_layers, 6))
     if cfg.family == "xlstm":

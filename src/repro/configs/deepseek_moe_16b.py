@@ -1,7 +1,9 @@
 """deepseek-moe-16b — fine-grained MoE: 2 shared + 64 routed experts, top-6.
 
 [arXiv:2401.06066; hf:deepseek-ai/deepseek-moe-16b-base]
-28L d_model=2048 16H (MHA kv=16) per-expert d_ff=1408 vocab=102400.
+28L d_model=2048 16H (MHA kv=16) per-expert d_ff=1408 vocab=102400; the
+first layer is dense (SwiGLU 10944, first_k_dense_replace 1) and the top-6
+gates are not renormalised (norm_topk_prob false).
 """
 
 from repro.configs.base import ArchConfig
@@ -21,6 +23,9 @@ CONFIG = ArchConfig(
     n_experts=64,
     n_shared_experts=2,
     top_k=6,
+    norm_topk_prob=False,
+    first_dense_layers=1,
+    dense_d_ff=10944,
     max_seq=32768,
     notes="experts sharded over the model axis (EP=16, 4 experts/rank); "
           "full attention -> long_500k skipped",

@@ -257,7 +257,7 @@ def predict_footprint(
     Host offload shifts bytes out of this budget: with
     ``gather.carry_offload='host'`` the stored prefetch carry's
     O(stack x flat_len) residual leaves HBM (only the rotated shard copy
-    and a transient full buffer remain, same as remat), and with
+    and a transient full buffer remain; remat keeps only the latter), and with
     ``offload_opt=True`` the fp32 ``m``/``v`` shards leave the donated
     arguments entirely (2 x state shard bytes), replaced by a transient
     staging term for the shards streamed back during the boundary.  The
@@ -355,11 +355,12 @@ def predict_footprint(
     add("gather_adjoint", max_flat * 4)
 
     # -- prefetch-carry backward residual, per pool route (models/lm.py) --
-    # remat: the rotated shard copy + one transient re-gathered buffer.
-    # Host offload prices the same: the stacked residual streams to host
-    # memory.  stored (enc-dec decoder pools, which read the encoder
-    # output): the stacked carried buffer persists at fp32 (observed: the
-    # adjoint accumulation dtype) + the rotated shard copy.
+    # remat: one transient re-gathered buffer (its forward reads the
+    # lookahead row in place).  Host offload: that plus the rotated shard
+    # copy its forward scans; the stacked residual streams to host memory.
+    # stored (enc-dec decoder pools, which read the encoder output): the
+    # stacked carried buffer persists at fp32 (observed: the adjoint
+    # accumulation dtype) + the rotated shard copy.
     from repro.models.lm import train_route
 
     cfg = getattr(model, "cfg", None)
@@ -372,8 +373,10 @@ def predict_footprint(
         rolled = stack * math.ceil(flat_len / p) * 4
         if route == "stored":
             add("prefetch_carry", stack * flat_len * 4 + rolled)
-        else:
+        elif route == "host":
             add("prefetch_carry", rolled + flat_len * cb)
+        else:
+            add("prefetch_carry", flat_len * cb)
 
     # -- activation checkpoints + logits/CE workspace ----------------------
     if local_batch and seq and cfg is not None:
@@ -384,6 +387,12 @@ def predict_footprint(
         tp = max(int(getattr(model, "tp", 1)), 1)
         vocab = int(getattr(model, "vocab_padded", cfg.vocab))
         add("logits_ce", local_batch * seq * (vocab // tp) * 8)
+        if getattr(cfg, "moe_layers", 0):
+            # one expert layer's dropless buffers: the tokens' top-k rows in
+            # expert order and their outputs [n*k, d], the gate and up
+            # products and their product [n*k, f] (models/blocks.moe_routed)
+            add("expert_rows", local_batch * seq * cfg.top_k
+                * (2 * cfg.d_model + 3 * cfg.d_ff) * cb)
 
     # -- hop-2 staging (replication-group boundary) ------------------------
     if repl > 1 and sync.mode == "2hop":

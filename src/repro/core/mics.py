@@ -231,6 +231,12 @@ def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0, *,
     them — observed doubling every parameter on CPU meshes with a
     replication axis (pod/repl > 1).  device_put is exact and makes the
     initial state a pure function of (model, seed), independent of topology.
+
+    ``device_put`` cuts one slice per target device on the source device
+    before it copies them.  The pools are placed one at a time, each copy
+    finished and its one-device source dropped before the next, so the
+    first device never holds more than one pool's slices.  The moments
+    are zeros and are made in place: zeros are exact under any partitioning.
     """
     shapes = model.global_flat_shapes()
     shardings = state_shardings(model, topo, offload_opt=offload_opt)
@@ -249,16 +255,23 @@ def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0, *,
             rows = [lax.optimization_barrier(pool.layout.init_flat(k))
                     for k in jax.random.split(pool_key, stack * tp)]
             flat[pool.name] = jnp.stack(rows).reshape(stack, tp, -1)
-        out = {"params": flat, "step": jnp.int32(0)}
-        if not offload_opt:
-            # Offloaded moments zero-init lazily in the host stash instead
-            # (HostStash.get(..., or_zeros=True) on first boundary).
-            out["m"] = jax.tree.map(jnp.zeros_like, flat)
-            out["v"] = jax.tree.map(jnp.zeros_like, flat)
-        return out
+        return flat
 
-    state = jax.jit(_init)(jax.random.key(seed))
-    return jax.device_put(state, shardings)
+    drawn = jax.jit(_init)(jax.random.key(seed))
+    params = {name: jax.block_until_ready(
+        jax.device_put(drawn.pop(name), shardings["params"][name]))
+        for name in list(drawn)}
+    out = {"params": params,
+           "step": jax.device_put(jnp.int32(0), shardings["step"])}
+    if not offload_opt:
+        # Offloaded moments zero-init lazily in the host stash instead
+        # (HostStash.get(..., or_zeros=True) on first boundary).
+        zeros = jax.jit(
+            lambda: {name: jnp.zeros(shape, jnp.float32)
+                     for name, shape in shapes.items()},
+            out_shardings=shardings["params"])
+        out["m"], out["v"] = zeros(), zeros()
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -290,6 +303,7 @@ def build_train_step(
                 scores_bf16=mcfg.scores_bf16, mlstm_chunk=mcfg.mlstm_chunk)
     s = mcfg.micro_steps
     denom = float(s * topo.data_parallel_size)
+    counters = lm.counters(model)
 
     def loss_of(flat, micro_batch, step_ctx):
         return lm.loss_fn(model, flat, comm, step_ctx, micro_batch)
@@ -302,19 +316,21 @@ def build_train_step(
         step_ctx = dataclasses.replace(ctx, step_seed=state["step"])
 
         def micro(carry, mb):
-            grads_acc, loss_acc, aux_acc = carry
+            grads_acc, loss_acc, aux_acc, counts = carry
             (loss, metrics), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 params, mb, step_ctx)
             with jax.named_scope(scopes.GRAD_ACCUM):
                 grads_acc = jax.tree.map(
                     lambda a, g: a + g.astype(jnp.float32), grads_acc, grads)
+            counts = {k: counts[k] + metrics[k] for k in counts}
             return (grads_acc, loss_acc + metrics["loss"],
-                    aux_acc + metrics["aux"]), None
+                    aux_acc + metrics["aux"], counts), None
 
         with jax.named_scope(scopes.GRAD_ACCUM):
             zeros = jax.tree.map(jnp.zeros_like, params)
-        (grads, loss_sum, aux_sum), _ = lax.scan(
-            micro, (zeros, jnp.float32(0.0), jnp.float32(0.0)), batch)
+        (grads, loss_sum, aux_sum, count_sums), _ = lax.scan(
+            micro, (zeros, jnp.float32(0.0), jnp.float32(0.0),
+                    {k: jnp.float32(0.0) for k in counters}), batch)
 
         # ---- boundary: hop 2 + exact clip + AdamW (core/schedule.py) ------
         # Serial reference or the bucketed software pipeline; bitwise
@@ -330,6 +346,8 @@ def build_train_step(
                 "loss": lax.pmean(loss_sum / s, topo.data_axes),
                 "aux": lax.pmean(aux_sum / s, topo.data_axes),
                 "grad_norm": gnorm,
+                **{k: lax.pmean(v / s, topo.data_axes)
+                   for k, v in count_sums.items()},
             }
         new_state = {"params": new_params, "step": step + 1}
         if not mcfg.offload_opt:
@@ -338,10 +356,11 @@ def build_train_step(
 
     st_specs = state_pspecs(model, topo, offload_opt=mcfg.offload_opt)
     b_specs = batch_pspecs(model, topo)
+    m_specs = {k: P() for k in ("loss", "aux", "grad_norm", *counters)}
     sharded = shard_map(
         sharded_step, mesh=topo.mesh,
         in_specs=(st_specs, b_specs),
-        out_specs=(st_specs, {"loss": P(), "aux": P(), "grad_norm": P()}),
+        out_specs=(st_specs, m_specs),
         check_vma=False,
     )
     ns = lambda spec: jax.tree.map(
@@ -350,8 +369,7 @@ def build_train_step(
     step_fn = jax.jit(
         sharded,
         in_shardings=(ns(st_specs), ns(b_specs)),
-        out_shardings=(ns(st_specs),
-                       ns({"loss": P(), "aux": P(), "grad_norm": P()})),
+        out_shardings=(ns(st_specs), ns(m_specs)),
         donate_argnums=(0,),
     )
     return step_fn

@@ -1,5 +1,6 @@
 """Transformer layer types: dense (llama/qwen/granite/yi), cross-attention
-(llama-3.2-vision), encoder/decoder (whisper), and MoE (deepseek, dbrx).
+(llama-3.2-vision), encoder/decoder (whisper), MoE (deepseek, dbrx), and
+multi-head latent attention (deepseek-v2).
 
 Each layer type provides
   * ``*_layout(cfg, tp, b)``   — appends its segments to a LayoutBuilder
@@ -279,6 +280,106 @@ def make_cross_cache(cfg: ArchConfig, tp: int, batch: int, src_len: int):
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2 §2.1), train path
+# ---------------------------------------------------------------------------
+
+def mla_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder, prefix: str = "attn."):
+    """Heads are split over the model axis; the joint down projection and
+    the latent's norm are used whole by every head, so they are stored
+    sharded and gathered at use, like the router."""
+    d, h = cfg.d_model, shard_dim(cfg.n_heads, tp, "n_heads")
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    dr, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
+    std = 1.0 / math.sqrt(d)
+    out_std = 1.0 / math.sqrt(cfg.n_heads * dv) / math.sqrt(2 * cfg.n_layers)
+    b.add(prefix + "wq", (d, h * (dn + dr)), std=std)
+    b.add(prefix + "wkv_a", (d, shard_dim(r + dr, tp, "kv_lora_rank + rope")),
+          std=std, model_gather=tp, model_gather_dim=1)
+    b.add(prefix + "kv_norm.scale", (shard_dim(r, tp, "kv_lora_rank"),),
+          init="zeros", decay=False, model_gather=tp, model_gather_dim=0)
+    b.add(prefix + "wkv_b", (r, h * (dn + dv)), std=1.0 / math.sqrt(r))
+    b.add(prefix + "wo", (h * dv, d), std=out_std)
+
+
+def mla_scale(cfg: ArchConfig) -> float:
+    """The score scale: qk head size^-1/2, times YaRN's mscale squared
+    (DeepSeek-V2's ``softmax_scale``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ArchConfig, x, positions):
+    """Rotary over the rope part [b, t, h, dr], YaRN-scaled where set."""
+    if not cfg.yarn_factor:
+        return L.rotary(x, positions, cfg.rope_theta)
+    inv = L.yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn_factor,
+                          cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                          cfg.yarn_beta_slow)
+    out = L.rotary(x, positions, cfg.rope_theta, inv)
+    m = (L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+         / L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return out if m == 1.0 else (out.astype(jnp.float32) * m).astype(out.dtype)
+
+
+@jax.named_scope(scopes.ATTENTION)
+def mla_attention(t, x, ctx: L.Ctx, cfg: ArchConfig, prefix: str = "attn."):
+    """Causal multi-head latent attention over a whole sequence (training).
+
+    q = x Wq ([nope | rope] per head); [c | k_rope] = x Wkv_a, the latent c
+    RMS-normed, [k_nope | v] = c Wkv_b per head; the rope key is one for all
+    heads.  Scores over [nope | rope] (192 wide in DeepSeek-V2), values of
+    ``v_head_dim``.  Rope uses the rotate-half layout.
+    """
+    if ctx.mode != "train":
+        raise NotImplementedError(
+            "MLA runs the train path only: prefill and decode need its "
+            "latent cache, which the serving path does not have")
+    bsz, tq, _ = x.shape
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    dr, dv = cfg.qk_rope_head_dim, cfg.v_head_dim
+    h = t[prefix + "wq"].shape[1] // (dn + dr)
+    q = (x @ t[prefix + "wq"]).reshape(bsz, tq, h, dn + dr)
+    kv = x @ t[prefix + "wkv_a"]
+    latent = L.rms_norm(kv[..., :r], t[prefix + "kv_norm.scale"])
+    kvb = (latent @ t[prefix + "wkv_b"]).reshape(bsz, tq, h, dn + dv)
+    positions = jnp.broadcast_to(jnp.arange(tq), (bsz, tq))
+    q_rope = _mla_rope(cfg, q[..., dn:], positions)
+    k_rope = _mla_rope(cfg, kv[:, :, None, r:], positions)
+    q = jnp.concatenate([q[..., :dn], q_rope], -1)
+    k = jnp.concatenate(
+        [kvb[..., :dn], jnp.broadcast_to(k_rope, (bsz, tq, h, dr))], -1)
+    out = L.attention(q[:, :, :, None], k, kvb[..., dn:], scale=mla_scale(cfg),
+                      causal=True, scores_dtype=ctx.scores_dtype)
+    out = out.reshape(bsz, tq, h * dv) @ t[prefix + "wo"]
+    return L.tp_psum(out, ctx)
+
+
+def no_latent_cache(*_):
+    raise NotImplementedError(
+        "MLA has no decode cache yet: its latent cache is not built")
+
+
+def self_attn_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder):
+    """A layer's self attention: MLA where ``kv_lora_rank`` is set."""
+    if cfg.kv_lora_rank:
+        mla_layout(cfg, tp, b)
+    else:
+        attn_layout(cfg, tp, b, "attn.", bias=cfg.qkv_bias)
+
+
+def self_attn(cfg: ArchConfig, ad: AttnDims, t, x, ctx: L.Ctx, cache=None, *,
+              window: int = 0, causal: bool = True):
+    """The self attention ``self_attn_layout`` laid out: (out, new_cache)."""
+    if cfg.kv_lora_rank:
+        return mla_attention(t, x, ctx, cfg), None
+    return self_attention(
+        t, x, ctx, ad, cfg, prefix="attn.", causal=causal, window=window,
+        use_rope=cfg.use_rope, bias=cfg.qkv_bias, cache=cache)
+
+
+# ---------------------------------------------------------------------------
 # norms + MLP sub-blocks
 # ---------------------------------------------------------------------------
 
@@ -333,12 +434,13 @@ def mlp_apply(cfg: ArchConfig, t, x, ctx: L.Ctx, prefix: str = "mlp."):
 # dense decoder layer (llama / qwen / granite / yi family)
 # ---------------------------------------------------------------------------
 
-def dense_layer_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder, prefix: str = ""):
+def dense_layer_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder, prefix: str = "",
+                       d_ff: int | None = None):
     pb = LayoutBuilder(prefix)
     norm_layout(cfg, tp, pb, "ln1")
-    attn_layout(cfg, tp, pb, "attn.", bias=cfg.qkv_bias)
+    self_attn_layout(cfg, tp, pb)
     norm_layout(cfg, tp, pb, "ln2")
-    mlp_layout(cfg, tp, pb, "mlp.")
+    mlp_layout(cfg, tp, pb, "mlp.", d_ff=d_ff)
     b.extend(pb)
 
 
@@ -347,11 +449,8 @@ def dense_layer_apply(cfg: ArchConfig, ad: AttnDims, t, x, ctx: L.Ctx,
                       causal: bool = True):
     tt = {name[len(prefix):]: v for name, v in t.items()} if prefix else t
     h = apply_norm(cfg, tt, x, "ln1")
-    a, new_cache = self_attention(
-        tt, h, ctx, ad, cfg, prefix="attn.", causal=causal, window=window,
-        use_rope=cfg.use_rope, bias=cfg.qkv_bias,
-        cache=cache,
-    )
+    a, new_cache = self_attn(cfg, ad, tt, h, ctx, cache, window=window,
+                             causal=causal)
     x = x + a
     h = apply_norm(cfg, tt, x, "ln2")
     x = x + mlp_apply(cfg, tt, h, ctx, "mlp.")
@@ -426,19 +525,22 @@ def encdec_dec_apply(cfg: ArchConfig, ad: AttnDims, t, x, ctx: L.Ctx,
 
 
 # ---------------------------------------------------------------------------
-# MoE layer (deepseek-moe / dbrx)
+# MoE layer (deepseek-moe / deepseek-v2 / dbrx)
 # ---------------------------------------------------------------------------
 
 def moe_layer_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder, prefix: str = ""):
+    """The router scores all ``n_experts``; the layer holds the first
+    ``held_experts`` of them, split over the model axis."""
     pb = LayoutBuilder(prefix)
     norm_layout(cfg, tp, pb, "ln1")
-    attn_layout(cfg, tp, pb, "attn.", bias=cfg.qkv_bias)
+    self_attn_layout(cfg, tp, pb)
     norm_layout(cfg, tp, pb, "ln2")
     d, f = cfg.d_model, cfg.d_ff
-    e_local = shard_dim(cfg.n_experts, tp, "n_experts")
+    e_local = shard_dim(cfg.held_experts, tp, "held experts")
     std = 1.0 / math.sqrt(d)
     dstd = 1.0 / math.sqrt(f) / math.sqrt(2 * cfg.n_layers)
-    pb.add("router.w", (d, e_local), std=std, model_gather=tp, model_gather_dim=1)
+    pb.add("router.w", (d, shard_dim(cfg.n_experts, tp, "n_experts")), std=std,
+           model_gather=tp, model_gather_dim=1)
     pb.add("moe.wg", (e_local, d, f), std=std)
     pb.add("moe.wu", (e_local, d, f), std=std)
     pb.add("moe.wd", (e_local, f, d), std=dstd)
@@ -447,107 +549,82 @@ def moe_layer_layout(cfg: ArchConfig, tp: int, b: LayoutBuilder, prefix: str = "
     b.extend(pb)
 
 
-def _moe_dispatch_tokens(x2d, t, cfg: ArchConfig, ctx: L.Ctx):
-    """GShard-style capacity dispatch with expert parallelism over 'model'.
+def moe_routed(x2d, t, cfg: ArchConfig, ctx: L.Ctx):
+    """The routed experts' part of the layer that the experts held on this
+    model rank give, dropping no token.
 
-    x2d: [n, d] tokens.  Returns (out [n, d], aux_loss scalar).
+    x2d: [n, d] tokens.  Every token takes its top-k of all ``n_experts``
+    router outputs (softmax, then renormalised where ``norm_topk_prob``).
+    The n*k assignments are put in expert order, those to experts not held
+    here last, and the held experts run as grouped products over their own
+    rows (``lax.ragged_dot``): no capacity, no [experts, capacity, d]
+    buffer.  What absent experts would add is left out.  Returns (y [n, d],
+    aux [2]): the switch-style balance loss over all experts, and the held
+    experts' largest assignment count over their mean.
     """
     n, d = x2d.shape
-    e = cfg.n_experts
     k = cfg.top_k
-    cap = int(math.ceil(n * k / e * cfg.capacity_factor))
-    cap = max(4, ((cap + 3) // 4) * 4)
+    e_local = t["moe.wg"].shape[0]
+    with jax.named_scope(scopes.MOE_ROUTE):
+        logits = jnp.dot(x2d.astype(jnp.float32),
+                         t["router.w"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)       # [n, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = lax.top_k(probs, k)                         # [n, k]
+        if cfg.norm_topk_prob:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        slot = idx.reshape(-1) - ctx.tp_index() * e_local       # [n*k]
+        held = (slot >= 0) & (slot < e_local)
+        slot = jnp.where(held, slot, e_local)                   # absent: last
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.bincount(slot, length=e_local + 1)[:e_local].astype(jnp.int32)
+        rows = x2d[order // k]                                  # expert order
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        # The TPU's ragged dot leaves the rows past the groups undefined, in
+        # its transposes too: zero them, and so their cotangents.
+        live = (jnp.arange(n * k) < jnp.sum(sizes))[:, None]
 
-    logits = (x2d @ t["router.w"]).astype(jnp.float32)       # [n, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, k)                # [n, k]
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+        def grouped(a, w):
+            return jnp.where(live, lax.ragged_dot(a, w, sizes), 0)
 
-    flat_e = gate_idx.reshape(-1)                            # [n*k], token-major
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # [n*k, E]
-    pos_in_e = (jnp.cumsum(onehot, axis=0) - onehot)[jnp.arange(n * k), flat_e]
-    keep = (pos_in_e < cap).astype(x2d.dtype)                # capacity drop
-
-    # scatter tokens into [E, cap, d]
-    tok = jnp.repeat(x2d, k, axis=0) * keep[:, None]
-    buf = jnp.zeros((e, cap, d), x2d.dtype)
-    buf = buf.at[flat_e, jnp.clip(pos_in_e, 0, cap - 1)].add(tok)
-
-    # expert parallelism: ship expert slabs to their owner ranks
-    if ctx.tp > 1:
-        buf = lax.all_to_all(buf, ctx.tp_axis, split_axis=0, concat_axis=1, tiled=True)
-    # buf: [E_local, tp*cap, d]
-    h = jnp.einsum("ecd,edf->ecf", buf, t["moe.wg"])
-    u = jnp.einsum("ecd,edf->ecf", buf, t["moe.wu"])
-    h = jax.nn.silu(h) * u
-    out = jnp.einsum("ecf,efd->ecd", h, t["moe.wd"])
-    if ctx.tp > 1:
-        out = lax.all_to_all(out, ctx.tp_axis, split_axis=1, concat_axis=0, tiled=True)
-
-    # combine: gather each assignment's expert output, weight by gate
-    picked = out[flat_e, jnp.clip(pos_in_e, 0, cap - 1)]     # [n*k, d]
-    w = (gate_vals.reshape(-1) * keep).astype(picked.dtype)
-    y = jnp.sum((picked * w[:, None]).reshape(n, k, d), axis=1)
-
-    # switch-style load-balance loss
-    me = jnp.mean(probs, axis=0)                             # [E]
-    ce = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32), axis=0)
-    aux = e * jnp.sum(me * ce)
+        rows = jnp.where(live, rows, 0)
+        h = grouped(rows, t["moe.wg"])
+        u = grouped(rows, t["moe.wu"])
+        out = grouped(jax.nn.silu(h) * u, t["moe.wd"])
+    with jax.named_scope(scopes.MOE_ROUTE):
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+        w = jnp.where(held, gate.reshape(-1), 0.0).reshape(n, k)
+        y = jnp.einsum("nkd,nk->nd", out[back].reshape(n, k, d),
+                       w.astype(out.dtype),
+                       preferred_element_type=jnp.float32).astype(x2d.dtype)
+        e = probs.shape[-1]
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.mean(jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32), axis=0)
+        load = sizes.astype(jnp.float32)
+        aux = jnp.stack([e * jnp.sum(me * ce),
+                         jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9)])
     return y, aux
 
 
 @jax.named_scope(scopes.MLP)
 def moe_ffn(t, x, cfg: ArchConfig, ctx: L.Ctx):
-    """Token-parallel MoE: activations are replicated across the model axis,
-    so each rank routes only its 1/tp slice of the tokens (otherwise every
-    rank would redundantly dispatch identical copies — 16x wasted expert
-    FLOPs).  Outputs are re-assembled with an all-gather whose adjoint is a
-    reduce-scatter, keeping gradients exact.  Tiny token counts (decode)
-    fall back to the replicated path."""
+    """Routed experts plus shared experts.  Activations are whole on every
+    model rank, so each rank routes all tokens and runs its own experts;
+    the ranks' parts add up over the model axis (expert parallelism with no
+    token exchange).  Returns (out [b, s, d], aux [2], see moe_routed)."""
     b, s, d = x.shape
-    n = b * s
-    tp = ctx.tp
-    x2d = x.reshape(n, d)
-
-    shard_tokens = tp > 1 and n % tp == 0 and n >= tp
-    if shard_tokens:
-        n_local = n // tp
-        start = ctx.tp_index() * n_local
-        x2d = lax.dynamic_slice_in_dim(x2d, start, n_local, axis=0)
-        n = n_local
-
-    chunk = n
-    for cand in (4096, 2048, 1024):
-        if n > cand and n % cand == 0:
-            chunk = cand
-            break
-    x2 = x2d.reshape(n // chunk, chunk, d)
-
-    def body(aux, xc):
-        y, a = _moe_dispatch_tokens(xc, t, cfg, ctx)
-        return aux + a, y
-
-    aux, y = lax.scan(body, jnp.float32(0.0), x2)
-    aux = aux * (chunk / n)
-    y = y.reshape(n, d)
-    if shard_tokens:
-        y = lax.all_gather(y, ctx.tp_axis, axis=0, tiled=True)
-        aux = lax.pmean(aux, ctx.tp_axis)
-    out = y.reshape(b, s, d)
+    y, aux = moe_routed(x.reshape(b * s, d), t, cfg, ctx)
+    out = L.tp_psum(y, ctx).reshape(b, s, d)
     if cfg.n_shared_experts:
         out = out + mlp_apply(cfg, t, x, ctx, "shared.")
-    return out, aux * (chunk / n)
+    return out, aux
 
 
 def moe_layer_apply(cfg: ArchConfig, ad: AttnDims, t, x, ctx: L.Ctx,
                     cache=None, prefix: str = ""):
     tt = {name[len(prefix):]: v for name, v in t.items()} if prefix else t
     h = apply_norm(cfg, tt, x, "ln1")
-    a, new_cache = self_attention(
-        tt, h, ctx, ad, cfg, prefix="attn.", causal=True,
-        use_rope=cfg.use_rope, bias=cfg.qkv_bias, cache=cache,
-    )
+    a, new_cache = self_attn(cfg, ad, tt, h, ctx, cache)
     x = x + a
     h = apply_norm(cfg, tt, x, "ln2")
     y, aux = moe_ffn(tt, h, cfg, ctx)

@@ -67,14 +67,22 @@ def build_model(cfg: ArchConfig, tp: int) -> ModelDef:
         ))
 
     elif cfg.family == "moe":
+        # the leading dense layers, then the expert layers: a pool each
+        make_cache = B.no_latent_cache if cfg.kv_lora_rank else (
+            lambda bsz, clen: B.make_kv_cache(cfg, tp, bsz, clen))
+        if cfg.first_dense_layers:
+            b = LayoutBuilder()
+            B.dense_layer_layout(cfg, tp, b, d_ff=cfg.dense_d_ff or cfg.d_ff)
+            apply = _wrap(lambda t, x, ctx, cache: B.dense_layer_apply(
+                cfg, ad, t, x, ctx, cache))
+            pools.append(Pool("dense", b.build(), cfg.first_dense_layers,
+                              apply, make_cache=make_cache))
         b = LayoutBuilder()
         B.moe_layer_layout(cfg, tp, b)
         apply = _wrap(lambda t, x, ctx, cache: B.moe_layer_apply(
             cfg, ad, t, x, ctx, cache))
-        pools.append(Pool(
-            "layers", b.build(), cfg.n_layers, apply,
-            make_cache=lambda bsz, clen: B.make_kv_cache(cfg, tp, bsz, clen),
-        ))
+        pools.append(Pool("layers", b.build(), cfg.moe_layers, apply,
+                          make_cache=make_cache, aux_shape=(2,)))
 
     elif cfg.family == "vlm":
         n_self = cfg.cross_interval
@@ -242,7 +250,7 @@ def _counts(cfg: ArchConfig) -> tuple[int, int]:
         for seg in pool.layout.segments:
             n = seg.size * pool.stack
             total += n
-            if seg.name.split(".")[0] == "moe" or seg.name.startswith("moe."):
+            if seg.name.startswith("moe."):
                 active += int(n * cfg.top_k / max(cfg.n_experts, 1))
             else:
                 active += n

@@ -80,17 +80,45 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-
 # rotary position embedding
 # ---------------------------------------------------------------------------
 
-def rotary(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [b, t, h, dh]; positions: [b, t] absolute token positions."""
+def rotary(x: jax.Array, positions: jax.Array, theta: float,
+           inv_freq: jax.Array | None = None) -> jax.Array:
+    """x: [b, t, h, dh]; positions: [b, t] absolute token positions.
+    ``inv_freq`` [dh/2] replaces the plain ``theta`` frequencies (YaRN)."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    freqs = inv_freq if inv_freq is not None else jnp.exp(
+        -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs  # [b, t, half]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (DeepSeek-V2 ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max_pos: int,
+                  beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN's rope frequencies [dim/2] (DeepSeek-V2
+    ``DeepseekV2YarnRotaryEmbedding``): the plain frequencies where a
+    dimension turns more than ``beta_fast`` times over the original context,
+    those divided by ``factor`` where it turns fewer than ``beta_slow``
+    times, and a linear ramp between."""
+    def corr(rot):
+        return (dim * math.log(original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr(beta_fast)), 0)
+    hi = min(math.ceil(corr(beta_slow)), dim - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    extra = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
 
 
 # ---------------------------------------------------------------------------
@@ -122,7 +150,7 @@ def _masked_softmax(s: jax.Array, bias: jax.Array) -> jax.Array:
 
 
 def _gqa_out(p: jax.Array, v: jax.Array) -> jax.Array:
-    """p [b, hkv, g, tq, tk] x v [b, tk, hkv, dh] -> [b, tq, hkv, g, dh]."""
+    """p [b, hkv, g, tq, tk] x v [b, tk, hkv, dv] -> [b, tq, hkv, g, dv]."""
     return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
 
 
@@ -145,8 +173,9 @@ def _mask_bias(
 def attention(
     q: jax.Array,                 # [b, tq, hkv, g, dh]
     k: jax.Array,                 # [b, tk, hkv, dh]
-    v: jax.Array,                 # [b, tk, hkv, dh]
+    v: jax.Array,                 # [b, tk, hkv, dv]
     *,
+    scale: float | None = None,   # of the scores; None -> 1/sqrt(dh)
     causal: bool = True,
     window: int = 0,
     q_offset: Any = 0,            # absolute position of q[0]
@@ -165,8 +194,9 @@ def attention(
     tensors (fp32 statistics retained) — beyond-paper optimization, §Perf.
     """
     b, tq, hkv, g, dh = q.shape
-    tk = k.shape[1]
-    scale = 1.0 / math.sqrt(dh)
+    tk, dv = k.shape[1], v.shape[-1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(dh)
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     # Per-request valid lengths ([b] or [b, tq]) produce a [b, tq, tk] bias;
@@ -219,8 +249,8 @@ def attention(
 
         _, chunks = lax.scan(body, None, jnp.arange(nq))
 
-    # chunks: [nq, b, chunk_q, hkv, g, dh] -> [b, tq, hkv, g, dh]
-    return jnp.moveaxis(chunks, 0, 1).reshape(b, tq, hkv, g, dh)
+    # chunks: [nq, b, chunk_q, hkv, g, dv] -> [b, tq, hkv, g, dv]
+    return jnp.moveaxis(chunks, 0, 1).reshape(b, tq, hkv, g, dv)
 
 
 # ---------------------------------------------------------------------------

@@ -65,6 +65,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro import scopes
@@ -82,6 +83,14 @@ class Pool:
     apply: Callable
     # make_cache(batch, cache_len) -> cache pytree for ONE stacked row
     make_cache: Callable | None = None
+    # shape of a layer's aux: () or, in an expert layer, (2,) -- its balance
+    # loss and its experts' load max over mean (blocks.moe_routed)
+    aux_shape: tuple[int, ...] = ()
+
+
+def _aux0(pool: Pool):
+    """The zero a pool's scan starts its summed aux from."""
+    return np.zeros(pool.aux_shape, np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,7 +205,7 @@ def _apply_pool_serial(pool, flat_rows, x, ctx, comm, caches):
             return (x, aux_tot + aux), None
 
         with jax.named_scope(scopes.CARRY):
-            (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), flat_rows)
+            (x, aux), _ = lax.scan(body, (x, _aux0(pool)), flat_rows)
         return x, aux, None
 
     def body(carry, xs):
@@ -207,7 +216,7 @@ def _apply_pool_serial(pool, flat_rows, x, ctx, comm, caches):
 
     with jax.named_scope(scopes.CARRY):
         (x, aux), new_caches = lax.scan(
-            body, (x, jnp.float32(0.0)), (flat_rows, caches))
+            body, (x, _aux0(pool)), (flat_rows, caches))
     return x, aux, new_caches
 
 
@@ -247,7 +256,7 @@ def _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches):
 
         with jax.named_scope(scopes.CARRY):
             (x, aux, _), _ = lax.scan(
-                body, (x, jnp.float32(0.0), cur0), nxt_rows)
+                body, (x, _aux0(pool), cur0), nxt_rows)
         return x, aux, None
 
     def body(carry, xs):
@@ -258,7 +267,7 @@ def _apply_pool_prefetch(pool, flat_rows, x, ctx, comm, caches):
 
     with jax.named_scope(scopes.CARRY):
         (x, aux, _), new_caches = lax.scan(
-            body, (x, jnp.float32(0.0), cur0), (nxt_rows, caches))
+            body, (x, _aux0(pool), cur0), (nxt_rows, caches))
     return x, aux, new_caches
 
 
@@ -303,14 +312,18 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
         return x_out, aux
 
     def fwd_scan(x, flat_rows):
-        """The double-buffered forward; also stacks per-layer inputs."""
-        with jax.named_scope(scopes.CARRY):
-            nxt_rows = jax.tree.map(
-                lambda a: jnp.roll(a, -1, axis=0), flat_rows)
+        """The double-buffered forward; also stacks per-layer inputs.
+        Row i+1 is read from the stack in place (the last layer's
+        lookahead wraps to row 0, as the stored schedule's roll does): a
+        rolled copy of the stack would hold it twice, and in the wire dtype
+        once more (1.17 GiB of bert-10b-l4's step compiled for a v5e)."""
         cur0 = comm.gather_flat(_row(flat_rows, (0, 0)), seed=seed)
 
-        def body(carry, nxt_row):
+        def body(carry, i):
             xc, aux_tot, cur = carry
+            nxt_row = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(
+                    a, (i + 1) % pool.stack, keepdims=False), flat_rows)
             nxt = comm.gather_flat(_row(nxt_row), seed=seed)  # layer i+1
             tensors = comm.unflatten(pool, cur)
             (x_out, aux), _ = pool.apply(tensors, xc, ctx, None)
@@ -318,7 +331,7 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
 
         with jax.named_scope(scopes.CARRY):
             (x_out, aux, _), x_ins = lax.scan(
-                body, (x, jnp.float32(0.0), cur0), nxt_rows)
+                body, (x, _aux0(pool), cur0), jnp.arange(pool.stack))
         return (x_out, aux), x_ins
 
     @jax.custom_vjp
@@ -404,7 +417,7 @@ def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
 
         with jax.named_scope(scopes.CARRY):
             (x_out, aux, _, tok), x_ins = lax.scan(
-                body, (x, jnp.float32(0.0), cur0, jnp.int32(0)),
+                body, (x, _aux0(pool), cur0, jnp.int32(0)),
                 (jnp.arange(pool.stack), nxt_rows))
         return (x_out, aux), tok, x_ins
 
@@ -545,8 +558,24 @@ def loss_fn(
             vocab_real=model.cfg.vocab, vocab_padded=model.vocab_padded,
             ctx=ctx,
         )
-    loss = ce + model.cfg.router_aux_weight * aux
-    return loss, {"loss": ce, "aux": aux}
+    metrics = {"loss": ce, "aux": aux}
+    if model.cfg.moe_layers:
+        # the expert layers' aux: balance loss, and experts' load max over
+        # mean, reported as its mean over those layers
+        metrics = {"loss": ce, "aux": aux[0],
+                   COUNTERS[0]: aux[1] / model.cfg.moe_layers}
+    loss = ce + model.cfg.router_aux_weight * metrics["aux"]
+    return loss, metrics
+
+
+# the loss function's metrics beside "loss" and "aux", in a model with
+# expert layers
+COUNTERS = ("expert_load_max_over_mean",)
+
+
+def counters(model: ModelDef) -> tuple[str, ...]:
+    """The names of :func:`loss_fn`'s metrics beside "loss" and "aux"."""
+    return COUNTERS if model.cfg.moe_layers else ()
 
 
 # ---------------------------------------------------------------------------

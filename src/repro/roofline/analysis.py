@@ -11,7 +11,7 @@ core/linkmodel.py is the single link model of the tree):
 
 MODEL_FLOPS uses 6·N·D for training (N = active params for MoE) and 2·N·D
 for inference shapes, divided across all chips; the ratio MODEL/HLO exposes
-remat + padded-head + capacity-factor waste.
+remat + padded-head waste.
 """
 
 from __future__ import annotations
@@ -64,7 +64,7 @@ def roofline_terms(rec: dict) -> dict:
 
 
 _SUGGESTIONS = {
-    "compute": ("compute-bound: reduce padded-head / capacity-factor / remat "
+    "compute": ("compute-bound: reduce padded-head / remat "
                 "waste, or increase per-chip batch to amortize fixed work"),
     "memory": ("memory-bound: fuse the attention softmax (Pallas flash "
                "kernel keeps scores in VMEM) and keep activations bf16"),

@@ -25,3 +25,8 @@ HEAD = "model.head"             # models/lm: final norm, logits and the loss
 
 ALL = (GATHER, HOP1, HOP2, CARRY, GRAD_ACCUM, OPTIMIZER, ATTENTION, MLP,
        EMBED, HEAD)
+
+# Inside MLP, in an expert layer: a reader of ALL still finds MLP for them.
+MOE_ROUTE = "moe.route"         # router, top-k, expert order and back, combine
+MOE_EXPERTS = "moe.experts"     # the held experts' grouped products
+MOE = (MOE_ROUTE, MOE_EXPERTS)

@@ -14,6 +14,8 @@ asserts on them.  Checks:
   hier_train_equiv   hierarchical gather on == off, same losses
   compress_hop2      bf16-compressed hop 2 stays close
   decode_consistency prefill+decode logits == teacher-forced forward
+  init_state_placement init_state on repl=2 x shard=2 == on one device,
+                     placed in the state's shardings, moments zero
 """
 
 import os
@@ -34,7 +36,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, smoke_variant
 from repro.core import collectives as C
-from repro.core.mics import MiCSConfig, build_train_step, init_state, state_pspecs
+from repro.core.mics import (
+    MiCSConfig, build_train_step, init_state, state_pspecs, state_shardings,
+)
 from repro.core.topology import MiCSTopology, make_host_mesh
 from repro.models.build import build_model
 from repro.optim.adamw import OptConfig
@@ -196,6 +200,18 @@ def _moe_tp():
     np.testing.assert_allclose(ep, one, rtol=0.03, atol=0.05)
 
 
+@check("mla_tp_equiv")
+def _mla_tp():
+    """MLA heads and held experts split over the model axis (tp=2) ==
+    single-device model (deepseek-v2-lite: a dense layer, then expert
+    layers holding 4 of 8 experts)."""
+    one = _train_losses((1, 1, 1, 1), MiCSConfig(micro_steps=2),
+                        arch="deepseek-v2-lite", seed=5)
+    tp2 = _train_losses((1, 1, 2, 2), MiCSConfig(micro_steps=2),
+                        arch="deepseek-v2-lite", seed=5)
+    np.testing.assert_allclose(tp2, one, rtol=0.03, atol=0.05)
+
+
 @check("griffin_partition_equiv")
 def _griffin_partition():
     """Griffin (RG-LRU + MQA kv-group gathers) under MiCS partitioning:
@@ -217,6 +233,28 @@ def _mlstm_chunk():
                         MiCSConfig(micro_steps=2, mlstm_chunk=8),
                         arch="xlstm-125m", seed=4)
     np.testing.assert_allclose(chk, seq, rtol=5e-3, atol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+@check("init_state_placement")
+def _init_placement():
+    """The state is drawn on one device and placed a pool at a time: on a
+    repl=2 x shard=2 mesh it holds the one-device state's values, in the
+    state's shardings, with zero moments."""
+    cfg = smoke_variant(get_config("deepseek-v2-lite"))
+    model = build_model(cfg, tp=1)
+    one = init_state(model, MiCSTopology(make_host_mesh(1, 1, 1, 1)), seed=4)
+    topo = MiCSTopology(make_host_mesh(1, 2, 2, 1))
+    got = init_state(model, topo, seed=4)
+    want_sh = state_shardings(model, topo)
+    assert jax.tree.structure(got) == jax.tree.structure(one)
+    for leaf, ref, sh in zip(jax.tree.leaves(got), jax.tree.leaves(one),
+                             jax.tree.leaves(want_sh)):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim), leaf.sharding
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(ref))
+    for name in ("m", "v"):
+        assert all(not np.asarray(a).any()
+                   for a in jax.tree.leaves(got[name])), name
 
 
 # ---------------------------------------------------------------------------

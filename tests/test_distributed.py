@@ -20,9 +20,9 @@ def harness_results():
 
 CHECKS = [
     "hier_gather", "mics_fidelity", "zero3_equiv", "alt_sync_equiv",
-    "hier_train_equiv", "compress_hop2", "moe_tp_equiv",
+    "hier_train_equiv", "compress_hop2", "moe_tp_equiv", "mla_tp_equiv",
     "griffin_partition_equiv", "mlstm_chunk_train_equiv",
-    "decode_consistency",
+    "decode_consistency", "init_state_placement",
 ]
 
 

@@ -90,39 +90,108 @@ def test_rotary_preserves_norm_and_relative_phase():
     np.testing.assert_allclose(score(5, 3), score(12, 10), rtol=1e-4)
 
 
-def test_moe_dispatch_matches_dense_reference(topo1):
-    """Capacity-dispatch MoE (no drops) == explicit per-token expert mix."""
+@pytest.mark.parametrize("norm_topk_prob", [True, False])
+@pytest.mark.parametrize("held", [8, 4])
+def test_moe_dispatch_matches_dense_reference(topo1, norm_topk_prob, held):
+    """The dropless grouped expert layer == an explicit per-token mix of the
+    experts it holds (the first ``held`` of 8), top-k over all 8, with the
+    top-k gates renormalised or not."""
     import dataclasses
 
     from repro.configs import get_config, smoke_variant
-    from repro.models.blocks import _moe_dispatch_tokens
+    from repro.models.blocks import moe_routed
 
     cfg = smoke_variant(get_config("deepseek-moe-16b"))
-    cfg = dataclasses.replace(cfg, capacity_factor=8.0)
+    cfg = dataclasses.replace(cfg, experts_held=held,
+                              norm_topk_prob=norm_topk_prob)
     d, e, k = 16, cfg.n_experts, cfg.top_k
     n = 32
     t = {
         "router.w": jnp.asarray(RNG.normal(size=(d, e)) * 0.3, jnp.float32),
-        "moe.wg": jnp.asarray(RNG.normal(size=(e, d, 8)) * 0.3, jnp.float32),
-        "moe.wu": jnp.asarray(RNG.normal(size=(e, d, 8)) * 0.3, jnp.float32),
-        "moe.wd": jnp.asarray(RNG.normal(size=(e, 8, d)) * 0.3, jnp.float32),
+        "moe.wg": jnp.asarray(RNG.normal(size=(held, d, 8)) * 0.3, jnp.float32),
+        "moe.wu": jnp.asarray(RNG.normal(size=(held, d, 8)) * 0.3, jnp.float32),
+        "moe.wd": jnp.asarray(RNG.normal(size=(held, 8, d)) * 0.3, jnp.float32),
     }
     x = jnp.asarray(RNG.normal(size=(n, d)), jnp.float32)
     ctx = L.Ctx(tp=1)
-    got, _aux = _moe_dispatch_tokens(x, t, cfg, ctx)
+    got, aux = moe_routed(x, t, cfg, ctx)
 
-    # dense reference
-    logits = np.asarray(x) @ np.asarray(t["router.w"])
+    # dense reference over the held experts
+    logits = np.asarray(x, np.float64) @ np.asarray(t["router.w"], np.float64)
     probs = np.exp(logits - logits.max(-1, keepdims=True))
     probs /= probs.sum(-1, keepdims=True)
     topk = np.argsort(-probs, axis=-1)[:, :k]
     want = np.zeros((n, d), np.float32)
+    load = np.zeros(held)
     for i in range(n):
         gates = probs[i, topk[i]]
-        gates = gates / gates.sum()
+        if norm_topk_prob:
+            gates = gates / gates.sum()
         for j, eid in enumerate(topk[i]):
+            if eid >= held:
+                continue
+            load[eid] += 1
             h = np.asarray(x)[i] @ np.asarray(t["moe.wg"])[eid]
             u = np.asarray(x)[i] @ np.asarray(t["moe.wu"])[eid]
             act = h / (1 + np.exp(-h)) * u
             want[i] += gates[j] * (act @ np.asarray(t["moe.wd"])[eid])
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(float(aux[1]), load.max() / load.mean(),
+                               rtol=1e-6)
+
+
+def test_moe_rows_past_the_groups_do_not_leak(topo1, monkeypatch):
+    """A grouped product may leave the rows past its groups undefined, in
+    its transposes too (the TPU's does): with NaN there, the expert
+    layer's output and gradients are those of the CPU's zeros."""
+    import dataclasses
+
+    import jax
+    from jax import lax
+
+    from repro.configs import get_config, smoke_variant
+    from repro.models.blocks import moe_routed
+
+    cfg = dataclasses.replace(smoke_variant(get_config("deepseek-moe-16b")),
+                              experts_held=4)
+    d, held = 16, 4
+    t = {
+        "router.w": jnp.asarray(RNG.normal(size=(d, 8)) * 0.3, jnp.float32),
+        "moe.wg": jnp.asarray(RNG.normal(size=(held, d, 8)) * 0.3, jnp.float32),
+        "moe.wu": jnp.asarray(RNG.normal(size=(held, d, 8)) * 0.3, jnp.float32),
+        "moe.wd": jnp.asarray(RNG.normal(size=(held, 8, d)) * 0.3, jnp.float32),
+    }
+    x = jnp.asarray(RNG.normal(size=(32, d)), jnp.float32)
+
+    def loss(t, x):
+        y, aux = moe_routed(x, t, cfg, L.Ctx(tp=1))
+        return jnp.sum(y * y) + aux[0]
+
+    want = jax.value_and_grad(loss, argnums=(0, 1))(t, x)
+    real = lax.ragged_dot
+
+    def undefined_past_groups(lhs, rhs, group_sizes, **kw):
+        def fill(a):
+            past = jnp.arange(a.shape[0]) >= jnp.sum(group_sizes)
+            return jnp.where(past[:, None], jnp.nan, a)
+
+        @jax.custom_vjp
+        def f(l, r):
+            return fill(real(l, r, group_sizes, **kw))
+
+        def fwd(l, r):
+            return f(l, r), (l, r)
+
+        def bwd(res, ct):
+            dl, dr = jax.vjp(lambda a, b: real(a, b, group_sizes, **kw),
+                             *res)[1](ct)
+            return fill(dl), dr
+
+        f.defvjp(fwd, bwd)
+        return f(lhs, rhs)
+
+    monkeypatch.setattr(lax, "ragged_dot", undefined_past_groups)
+    got = jax.value_and_grad(loss, argnums=(0, 1))(t, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7)

@@ -399,3 +399,24 @@ def test_offload_peak_accounting(harness_results):
     # m/v leave the donated args: the drop is ~2/3 of the state bytes
     drop = s["measured_args_bytes"] - ho["measured_args_bytes"]
     assert drop > 0.5 * s["measured_args_bytes"], det
+
+
+def test_expert_model_pools_are_priced_and_routed():
+    """DeepSeek-V2-Lite's smoke variant: a dense pool (one layer, serial)
+    and an expert pool (remat), the expert layer's dropless row buffers
+    priced, the remat carry only its one in-flight buffer."""
+    from repro.configs import get_config, smoke_variant
+    from repro.models.build import build_model
+    from repro.models.lm import train_routes
+
+    model = build_model(smoke_variant(get_config("deepseek-v2-lite")), tp=1)
+    gp = GatherPolicy(prefetch=True)
+    assert train_routes(model, gp) == {"dense": "serial", "layers": "remat"}
+    grid = DeviceGrid(partition_size=1, replication_degree=1)
+    plan = predict_footprint(model, grid, gp, SyncPolicy(), micro_steps=2,
+                             local_batch=2, seq=32)
+    cfg = model.cfg
+    assert plan.components["expert_rows"] == (
+        2 * 32 * cfg.top_k * (2 * cfg.d_model + 3 * cfg.d_ff) * 2)
+    flat = model.pool("layers").layout.flat_len
+    assert plan.components["prefetch_carry"] == flat * 2

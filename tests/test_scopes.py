@@ -7,6 +7,7 @@ on by default, names none of them.
 """
 
 import pathlib
+import re
 
 import pytest
 from harness_util import run_harness
@@ -20,7 +21,7 @@ ONE_DEVICE = set(scopes.ALL) - {scopes.HOP1, scopes.HOP2}
 COMM = {scopes.GATHER, scopes.HOP1, scopes.HOP2, scopes.OPTIMIZER}
 
 
-@pytest.fixture(scope="module", params=["bert-10b", "yi-9b"])
+@pytest.fixture(scope="module", params=["bert-10b", "yi-9b", "deepseek-v2-lite"])
 def one_device(request):
     lowered = lower_step(request.param)
     return lowered, instructions(lowered.compile().as_text())
@@ -58,3 +59,24 @@ def test_mesh_collectives_lie_under_comm_scopes(mesh):
 
 def test_mesh_stripped_module_names_no_scope(mesh):
     assert mesh["stripped_names"] == []
+
+
+def test_expert_scopes_lie_inside_mlp(one_device, request):
+    """In an expert layer the routing and the grouped products carry their
+    own scopes, nested in ``model.mlp``: a reader of ``scopes.ALL`` still
+    puts them under the MLP."""
+    _, ins = one_device
+    ops = [op for _, _, op in ins if op]
+    for s in scopes.MOE:
+        under = [op for op in ops if s in op]
+        if "deepseek" in request.node.callspec.params["one_device"]:
+            assert under, s
+        assert all(re.search(rf"{re.escape(scopes.MLP)}/(.*/)?{re.escape(s)}",
+                             op) for op in under), s
+        assert {innermost_scope(op) for op in under} <= {scopes.MLP}
+
+
+def test_stripped_module_names_no_expert_scope(one_device):
+    lowered, _ = one_device
+    stripped = lowered.as_text(debug_info=False)
+    assert [s for s in scopes.MOE if s in stripped] == []

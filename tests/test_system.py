@@ -89,3 +89,32 @@ def test_arch_smoke_decode(arch, topo1):
         assert np.all(np.isfinite(arr))
         ids = np.asarray(tok)
         assert ids.min() >= 0 and ids.max() < cfg.vocab
+
+
+def test_deepseek_v2_lite_smoke_trains_through_the_launcher(tmp_path, capsys):
+    """``python -m repro.launch.train --arch deepseek-v2-lite --smoke``'s
+    path: the smoke variant keeps a leading dense layer, MLA and a held share
+    of the experts; ``build_training`` prints a dense pool and then the
+    expert pool on the remat route; the loop trains it."""
+    from repro.core.topology import elastic_host_topology
+    from repro.launch.train import build_training
+
+    cfg = smoke_variant(get_config("deepseek-v2-lite"))
+    assert cfg.first_dense_layers == 1 and cfg.kv_lora_rank
+    assert 0 < cfg.experts_held < cfg.n_experts
+    run = build_training(cfg, elastic_host_topology(1, 1),
+                         MiCSConfig(micro_steps=2), steps=4, global_batch=4,
+                         seq=32, lr=1e-3, checkpoint_dir=str(tmp_path),
+                         checkpoint_every=0)
+    assert "routes: dense=serial layers=remat" in capsys.readouterr().out
+    stats = run.train()
+    assert len(stats.losses) == 4
+    assert np.all(np.isfinite(stats.losses))
+
+
+def test_mla_serving_raises_not_implemented(topo1):
+    """MLA trains only: the serve path has no latent cache, and says so."""
+    cfg = smoke_variant(get_config("deepseek-v2-lite"))
+    model = build_model(cfg, tp=1)
+    with pytest.raises(NotImplementedError, match="latent cache"):
+        build_serve_steps(model, topo1, MiCSConfig(), cache_len=24)
