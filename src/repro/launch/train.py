@@ -96,9 +96,11 @@ def build_training(cfg: ArchConfig, topo: MiCSTopology, mcfg: MiCSConfig, *,
         hop2_bucket_mb=mcfg.hop2_bucket_mb, offload_opt=mcfg.offload_opt)
     routes = " ".join(f"{name}={route}" for name, route
                       in lm.train_routes(model, gp).items())
+    paths = lm.attention_paths(model, seq)
     print(f"memplan: {mem.total_gb:.3f} GiB predicted per device "
           f"(carry_offload={mcfg.carry_offload}, "
-          f"offload_opt={mcfg.offload_opt}) routes: {routes}")
+          f"offload_opt={mcfg.offload_opt}) routes: {routes} "
+          f"attention: kernel={paths['kernel']} jnp={paths['jnp']}")
     oc = OptConfig(lr_max=lr, total_steps=steps,
                    warmup_steps=max(steps // 20, 1))
     dc = DataConfig(vocab=cfg.vocab, seq=seq, global_batch=global_batch,

@@ -4,12 +4,16 @@ Everything here is a pure function over explicit tensors; tensor-parallel
 collectives use named mesh axes (the functions are always called inside
 ``shard_map`` — on a single device the axes simply have size 1).
 
-The chunked online-softmax attention is the pure-jnp oracle for the Pallas
-flash-attention kernel in ``repro.kernels.flash_attention``.
+:func:`attention` runs a whole causal sequence on the TPU through the fused
+Pallas kernel of ``repro.kernels.flash_attention``, and every other call
+through chunked jnp scores (:func:`attention_path` says which).
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import dataclasses
 import math
 from typing import Any
@@ -17,6 +21,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.kernels.flash_attention import causal_attention
+from repro.kernels.flash_attention.ops import BLOCKS
 
 NEG_INF = -1e30
 
@@ -38,7 +45,7 @@ class Ctx:
     vision: Any = None             # [b, n_img, d] stub patch embeddings
     enc_out: Any = None            # [b, n_frames, d] encoder output
     compute_dtype: Any = jnp.bfloat16
-    scores_bf16: bool = False      # bf16 attention scores (§Perf)
+    scores_bf16: bool = False      # bf16 jnp attention scores (§Perf)
     mlstm_chunk: int = 0           # chunkwise-parallel mLSTM (§Perf; 0=scan)
     step_seed: Any = None          # traced step counter (qgZ dither seed)
     pages: Any = None              # paged-KV state (runtime/paged.PageState):
@@ -170,6 +177,45 @@ def _mask_bias(
     return m
 
 
+# the fused kernel runs sequences that are multiples of its smallest tile
+KERNEL_TILE = min(BLOCKS)
+
+# the Counter that count_attention_paths() has open, if any
+_PATHS: contextvars.ContextVar = contextvars.ContextVar("attention_paths",
+                                                        default=None)
+
+
+def attention_path(
+    tq: int, tk: int, *, causal: bool, window: int, q_offset: Any,
+    k_offset: Any, kv_valid_len: Any, platform: str,
+) -> str:
+    """The path :func:`attention` takes: ``'kernel'`` (the fused causal
+    kernel with its own backward) for causal self attention over a whole
+    sequence, no window, at a multiple of :data:`KERNEL_TILE`, on the TPU;
+    ``'jnp'`` (chunked scores) for everything else: decode against a cache,
+    offsets, local windows, cross attention, other backends."""
+    def zero(offset):
+        return isinstance(offset, int) and offset == 0
+
+    whole = (kv_valid_len is None and zero(q_offset) and zero(k_offset)
+             and tq == tk and tq % KERNEL_TILE == 0)
+    if platform == "tpu" and causal and not window and whole:
+        return "kernel"
+    return "jnp"
+
+
+@contextlib.contextmanager
+def count_attention_paths():
+    """While open, tally the :func:`attention_path` of each
+    :func:`attention` call traced; yields the ``collections.Counter``."""
+    counts = collections.Counter()
+    token = _PATHS.set(counts)
+    try:
+        yield counts
+    finally:
+        _PATHS.reset(token)
+
+
 def attention(
     q: jax.Array,                 # [b, tq, hkv, g, dh]
     k: jax.Array,                 # [b, tk, hkv, dh]
@@ -192,9 +238,22 @@ def attention(
     chunk, so HLO FLOPs reflect the sub-quadratic cost.
     scores_dtype=bf16 halves the HBM traffic of the score/probability
     tensors (fp32 statistics retained) — beyond-paper optimization, §Perf.
+
+    Where :func:`attention_path` says ``'kernel'``, none of that: the
+    fused kernel takes the call, with the same scale on q, and
+    ``scores_dtype`` and ``chunk_q`` do not apply.
     """
     b, tq, hkv, g, dh = q.shape
     tk, dv = k.shape[1], v.shape[-1]
+    path = attention_path(tq, tk, causal=causal, window=window,
+                          q_offset=q_offset, k_offset=k_offset,
+                          kv_valid_len=kv_valid_len,
+                          platform=jax.default_backend())
+    counts = _PATHS.get()
+    if counts is not None:
+        counts[path] += 1
+    if path == "kernel":
+        return causal_attention(q, k, v, scale=scale)
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)

@@ -60,6 +60,7 @@ step (PERF.md §5, §6).  The stored residual also always takes more HBM
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Callable
 
@@ -67,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro import scopes
 from repro.configs.base import ArchConfig
@@ -165,6 +167,45 @@ def train_routes(model: ModelDef, comm) -> dict[str, str]:
     """:func:`train_route` of each scanned pool of ``model``."""
     return {pool.name: train_route(model.cfg, pool.name, pool.stack, comm)
             for pool in model.pools}
+
+
+def attention_paths(model: ModelDef, seq: int) -> collections.Counter:
+    """How many of a training forward's attention calls take each path of
+    :func:`layers.attention` (``'kernel'``, ``'jnp'``; its
+    ``attention_path``), over every layer at ``seq`` tokens.  Each pool's
+    layer is traced once, abstractly (``jax.eval_shape``, one row, over an
+    abstract mesh of the model axis), and counted ``stack`` times."""
+    cfg = model.cfg
+    bf16 = jnp.bfloat16
+    paths = collections.Counter()
+    for pool in model.pools:
+        encoder = is_encoder_pool(cfg, pool.name)
+        x = jax.ShapeDtypeStruct(
+            (1, cfg.n_audio_frames if encoder else seq, cfg.d_model), bf16)
+        src = {}
+        if cfg.family == "vlm":
+            src["vision"] = (1, cfg.n_vision_tokens, cfg.d_model)
+        if cfg.family == "encdec" and not encoder:
+            src["enc_out"] = (1, cfg.n_audio_frames, cfg.d_model)
+        src = {k: jax.ShapeDtypeStruct(s, bf16) for k, s in src.items()}
+        tensors = {}
+        for s in pool.layout.segments:
+            shape = list(s.shape)
+            shape[s.model_gather_dim] *= s.model_gather
+            tensors[s.name] = jax.ShapeDtypeStruct(tuple(shape), bf16)
+        ctx = L.Ctx(mode="train", tp=model.tp)
+
+        def layer(tensors, x, src):
+            return pool.apply(tensors, x, dataclasses.replace(ctx, **src), None)
+
+        mesh = jax.sharding.AbstractMesh((model.tp,), (ctx.tp_axis,))
+        with L.count_attention_paths() as layer_paths:
+            jax.eval_shape(jax.shard_map(
+                layer, mesh=mesh, in_specs=P(), out_specs=P(),
+                check_vma=False), tensors, x, src)
+        for path, n in layer_paths.items():
+            paths[path] += n * pool.stack
+    return paths
 
 
 def _apply_pool(

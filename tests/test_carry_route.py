@@ -66,7 +66,8 @@ def test_train_routes(arch, layers, want):
 
 
 @pytest.mark.parametrize("arch,layers,route", [
-    ("bert-10b", 4, "layers=remat"), ("yi-9b", 1, "layers=serial")])
+    ("bert-10b", 4, "layers=remat attention: kernel=0 jnp=4"),
+    ("yi-9b", 1, "layers=serial attention: kernel=0 jnp=1")])
 def test_build_training_prints_routes(capsys, arch, layers, route):
     cfg = dataclasses.replace(get_config(arch), n_layers=layers)
     build_training(cfg, elastic_host_topology(1, 1), MiCSConfig(),

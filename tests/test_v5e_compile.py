@@ -44,12 +44,29 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _grad(fn):
+    """The gradient w.r.t. every argument of ``sum(fn(...))``."""
+    def loss(*args):
+        return jnp.sum(fn(*args).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
 def _kernel_cases():
-    from repro.kernels.flash_attention import flash_attention
+    from repro.kernels.flash_attention import causal_attention, flash_attention
     from repro.kernels.rglru import rglru
     from repro.kernels.rmsnorm import rmsnorm
 
     bf16 = jnp.bfloat16
+
+    def mla(q, k, v):
+        return causal_attention(q, k, v, scale=0.1)
+
+    # yi-9b: 32 query heads over 4 kv heads of 128, 4096 tokens
+    yi = [((1, 4096, 4, 8, 128), bf16), ((1, 4096, 4, 128), bf16),
+          ((1, 4096, 4, 128), bf16)]
+    # DeepSeek-V2-Lite's MLA: 16 heads, qk 192, v 128, 4096 tokens
+    mla_shapes = [((1, 4096, 16, 1, 192), bf16), ((1, 4096, 16, 192), bf16),
+                  ((1, 4096, 16, 128), bf16)]
     return {
         # 8192 token rows at bert-10b's d_model 2560
         "rmsnorm": (rmsnorm, [((8192, 2560), bf16), ((2560,), jnp.float32)]),
@@ -57,10 +74,17 @@ def _kernel_cases():
         "flash_attention": (flash_attention, [((40, 512, 64), bf16)] * 3),
         # recurrentgemma-2b: lru_width 2560 over 4096 steps
         "rglru": (rglru, [((2, 4096, 2560), bf16)] * 2),
+        "causal_attention_yi": (causal_attention, yi),
+        "causal_attention_yi_grad": (_grad(causal_attention), yi),
+        "causal_attention_mla": (mla, mla_shapes),
+        "causal_attention_mla_grad": (_grad(mla), mla_shapes),
     }
 
 
-@pytest.mark.parametrize("name", ["rmsnorm", "flash_attention", "rglru"])
+@pytest.mark.parametrize("name", [
+    "rmsnorm", "flash_attention", "rglru",
+    "causal_attention_yi", "causal_attention_yi_grad",
+    "causal_attention_mla", "causal_attention_mla_grad"])
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = _kernel_cases()[name]
     args = [_sds(s, d, one_chip) for s, d in shapes]
