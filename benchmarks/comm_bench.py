@@ -26,9 +26,8 @@ Prints one JSON object (saved as BENCH_comm.json by run.py):
 * a ``boundary`` section on a replicated mesh (hop 2 live): serial vs
   bucketed-exact vs bucketed-approx (``clip_mode='approx'``: AdamW
   pipelined under the next bucket's hop-2 with a one-bucket-stale clip
-  factor) vs host-offloaded (``carry_offload='host'`` +
-  ``offload_opt=True``) boundary cells — exact/offload trajectories
-  bitwise equal, approx within ``APPROX_CLIP_LOSS_RTOL``, per-cell
+  factor) boundary cells — exact trajectories bitwise equal, approx
+  within ``APPROX_CLIP_LOSS_RTOL``, per-cell
   measured wall times, the bucket-granular hop-2 census, and an
   ``overlap`` roll-up of measured step time vs the link model's predicted
   exposed-hop-2 time per cell and profile;
@@ -43,8 +42,7 @@ This script is the ``comm`` suite of the declarative perf matrix
 exactly the gates ``repro.bench.matrixdef`` declares for this suite —
 bitwise/census/rtol contracts per cell, and the variance-aware step-time
 regression gates of the non-serial boundary cells against the same-run
-serial reference (the host-offload cell gets a wider threshold for its
-documented CPU io_callback overhead).
+serial reference.
 """
 
 import argparse
@@ -64,9 +62,7 @@ from repro.core.autotune import (
     compare_census, cost_candidate, cost_hop2_schedule, predict_traffic,
     rank_policies,
 )
-from repro.core import memplan
 from repro.core.comm import CommEngine
-from repro.core.hostoffload import stash_clear
 from repro.core.linkmodel import get_profile
 from repro.core.mics import (
     MiCSConfig, build_train_step, init_state, init_state_shapes,
@@ -87,9 +83,7 @@ PROFILES = ("v5e", "efa-100g")
 # (label, MiCSConfig fields) — >= 3 policies for the predicted-vs-measured
 # ledger (acceptance criterion of ISSUE 2); the GatherPolicy/SyncPolicy are
 # derived via CommEngine.from_config so the ledger prices exactly what the
-# step runs.  The qgZ rows ship the int8 hop-1 gradient wire (ISSUE 4);
-# the +host row streams the prefetch carry over the host tier, giving
-# tools/fit_profile.py a ``tier='host'`` stage to constrain (α, β) from.
+# step runs.  The qgZ rows ship the int8 hop-1 gradient wire (ISSUE 4).
 # Labels are pinned by repro.bench.matrixdef.COMM_POLICY_LABELS — the
 # declared matrix cells — so coverage drift fails the matrix loudly.
 POLICIES = tuple(zip(COMM_POLICY_LABELS, (
@@ -99,24 +93,16 @@ POLICIES = tuple(zip(COMM_POLICY_LABELS, (
     dict(quant_gather=True),
     dict(hop1_wire_dtype="int8"),
     dict(quant_gather=True, hop1_wire_dtype="int8"),
-    dict(prefetch=True, carry_offload="host"),
-    # second host row at a different bytes-per-event ratio (fp32 carry is
-    # 2x the bytes of bf16 at the same event count) — separates the host
-    # α from its β in the fit
-    dict(prefetch=True, gather_dtype="float32", carry_offload="host"),
 )))
 
-# Boundary cells (replicated mesh): the bitwise-exact schedules, the
-# approximate-clip pipeline, and the host-offloaded cell (carry + AdamW
-# moments streamed through the host stash; numerics still bitwise-exact).
+# Boundary cells (replicated mesh): the bitwise-exact schedules and the
+# approximate-clip pipeline.
 # Cell labels pinned by matrixdef.COMM_BOUNDARY_CELLS, thresholds by
 # matrixdef.COMM_BOUNDARY_THRESHOLDS.
 BOUNDARY_CELLS = tuple(zip(COMM_BOUNDARY_CELLS, (
     dict(boundary_schedule="serial"),
     dict(boundary_schedule="bucketed"),
     dict(boundary_schedule="bucketed", clip_mode="approx"),
-    dict(boundary_schedule="bucketed", carry_offload="host",
-         offload_opt=True),
 )))
 
 
@@ -282,24 +268,6 @@ def policy_ledger(model, topo, mesh_shape, batch, steps, warmup, cells,
                 },
             },
         }
-        if gp.carry_offload == "host":
-            # The carry's d2h/h2d stream, ledgered exactly as
-            # cost_candidate's ``host_offload`` stage prices it: 2 x stack
-            # x flat_len bytes per scanned pool per micro-step over the
-            # host tier, one α-event per transfer (point-to-point — no
-            # ring, so no (g-1) hop factor).
-            cb = memplan._COMPUTE_BYTES[gp.wire_dtype]
-            scanned = {pl.name for pl in model.pools}
-            host_bytes, host_events = 0.0, 0
-            for name, (stack, _tp, flat_len) in \
-                    model.global_flat_shapes().items():
-                if name in scanned and stack > 1:
-                    host_bytes += 2.0 * MICRO * stack * flat_len * cb
-                    host_events += 2 * MICRO * stack
-            entry["fit_inputs"]["stages"]["carry_offload"] = {
-                "tier": "host", "alpha_events": host_events,
-                "wire_bytes": host_bytes}
-            stash_clear()
         for name in PROFILES:
             cand = cost_candidate(model, topo, get_profile(name), gp, sp,
                                   micro_steps=MICRO)
@@ -321,12 +289,10 @@ def policy_ledger(model, topo, mesh_shape, batch, steps, warmup, cells,
 
 def boundary_bench(cfg, steps, warmup, cells) -> dict:
     """The ``BOUNDARY_CELLS`` grid on a replicated mesh (repl=2, p=2, tp=2
-    — hop 2 is live).  serial / bucketed / bucketed_offload must produce
-    bitwise equal loss/grad-norm trajectories (the offload cell merely
-    relocates the carry + AdamW moments to the host stash);
-    bucketed_approx pipelines AdamW under hop-2 with a one-bucket-stale
-    clip factor, so its trajectory may drift — bounded by
-    ``APPROX_CLIP_LOSS_RTOL`` on the final loss.  The ledger records
+    — hop 2 is live).  serial / bucketed must produce bitwise equal
+    loss/grad-norm trajectories; bucketed_approx pipelines AdamW under
+    hop-2 with a one-bucket-stale clip factor, so its trajectory may
+    drift — bounded by ``APPROX_CLIP_LOSS_RTOL`` on the final loss.  The ledger records
     per-cell timing stats (median + MAD over the timed steps), the
     bucket-granular hop-2 census, and an ``overlap`` roll-up against the
     link model's exposed-hop-2 prediction per profile; the step-time
@@ -362,15 +328,13 @@ def boundary_bench(cfg, steps, warmup, cells) -> dict:
                                 OptConfig(total_steps=100, warmup_steps=0,
                                           lr_max=3e-3))
         stats = analyze(
-            step.lower(init_state_shapes(model,
-                                         offload_opt=mcfg.offload_opt),
+            step.lower(init_state_shapes(model),
                        make_batch_shapes(model, MICRO * b, t, MICRO))
                 .compile().as_text(),
             mesh_shape,
             partition_axes=topo.partition_axes,
             replication_axes=topo.replication_axes)
-        state = init_state(model, topo, seed=13,
-                           offload_opt=mcfg.offload_opt)
+        state = init_state(model, topo, seed=13)
         _state, timing, traj = _timed_steps(step, state, batch, steps,
                                             warmup)
         timings[label] = timing
@@ -380,12 +344,8 @@ def boundary_bench(cfg, steps, warmup, cells) -> dict:
             "trajectory": traj,
             "census_boundary": stats["boundary"],
         }
-        if mcfg.offload_opt or mcfg.carry_offload == "host":
-            stash_clear()
     out["trajectory_bitwise_equal"] = (
         out["serial"]["trajectory"] == out["bucketed"]["trajectory"])
-    out["offload_bitwise_equal"] = (
-        out["bucketed"]["trajectory"] == out["bucketed_offload"]["trajectory"])
     exact_final = out["bucketed"]["trajectory"][-1][0]
     approx_final = out["bucketed_approx"]["trajectory"][-1][0]
     out["approx_final_loss_rtol"] = abs(approx_final - exact_final) \
@@ -408,13 +368,7 @@ def boundary_bench(cfg, steps, warmup, cells) -> dict:
         for name in PROFILES
     }
     # The overlap roll-up: measured step time per cell against the link
-    # model's exposed-hop-2 prediction.  The offload cell runs the exact
-    # bucketed schedule — its hop-2 prediction is the bucketed row (the
-    # host stream is priced separately, cost_candidate's host_offload
-    # stage).
-    pred_key = {"serial": "serial", "bucketed": "bucketed",
-                "bucketed_approx": "bucketed_approx",
-                "bucketed_offload": "bucketed"}
+    # model's exposed-hop-2 prediction.
     out["overlap"] = {
         label: {
             "us_per_step": out[label]["us_per_step"],
@@ -423,7 +377,7 @@ def boundary_bench(cfg, steps, warmup, cells) -> dict:
                                / out["serial"]["us_per_step_min"], 3),
             "predicted_exposed_hop2_us": {
                 name: round(
-                    out["predicted"][name][pred_key[label]]["t_exposed_s"]
+                    out["predicted"][name][label]["t_exposed_s"]
                     * 1e6, 2)
                 for name in PROFILES},
         }
@@ -448,9 +402,6 @@ def boundary_bench(cfg, steps, warmup, cells) -> dict:
             and out["approx_final_loss_rtol"] <= APPROX_CLIP_LOSS_RTOL,
             f"approx clip diverged "
             f"(rtol={out['approx_final_loss_rtol']:.4f})"),
-        "bucketed_offload": (
-            out["offload_bitwise_equal"] and census_ok("bucketed_offload"),
-            "host offload changed numerics or census off-granular"),
     }
     for label, _ in BOUNDARY_CELLS:
         ok, why = verdicts[label]
@@ -479,23 +430,6 @@ def boundary_bench(cfg, steps, warmup, cells) -> dict:
         cell_config("contract", "predicted_exposed"), pred_ok,
         detail=None if pred_ok else "exposed-hop2 prediction ordering broke")
     return out
-
-
-def finish_cells(out: dict) -> None:
-    """Post-run contract cells that span sections."""
-    host_ok = any(
-        s["tier"] == "host"
-        for entry in out["policies"].values()
-        for s in entry["fit_inputs"]["stages"].values())
-    fit_ok = host_ok and all(
-        entry["fit_inputs"]["t_measured_s"] > 0
-        and entry["fit_inputs"]["stages"]
-        for entry in out["policies"].values())
-    out["cells"]["comm/contract/host_fit_stage"] = MS.contract_cell(
-        dict(suite="comm", section="contract", cell="host_fit_stage"),
-        fit_ok,
-        detail=None if fit_ok else
-        "no host-tier fit stage — tools/fit_profile.py host fit unexercised")
 
 
 def check_ledger(out: dict, smoke: bool) -> None:
@@ -531,7 +465,6 @@ def main() -> None:
     args = ap.parse_args()
     steps = args.steps or (5 if args.smoke else STEPS)
     out = run(steps, args.warmup)
-    finish_cells(out)
     print(json.dumps(out, indent=1))
     if args.check:
         check_ledger(out, args.smoke)

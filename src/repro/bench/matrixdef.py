@@ -79,19 +79,15 @@ class MatrixSpec:
 
 COMM_POLICY_LABELS = (
     "flat@bf16", "inner_first@bf16", "outer_first@bf16", "inner_first@int8",
-    "inner_first@bf16+qgZ", "inner_first@int8+qgZ", "inner_first@bf16+host",
-    "inner_first@fp32+host",
+    "inner_first@bf16+qgZ", "inner_first@int8+qgZ",
 )
-COMM_BOUNDARY_CELLS = ("serial", "bucketed", "bucketed_approx",
-                       "bucketed_offload")
-# step-time thresholds vs the same-run serial reference (the offload cell
-# pays the documented CPU io_callback round-trip a real DMA engine avoids;
-# the approx-clip cell's extra global-norm estimate can serialize badly
-# when the 8-virtual-device host is contended — observed up to ~1.5x on
-# an otherwise-passing host — so its bound is loose enough to only catch
+COMM_BOUNDARY_CELLS = ("serial", "bucketed", "bucketed_approx")
+# step-time thresholds vs the same-run serial reference (the approx-clip
+# cell's extra global-norm estimate can serialize badly when the
+# 8-virtual-device host is contended — observed up to ~1.5x on an
+# otherwise-passing host — so its bound is loose enough to only catch
 # overlap actually breaking)
-COMM_BOUNDARY_THRESHOLDS = {"bucketed": 1.2, "bucketed_approx": 1.8,
-                            "bucketed_offload": 3.0}
+COMM_BOUNDARY_THRESHOLDS = {"bucketed": 1.2, "bucketed_approx": 1.8}
 
 SERVE_RATES_FULL = ("0.25", "0.5", "1.0", "2.0", "inf")
 SERVE_RATES_SMOKE = ("0.5", "inf")
@@ -101,11 +97,11 @@ SERVE_PER_ROW_THRESHOLD = 1.2
 
 MEMPLAN_CHECKS = ("footprint_match", "footprint_degenerate",
                   "remat_lowers_peak", "census_match_remat",
-                  "carried_buffer_census", "offload_lowers_peak")
+                  "carried_buffer_census")
 ELASTIC_CHECKS = ("kill_pod_resume_bitwise", "grow_back_resume_bitwise",
                   "repick_keep_rule_bitwise", "resolve_scale_repick",
                   "data_continuity", "straggler_flagged", "crash_mid_save",
-                  "reshard_roundtrip", "offload_cross_topology")
+                  "reshard_roundtrip", "reshard_moments")
 CHAOS_CHECKS = ("preempt_replay_bitwise", "grow_back_readmission",
                 "straggler_evict", "crash_retry", "shed_under_burst")
 
@@ -177,7 +173,6 @@ def build_matrix(smoke: bool) -> MatrixSpec:
                 _timing_gates("comm/boundary/serial",
                               COMM_BOUNDARY_THRESHOLDS[label]))
     add("comm/contract/predicted_exposed", "comm", _CONTRACT)
-    add("comm/contract/host_fit_stage", "comm", _CONTRACT)
 
     # --- serve: interleaved step prices, closed-loop sweep, overload ------
     add("serve/step/fixed_decode", "serve", ())      # the in-run reference

@@ -32,7 +32,6 @@ Fault-tolerance model (DESIGN.md §6; targets 1000+ nodes):
 from __future__ import annotations
 
 import json
-import logging
 import os
 import pathlib
 import shutil
@@ -48,11 +47,8 @@ from repro.core.mics import state_shardings
 from repro.core.topology import MiCSTopology
 from repro.models.lm import ModelDef
 
-log = logging.getLogger("repro.checkpoint")
-
 MANIFEST = "manifest.json"
 STATE_BLOB = "state.npz"
-STASH_BLOB = "stash.npz"
 
 
 def _fsync(path: pathlib.Path) -> None:
@@ -77,12 +73,9 @@ class Checkpointer:
     # -- save ---------------------------------------------------------------
     def save(self, state, step: int, *, topo: MiCSTopology,
              data_cursor: int = 0, blocking: bool = True,
-             host_stash: dict | None = None, emergency: bool = False):
+             emergency: bool = False):
         """Snapshot `state` at `step`.  Arrays are fetched to host first (so
         the device buffers donate-rotate freely) and written by a worker.
-        ``host_stash`` (core/hostoffload.export_stash) carries the
-        host-offloaded optimizer moments when ``offload_opt=True`` — the
-        half of the training state that is not in ``state``.
         ``emergency=True`` tags a preemption-triggered save in the manifest
         (the train loop's response to a world-change notice)."""
         host_state = jax.tree.map(np.asarray, state)
@@ -97,8 +90,7 @@ class Checkpointer:
         }
         self.wait()
         self._worker = threading.Thread(
-            target=self._write_guarded, args=(host_state, meta, host_stash),
-            daemon=True)
+            target=self._write_guarded, args=(host_state, meta), daemon=True)
         self._worker.start()
         if blocking:
             self.wait()
@@ -112,13 +104,13 @@ class Checkpointer:
             exc, self._exc = self._exc, None
             raise exc
 
-    def _write_guarded(self, host_state, meta, host_stash=None):
+    def _write_guarded(self, host_state, meta):
         try:
-            self._write(host_state, meta, host_stash)
+            self._write(host_state, meta)
         except BaseException as e:  # noqa: BLE001 - held for wait()
             self._exc = e
 
-    def _write(self, host_state, meta, host_stash=None):
+    def _write(self, host_state, meta):
         step = meta["step"]
         tmp = self.dir / f"step_{step:08d}.tmp"
         final = self.dir / f"step_{step:08d}"
@@ -134,11 +126,6 @@ class Checkpointer:
             arrays[key] = leaf
         np.savez(tmp / STATE_BLOB, **arrays)
         meta["leaves"] = names
-        if host_stash:
-            # offloaded-moment shards, keyed "k_<ns>_<tag>_<slot>_<device>"
-            np.savez(tmp / STASH_BLOB,
-                     **{"k_" + "_".join(str(int(x)) for x in k): v
-                        for k, v in host_stash.items()})
         if self.fault_hook is not None:
             # state blob is on disk, manifest is not: the mid-save kill
             # window the atomicity contract is tested against.
@@ -177,30 +164,16 @@ class Checkpointer:
         return steps[-1] if steps else None
 
     def restore(self, model: ModelDef, topo: MiCSTopology,
-                step: int | None = None, *, offload_opt: bool = False):
+                step: int | None = None):
         """Load a checkpoint onto (possibly different) `topo`.
 
         Returns (state, meta).  Cross-topology restores reshard via the flat
         layout — the on-disk representation is topology-agnostic global
-        arrays, so nothing special is needed beyond new out-shardings.
-
-        ``offload_opt=True`` additionally imports the checkpoint's host-stash
-        shards (the offloaded AdamW moments) under the sentinel namespace
-        (core/hostoffload.CKPT_NAMESPACE).  The stash keys are per-device
-        (the mesh-linearized device index), so that leg of the restore is
-        same-topology only; a cross-topology restore restarts the moments
-        from the lazy zero-init — EXPLICITLY: a warning is logged and
-        ``meta["host_stash"]`` records ``{present, restored, reset}`` so
-        callers (and tests) see exactly which half of the optimizer state
-        survived the world change.
+        arrays (params and both AdamW moments), so nothing special is
+        needed beyond new out-shardings.
         """
-        state_host, meta = self.load_host(model, step,
-                                          offload_opt=offload_opt)
-        if offload_opt:
-            path = self.dir / f"step_{meta['step']:08d}"
-            meta["host_stash"] = self._restore_stash(path, meta, topo)
-
-        shardings = state_shardings(model, topo, offload_opt=offload_opt)
+        state_host, meta = self.load_host(model, step)
+        shardings = state_shardings(model, topo)
         with topo.mesh:
             state = jax.tree.map(
                 lambda a, s: jax.device_put(jnp.asarray(a), s),
@@ -209,8 +182,7 @@ class Checkpointer:
             )
         return state, meta
 
-    def load_host(self, model: ModelDef, step: int | None = None, *,
-                  offload_opt: bool = False):
+    def load_host(self, model: ModelDef, step: int | None = None):
         """(state, meta) of a checkpoint as global host numpy arrays, in the
         pytree structure of ``init_state_shapes`` — no device is touched.
         ``step=None`` loads the newest complete checkpoint."""
@@ -230,7 +202,7 @@ class Checkpointer:
         # rebuild the pytree structure from a template
         from repro.core.mics import init_state_shapes
 
-        template = init_state_shapes(model, offload_opt=offload_opt)
+        template = init_state_shapes(model)
         flat_t, treedef = jax.tree_util.tree_flatten(template)
         if len(flat_t) != len(leaves):
             raise ValueError(
@@ -242,50 +214,3 @@ class Checkpointer:
                     f"restore reshards pods/partition/replication freely but "
                     f"the TP degree is fixed (flat layouts are TP-local)")
         return jax.tree_util.tree_unflatten(treedef, leaves), meta
-
-    def _restore_stash(self, path: pathlib.Path, meta: dict,
-                       topo: MiCSTopology) -> dict:
-        """Import the host-stash leg of a checkpoint (offload_opt=True).
-
-        Same-topology only: the stash is keyed by the mesh-linearized
-        device index, and the shard *shapes* are per-topology, so a
-        cross-topology import would collide wrong-shaped arrays into the
-        live engine's reads.  On a topology mismatch the import is skipped,
-        stale sentinel entries are purged, and the reset is surfaced."""
-        from repro.core.hostoffload import (
-            CKPT_NAMESPACE, clear_namespace, import_stash,
-        )
-
-        info = {"present": (path / STASH_BLOB).exists(),
-                "restored": False, "reset": None}
-        here = {
-            "mesh_axes": dict(zip(topo.mesh.axis_names,
-                                  (int(s) for s in topo.mesh.devices.shape))),
-            "partition_axes": list(topo.partition_axes),
-        }
-        same_topo = (
-            {k: int(v) for k, v in meta.get("mesh_axes", {}).items()} ==
-            here["mesh_axes"]
-            and list(meta.get("partition_axes", [])) == here["partition_axes"])
-        if not info["present"]:
-            info["reset"] = "missing"
-            log.warning(
-                "offload_opt restore from %s: checkpoint has no host stash; "
-                "optimizer moments restart from zero", path.name)
-        elif not same_topo:
-            clear_namespace(CKPT_NAMESPACE)   # no stale wrong-shape entries
-            info["reset"] = "cross-topology"
-            log.warning(
-                "offload_opt restore from %s onto a different topology "
-                "(%s -> %s): host-stash optimizer moments are per-device and "
-                "do not reshard; restarting m/v from zero (params/step are "
-                "restored exactly)", path.name,
-                meta.get("mesh_axes"), here["mesh_axes"])
-        else:
-            blob = np.load(path / STASH_BLOB)
-            import_stash(
-                {tuple(int(x) for x in name[2:].split("_")): blob[name]
-                 for name in blob.files},
-                as_checkpoint=True)
-            info["restored"] = True
-        return info

@@ -23,8 +23,7 @@ module closes the loop: given a model, a MiCS topology and a link profile it
 4. **gates on memory** (``hbm_budget_gb``): every candidate is priced per
    device by the analytical HBM footprint model (core/memplan.py, the
    same predicted-vs-compiled discipline as the wire-byte census),
-   infeasible candidates are filtered from selection, the host-offloaded
-   carry joins the grid beside the re-gathering default, and
+   infeasible candidates are filtered from selection, and
    :func:`resolve_scale` implements the paper's §3.1 rule — the minimal
    partition-group size whose aggregate memory holds the model states.
 
@@ -198,13 +197,6 @@ def gather_stages(topology: str, topo: MiCSTopology,
     raise ValueError(f"unknown topology {topology!r}")
 
 
-def _carry(policy) -> str:
-    """The training carry a GatherPolicy or MiCSConfig asks for:
-    ``'host'`` under ``carry_offload='host'``, else ``'remat'``."""
-    return "host" if getattr(policy, "carry_offload", "none") == "host" \
-        else "remat"
-
-
 # ---------------------------------------------------------------------------
 # collective event counts per schedule
 # ---------------------------------------------------------------------------
@@ -232,13 +224,6 @@ def _event_counts(stack: int, s: int, *, scanned: bool, prefetch: bool,
       buffer as a backward residual — no backward re-gather
       (``s·stack + 1`` gathers) — and adds the prologue gather's adjoint
       per micro-step (``s·(stack+1)`` adjoints).
-    * ``carry='host'`` (``GatherPolicy.carry_offload='host'``) keeps the
-      stored forward's gather count (``s·stack + 1``) — the carry streams
-      to host memory instead of re-gathering — while its hand-rolled
-      backward contributes exactly one adjoint per layer (``s·stack``,
-      like remat: the prologue gather sits outside the custom VJP).  The
-      d2h/h2d stream itself is not wire traffic; ``cost_candidate`` prices
-      it on the profile's ``host`` tier.
     * embed/head pools are gathered outside the layer scans; the gather is
       loop-invariant across micro-steps, so XLA hoists it out of the micro
       loop entirely: ONE gather per step, however many micro-steps.
@@ -252,9 +237,6 @@ def _event_counts(stack: int, s: int, *, scanned: bool, prefetch: bool,
         if carry == "remat":
             ag = 2 * s * stack + 1    # prefetch fwd + backward re-gather
             rs = s * stack
-        elif carry == "host":
-            ag = s * stack + 1        # stored forward, host-resident carry
-            rs = s * stack            # one hand-rolled adjoint per layer
         else:
             ag = s * stack + 1
             rs = s * (stack + 1)
@@ -540,7 +522,6 @@ class Candidate:
             "boundary": self.boundary,
             "hop2_bucket_mb": self.hop2_bucket_mb,
             "clip_mode": self.clip_mode,
-            "carry_offload": self.gather.carry_offload,
             "n_hop2_buckets": self.n_hop2_buckets,
             "t_hop2_total_s": self.t_hop2_total_s,
             "t_hop2_exposed_s": self.t_hop2_exposed_s,
@@ -587,7 +568,7 @@ class Plan:
                 f"{'mem_GB':>7}") if serve else (
                 f"  {'rank':>4} {'topology':<12} {'inner':>5} {'wire':>5} "
                 f"{'hop1':>5} {'hop2':>5} {'sched':>6} {'bkt_MB':>6} "
-                f"{'clip':>6} {'carry':>6} "
+                f"{'clip':>6} "
                 f"{'t_comm_ms':>10} {'h2_exp_ms':>9} {'inter_MB':>9} "
                 f"{'mem_GB':>7}")
         rows = [f"autotune[{self.profile.name}] mode={self.mode}{budget} "
@@ -615,7 +596,7 @@ class Plan:
                 f"{str(c.gather.inner or '-'):>5} {c.gather.wire_dtype:>5} "
                 f"{c.sync.hop1_wire_dtype:>5} "
                 f"{c.sync.hop2_wire_dtype:>5} {sched:>6} {bkt:>6} "
-                f"{c.clip_mode:>6} {_carry(c.gather):>6} "
+                f"{c.clip_mode:>6} "
                 f"{c.t_comm_s * 1e3:>10.3f} "
                 f"{c.t_hop2_exposed_s * 1e3:>9.3f} "
                 f"{c.inter_wire_bytes / 1e6:>9.2f} "
@@ -644,10 +625,7 @@ def cost_candidate(
     enters ``t_comm_s`` — under the bucketed pipeline the hidden fraction
     overlaps boundary compute and no longer serializes the step, and the
     approx clip (``clip_mode='approx'``) additionally pipelines AdamW
-    under the collectives.  A host-offloaded carry
-    (``gather.carry_offload='host'``) adds a ``host_offload`` stage: the
-    2 x stack x flat_len bytes/micro-step each scanned pool streams over
-    the profile's host tier (the price of freeing that HBM)."""
+    under the collectives."""
     pred = predict_traffic(model, topo, gather, sync,
                            micro_steps=micro_steps, mode=mode,
                            profile=profile)
@@ -685,27 +663,6 @@ def cost_candidate(
         t_by_stage["reorder.copy"] = profile.copy_time(
             pred["local_copy_bytes"])
         total += t_by_stage["reorder.copy"]
-    if (mode == "train"
-            and getattr(gather, "carry_offload", "none") == "host"):
-        # d2h (forward put) + h2d (backward get) of every scanned pool's
-        # carried buffer, once per layer per micro-step.  Priced serially
-        # on the host tier — pessimistic (the streams overlap layer
-        # compute on a real DMA engine), which keeps host-carry rows from
-        # outranking in-HBM ones on time; they win only through the memory
-        # gate, which is their purpose.
-        cb = M._COMPUTE_BYTES[gather.wire_dtype]
-        host_bytes = 0.0
-        host_events = 0
-        scanned = {pl.name for pl in model.pools}
-        for name, (stack, _tp, flat_len) in \
-                model.global_flat_shapes().items():
-            if name in scanned and stack > 1:
-                host_bytes += 2.0 * micro_steps * stack * flat_len * cb
-                host_events += 2 * micro_steps * stack
-        if host_bytes:
-            t_by_stage["host_offload"] = profile.xfer_time(
-                "host", host_bytes, host_events)
-            total += t_by_stage["host_offload"]
     return Candidate(
         gather=gather, sync=sync, t_comm_s=total, t_by_stage=t_by_stage,
         bytes_by_stage=pred["by_stage"], inter_wire_bytes=inter_bytes,
@@ -847,7 +804,6 @@ def rank_policies(
     hbm_budget_gb: float | None = None,
     local_batch: int = 0,
     seq: int = 0,
-    offload_opt: bool = False,
     kv_ceiling: str = "bf16",
     kv_block_size: int = 16,
     serve_ctx: int = 0,
@@ -863,14 +819,13 @@ def rank_policies(
     the full ranking (including lossy rows) is kept for the dry-run table
     and BENCH artifacts.
 
-    Without ``hbm_budget_gb`` every training row re-gathers in the
-    backward (the ``remat`` carry).  ``hbm_budget_gb`` adds the memory
-    planner's gate (core/memplan.py): every candidate is priced per
-    device, the host-offloaded carry joins the grid, infeasible
-    candidates are excluded from selection (they stay in the ranking,
-    marked by their ``mem_bytes``), and
-    :class:`repro.core.memplan.MemoryBudgetError` is raised — never a
-    silently empty plan — when nothing numerics-eligible fits.
+    Every training row re-gathers in the backward (the ``remat`` carry).
+    ``hbm_budget_gb`` adds the memory planner's gate (core/memplan.py):
+    every candidate is priced per device, infeasible candidates are
+    excluded from selection (they stay in the ranking, marked by their
+    ``mem_bytes``), and :class:`repro.core.memplan.MemoryBudgetError` is
+    raised — never a silently empty plan — when nothing numerics-eligible
+    fits.
     ``local_batch``/``seq`` size the activation terms (0 = model states +
     comm buffers only).
 
@@ -878,12 +833,9 @@ def rank_policies(
     (``clip_mode`` column) but is selected only under
     ``allow_approx_clip`` — like the lossy wires, it changes numerics
     (one-bucket-stale clip factor) and must be opted into
-    (``MiCSConfig(clip_mode="approx")``).  ``offload_opt`` is a config
-    passthrough that shifts the m/v shards off-device in the footprint
-    pricing; it is not a ranked axis (it has no policy interaction).
+    (``MiCSConfig(clip_mode="approx")``).
     """
     profile = get_profile(profile)
-    carries = ("remat",) if hbm_budget_gb is None else ("remat", "host")
     serve = mode == "serve"
     # serving ranks the prefetch toggle itself (overlap vs serial gathers
     # changes the decode roofline); training takes it as a caller input.
@@ -896,68 +848,59 @@ def rank_policies(
                 boundary == "bucketed" and mode == "train"
                 and topo.replication_degree > 1) else ("exact",)
             for clip in clips:
-                for carry in carries:
-                    if carry == "host" and not (
-                            g.prefetch and mode == "train"):
-                        continue   # carries only differ with a backward
-                    g2 = dataclasses.replace(
-                        g, carry_offload="host" if carry == "host"
-                        else "none")
-                    c = cost_candidate(model, topo, profile, g2, s,
-                                       micro_steps=micro_steps, mode=mode,
-                                       boundary=boundary,
-                                       hop2_bucket_mb=bucket_mb,
-                                       clip_mode=clip)
-                    if serve:
-                        if getattr(model, "cfg", None) is None:
-                            # duck-typed planner stubs carry no attention
-                            # dims: rank the gather axes alone, without
-                            # the KV/residency grid (defaults sort these
-                            # by t_comm_s, the pre-KV serve behavior)
-                            mem = M.predict_footprint(
-                                model, topo, g2, s, mode="serve")
-                            cands.append(dataclasses.replace(
-                                c, mem_bytes=mem.total_bytes))
-                            continue
-                        # KV-dtype axis: residency from the free HBM after
-                        # the base footprint, decode step from the roofline.
-                        ctx = serve_ctx or DEFAULT_SERVE_CTX
-                        cap_bytes = hbm_budget_gb * GIB if hbm_budget_gb \
-                            else float(profile.hbm_bytes)
-                        for kv in KV_DTYPES:
-                            res = M.max_resident_requests(
-                                model, topo, g2, s, hbm_bytes=cap_bytes,
-                                ctx_len=ctx, kv_block_size=kv_block_size,
-                                kv_dtype=kv)
-                            if max_resident:
-                                res = min(res, max_resident)
-                            dec = cost_decode_step(
-                                model, topo, profile, g2,
-                                resident=max(res, 1), ctx_len=ctx,
-                                kv_dtype=kv, t_comm_s=c.t_comm_s)
-                            blocks = -(-ctx // kv_block_size)
-                            mem_kv = M.predict_footprint(
-                                model, topo, g2, s, mode="serve",
-                                kv_pages_tokens=res * blocks * kv_block_size,
-                                kv_dtype=kv)
-                            cands.append(dataclasses.replace(
-                                c, mem_bytes=mem_kv.total_bytes,
-                                kv_dtype=kv, resident_requests=res,
-                                t_decode_s=dec["t_step_s"],
-                                tokens_per_s=dec["tokens_per_s"]))
+                c = cost_candidate(model, topo, profile, g, s,
+                                   micro_steps=micro_steps, mode=mode,
+                                   boundary=boundary,
+                                   hop2_bucket_mb=bucket_mb,
+                                   clip_mode=clip)
+                if serve:
+                    if getattr(model, "cfg", None) is None:
+                        # duck-typed planner stubs carry no attention
+                        # dims: rank the gather axes alone, without
+                        # the KV/residency grid (defaults sort these
+                        # by t_comm_s, the pre-KV serve behavior)
+                        mem = M.predict_footprint(
+                            model, topo, g, s, mode="serve")
+                        cands.append(dataclasses.replace(
+                            c, mem_bytes=mem.total_bytes))
                         continue
-                    mem = M.predict_footprint(
-                        model, topo, g2, s, micro_steps=micro_steps,
-                        mode=mode, local_batch=local_batch, seq=seq,
-                        boundary=boundary, hop2_bucket_mb=bucket_mb,
-                        offload_opt=offload_opt and mode == "train")
-                    cands.append(dataclasses.replace(
-                        c, mem_bytes=mem.total_bytes))
+                    # KV-dtype axis: residency from the free HBM after
+                    # the base footprint, decode step from the roofline.
+                    ctx = serve_ctx or DEFAULT_SERVE_CTX
+                    cap_bytes = hbm_budget_gb * GIB if hbm_budget_gb \
+                        else float(profile.hbm_bytes)
+                    for kv in KV_DTYPES:
+                        res = M.max_resident_requests(
+                            model, topo, g, s, hbm_bytes=cap_bytes,
+                            ctx_len=ctx, kv_block_size=kv_block_size,
+                            kv_dtype=kv)
+                        if max_resident:
+                            res = min(res, max_resident)
+                        dec = cost_decode_step(
+                            model, topo, profile, g,
+                            resident=max(res, 1), ctx_len=ctx,
+                            kv_dtype=kv, t_comm_s=c.t_comm_s)
+                        blocks = -(-ctx // kv_block_size)
+                        mem_kv = M.predict_footprint(
+                            model, topo, g, s, mode="serve",
+                            kv_pages_tokens=res * blocks * kv_block_size,
+                            kv_dtype=kv)
+                        cands.append(dataclasses.replace(
+                            c, mem_bytes=mem_kv.total_bytes,
+                            kv_dtype=kv, resident_requests=res,
+                            t_decode_s=dec["t_step_s"],
+                            tokens_per_s=dec["tokens_per_s"]))
+                    continue
+                mem = M.predict_footprint(
+                    model, topo, g, s, micro_steps=micro_steps,
+                    mode=mode, local_batch=local_batch, seq=seq,
+                    boundary=boundary, hop2_bucket_mb=bucket_mb)
+                cands.append(dataclasses.replace(
+                    c, mem_bytes=mem.total_bytes))
     # modeled time first; among time-ties the smaller footprint wins.
-    # Exact clip and the re-gathering carry sort before approx/host on
-    # full ties — reference
-    # numerics and no host traffic unless they buy something.  Serving
-    # sorts by the decode roofline instead (throughput breaks ties).
+    # Exact clip sorts before approx on full ties — reference numerics
+    # unless approx buys something.  Serving sorts by the decode roofline
+    # instead (throughput breaks ties).
     if serve:
         cands.sort(key=lambda c: (c.t_decode_s, -c.tokens_per_s,
                                   c.t_comm_s, _KV_LOSS[c.kv_dtype],
@@ -969,8 +912,7 @@ def rank_policies(
                               c.sync.hop2_wire_dtype,
                               c.boundary, c.hop2_bucket_mb,
                               c.clip_mode != "exact",
-                              c.mem_bytes,
-                              c.gather.carry_offload != "none"))
+                              c.mem_bytes))
 
     def hop2_ok(c: Candidate) -> bool:
         wire = c.sync.hop2_wire_dtype
@@ -996,8 +938,8 @@ def rank_policies(
         raise M.MemoryBudgetError(
             f"no eligible policy fits hbm_budget_gb={hbm_budget_gb} on "
             f"p={topo.partition_size}: the smallest candidate "
-            f"({smallest.gather.topology}/{smallest.gather.wire_dtype}, "
-            f"carry={_carry(smallest.gather)!r}) needs "
+            f"({smallest.gather.topology}/{smallest.gather.wire_dtype}) "
+            f"needs "
             f"{smallest.mem_bytes / 1024**3:.3f} GiB per device; grow the "
             f"partition group (memplan.min_partition_size) or the budget")
     pool = feasible or eligible or cands
@@ -1026,9 +968,8 @@ def resolve_config(mcfg, model, topo: MiCSTopology, *,
     those fields are interpreted) reconstructs exactly the chosen policies.
 
     With ``mcfg.hbm_budget_gb`` set, the memory planner gates the ranking
-    (core/memplan.py): infeasible candidates are filtered out, the
-    host-offloaded carry joins the re-gathering default in the grid, and
-    a clear :class:`repro.core.memplan.MemoryBudgetError` is
+    (core/memplan.py): infeasible candidates are filtered out, and a
+    clear :class:`repro.core.memplan.MemoryBudgetError` is
     raised when nothing fits on this topology's partition group.  Use
     :func:`resolve_scale` to pick the partition-group *size* itself — the
     paper's §3.1 minimal-group rule.
@@ -1049,7 +990,6 @@ def resolve_config(mcfg, model, topo: MiCSTopology, *,
         allow_approx_clip=getattr(mcfg, "clip_mode", "exact") == "approx",
         hbm_budget_gb=getattr(mcfg, "hbm_budget_gb", None),
         local_batch=local_batch, seq=seq,
-        offload_opt=getattr(mcfg, "offload_opt", False),
         # serve axes: the configured kv_dtype is the numerics ceiling, the
         # configured residency (0 = planner-derived) caps the pool sizing
         kv_ceiling=getattr(mcfg, "kv_dtype", "bf16"),
@@ -1075,7 +1015,6 @@ def resolve_config(mcfg, model, topo: MiCSTopology, *,
         compress_hop2=(s.hop2_wire_dtype
                        if s.hop2_wire_dtype != "fp32" else False),
         hop1_wire_dtype=s.hop1_wire_dtype,
-        carry_offload=getattr(g, "carry_offload", "none"),
         boundary_schedule=plan.chosen.boundary,
         hop2_bucket_mb=plan.chosen.hop2_bucket_mb,
         clip_mode=plan.chosen.clip_mode,
@@ -1098,16 +1037,11 @@ def resolve_scale(model, mcfg, *, data_extent: int, mode: str = "train",
                   extra_replication: int = 1):
     """The paper's §3.1 scale-aware partitioning rule for ``MiCSConfig``.
 
-    Returns ``(partition_size, carry, mem_plan)`` — the *minimal*
-    partition-group size over a data axis of ``data_extent`` whose
-    predicted per-device footprint fits ``mcfg.hbm_budget_gb`` GiB, trying
-    the re-gathering default (``carry == "remat"``) first and the
-    host-offloaded carry (``carry == "host"`` ->
-    ``MiCSConfig(carry_offload="host")``) second at every size (a smaller
-    group beats a larger one: smaller groups keep collectives on faster
-    tiers, which is the whole point of scale-aware partitioning).  With ``mcfg.offload_opt`` the
-    m/v shards leave the footprint too, shrinking the minimal group
-    further.  Raises
+    Returns ``(partition_size, mem_plan)`` — the *minimal* partition-group
+    size over a data axis of ``data_extent`` whose predicted per-device
+    footprint fits ``mcfg.hbm_budget_gb`` GiB (a smaller group beats a
+    larger one: smaller groups keep collectives on faster tiers, which is
+    the whole point of scale-aware partitioning).  Raises
     :class:`repro.core.memplan.MemoryBudgetError` when even the full data
     axis (ZeRO-3 scale) does not fit.  ``extra_replication`` covers the
     data-parallel axes the group cannot span (pods, the dp2 leftover of a
@@ -1118,36 +1052,33 @@ def resolve_scale(model, mcfg, *, data_extent: int, mode: str = "train",
     if getattr(mcfg, "hbm_budget_gb", None) is None:
         raise ValueError("resolve_scale needs MiCSConfig.hbm_budget_gb")
     gp, sp = policies_from_config(mcfg)
-    carries = ("remat", "host") if gp.prefetch and mode == "train" \
-        else ("remat",)
     return M.min_partition_size(
         model, data_extent=data_extent, hbm_budget_gb=mcfg.hbm_budget_gb,
         gather=gp, sync=sp, micro_steps=mcfg.micro_steps, mode=mode,
         local_batch=local_batch, seq=seq,
         boundary=mcfg.boundary_schedule,
-        hop2_bucket_mb=mcfg.hop2_bucket_mb, carries=carries,
-        offload_opt=getattr(mcfg, "offload_opt", False) and mode == "train",
+        hop2_bucket_mb=mcfg.hop2_bucket_mb,
         extra_replication=extra_replication)
 
 
 def resolve_world(model, mcfg, *, n_devices: int, tp: int = 1,
                   partition_size: int | None = None, mode: str = "train",
                   local_batch: int = 0, seq: int = 0):
-    """Re-pick partition-group size + carry for an ``n_devices`` world.
+    """Re-pick the partition-group size for an ``n_devices`` world.
 
     The elastic train loop's policy half (runtime/train_loop.py calls this
     on every :class:`repro.core.faults.WorldChangeError` — pod loss or
     grow-back — before rebuilding the mesh): with ``mcfg.hbm_budget_gb``
     set it re-runs :func:`resolve_scale` so the degraded/grown world gets
-    the paper's §3.1 minimal-fitting group (and the carry mitigation that
-    rescued it); without a budget it keeps the previous ``partition_size``
-    where it still divides the new data extent, else the largest divisor
-    below it.  Everything here is analytic and deterministic, which is what
-    makes an in-loop resume bitwise-reproducible by a cold restore with the
-    same arguments (the kill-a-device contract, tests/elastic_harness.py).
+    the paper's §3.1 minimal-fitting group; without a budget it keeps the
+    previous ``partition_size`` where it still divides the new data extent,
+    else the largest divisor below it.  Everything here is analytic and
+    deterministic, which is what makes an in-loop resume
+    bitwise-reproducible by a cold restore with the same arguments (the
+    kill-a-device contract, tests/elastic_harness.py).
 
-    Returns ``(partition_size, mcfg2, info)`` where ``mcfg2`` carries the
-    chosen carry/offload fields and ``info`` is a ledger-friendly dict.
+    Returns ``(partition_size, mcfg, info)``: ``mcfg`` as given (the
+    rebuild paths take it back) and ``info``, a ledger-friendly dict.
     """
     if n_devices <= 0 or n_devices % max(tp, 1):
         raise ValueError(
@@ -1155,21 +1086,19 @@ def resolve_world(model, mcfg, *, n_devices: int, tp: int = 1,
             f"(flat layouts are TP-local: tp must divide the world)")
     data_extent = n_devices // max(tp, 1)
     if getattr(mcfg, "hbm_budget_gb", None) is not None:
-        p, carry, mem_plan = resolve_scale(
+        p, mem_plan = resolve_scale(
             model, mcfg, data_extent=data_extent, mode=mode,
             local_batch=local_batch, seq=seq)
-        mcfg2 = dataclasses.replace(
-            mcfg, carry_offload="host" if carry == "host" else "none")
-        info = {"rule": "resolve_scale", "carry": carry,
+        info = {"rule": "resolve_scale",
                 "hbm_budget_gb": mcfg.hbm_budget_gb,
                 "mem_gib": mem_plan.total_bytes / GIB}
     else:
         prefer = min(partition_size or data_extent, data_extent)
         p = max(d for d in range(1, prefer + 1) if data_extent % d == 0)
-        mcfg2, info = mcfg, {"rule": "keep", "carry": _carry(mcfg)}
+        info = {"rule": "keep"}
     info.update(partition_size=p, data_extent=data_extent, tp=tp,
                 n_devices=n_devices)
-    return p, mcfg2, info
+    return p, mcfg, info
 
 
 def rerank_serve_world(model, topo: MiCSTopology, mcfg, *, seq: int = 0,

@@ -47,7 +47,6 @@ from repro.core.topology import MODEL_AXIS, MiCSTopology, hierarchy_factors
 
 GATHER_TOPOLOGIES = ("flat", "inner_first", "outer_first")
 WIRE_DTYPES = ("fp32", "bf16", "int8")
-CARRY_OFFLOADS = ("none", "host")
 SYNC_MODES = ("2hop", "allreduce_slice")
 HOP1_WIRE_DTYPES = ("fp32", "bf16", "int8")
 HOP2_WIRE_DTYPES = ("fp32", "bf16", "int8")
@@ -63,32 +62,18 @@ class GatherPolicy:
     Under ``prefetch=True`` a training step keeps no gathered buffer for
     the backward pass: the backward re-issues each layer's gather
     (models/lm.py ``pool_route``, O(layers x shard) HBM).
-    ``carry_offload='host'`` instead keeps the stored carry's schedule (no
-    backward re-gather) and streams each layer's gathered buffer to host
-    memory in the forward and back to device in the backward
-    (core/hostoffload.py) — O(layers x shard) HBM too, priced as the link
-    model's host tier instead of an extra all-gather.
     """
 
     topology: str = "inner_first"  # 'flat' | 'inner_first' | 'outer_first'
     wire_dtype: str = "bf16"       # 'fp32' | 'bf16' | 'int8' (ZeRO++ qwZ)
     inner: int | None = None       # intra-"node" factor for staged gathers
     prefetch: bool = True          # one-slot lookahead layer scan
-    carry_offload: str = "none"    # 'none' | 'host' (d2h/h2d carry stream)
 
     def __post_init__(self):
         if self.topology not in GATHER_TOPOLOGIES:
             raise ValueError(f"unknown gather topology {self.topology!r}")
         if self.wire_dtype not in WIRE_DTYPES:
             raise ValueError(f"unknown wire dtype {self.wire_dtype!r}")
-        if self.carry_offload not in CARRY_OFFLOADS:
-            raise ValueError(
-                f"unknown carry_offload {self.carry_offload!r} "
-                f"(expected one of {CARRY_OFFLOADS})")
-        if self.carry_offload == "host" and not self.prefetch:
-            raise ValueError(
-                "carry_offload='host' requires prefetch=True (it offloads "
-                "the prefetch schedule's carried buffer)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,7 +133,6 @@ def policies_from_config(mcfg) -> tuple[GatherPolicy, SyncPolicy]:
         wire_dtype=wire,
         inner=mcfg.hierarchy_inner,
         prefetch=getattr(mcfg, "prefetch", True),
-        carry_offload=getattr(mcfg, "carry_offload", "none"),
     )
     hop2 = mcfg.compress_hop2  # bool (legacy) or wire-dtype string
     if hop2 is True:
@@ -192,8 +176,6 @@ class CommEngine:
             quantized=False, seeded=True)
         self._quant_gather_vjp_seeded = self._build_gather_vjp(
             quantized=True, seeded=True)
-        self._host_stash = None     # lazy (hostoffload.HostStash)
-        self._carry_tags: dict = {}  # pool name -> stash tag
 
     # -- construction -------------------------------------------------------
     @classmethod
@@ -209,41 +191,8 @@ class CommEngine:
         return self.gather_policy.prefetch
 
     @property
-    def carry_offload(self) -> str:
-        return self.gather_policy.carry_offload
-
-    @property
     def partition_size(self) -> int:
         return self.topo.partition_size
-
-    @property
-    def host_stash(self):
-        """Lazy host-memory stash bound to this topology's mesh — the
-        d2h/h2d stream backing ``carry_offload='host'`` and the offloaded
-        optimizer moments (core/hostoffload.py)."""
-        if self._host_stash is None:
-            from repro.core.hostoffload import HostStash
-
-            self._host_stash = HostStash(
-                tuple(zip(self.topo.mesh.axis_names,
-                          self.topo.mesh.devices.shape)))
-        return self._host_stash
-
-    def carry_tag(self, pool_name: str) -> int:
-        """Stable per-engine stash tag for a pool's offloaded carry."""
-        from repro.core.hostoffload import TAG_CARRY_BASE
-
-        if pool_name not in self._carry_tags:
-            self._carry_tags[pool_name] = TAG_CARRY_BASE + len(self._carry_tags)
-        return self._carry_tags[pool_name]
-
-    def gather_out_dtype(self):
-        """Dtype of :meth:`gather_flat`'s full buffer (the wire dtype for
-        float wires, the compute dtype for the int8 wire)."""
-        gp = self.gather_policy
-        if gp.wire_dtype == "int8":
-            return jnp.dtype(self.compute_dtype)
-        return jnp.dtype(_WIRE_JNP[gp.wire_dtype])
 
     def describe(self) -> dict:
         """Static policy record (dry-run artifacts, BENCH json)."""
@@ -415,26 +364,6 @@ class CommEngine:
 
     def gather(self, pool, row, *, seed=None) -> dict[str, jax.Array]:
         return self.unflatten(pool, self.gather_flat(row, seed=seed))
-
-    def gather_flat_adjoint(self, ct: jax.Array, *, seed=None) -> jax.Array:
-        """The standalone hop-1 adjoint of :meth:`gather_flat`: full-buffer
-        cotangent in, fp32 shard cotangent out.
-
-        Composes exactly what autodiff of ``gather_flat`` composes —
-        the custom-VJP backward (:meth:`_adjoint`, including the bf16/int8
-        hop-1 wire variants) plus the transpose of the outer wire-dtype
-        cast back to the fp32 row — *without* re-running the gather
-        forward.  The host-offload carry's hand-rolled backward
-        (models/lm.py) needs precisely this: it already holds the full
-        buffer (streamed back from the host), so ``jax.vjp`` of the gather
-        would re-issue the all-gather for nothing.
-        """
-        gp = self.gather_policy
-        if gp.wire_dtype == "int8":
-            if self.topo.partition_size == 1:   # forward was a pure cast
-                return ct.astype(jnp.float32)
-            return self._adjoint(ct.astype(jnp.float32), seed=seed)
-        return self._adjoint(ct, seed=seed).astype(jnp.float32)
 
     # -- gradient synchronization ------------------------------------------
     @jax.named_scope(scopes.HOP1)

@@ -23,13 +23,11 @@ the paper's analysis uses (§2.3, Fig 2) and enough to reproduce its
 flat-vs-hierarchical crossovers; finer hierarchies can be expressed by
 registering custom profiles per pool.
 
-A third, non-network tier prices the device<->host link (PCIe / DMA): the
-``host`` Link costs the d2h/h2d streams of ``carry_offload='host'`` and
-``offload_opt=True`` (core/hostoffload.py) in the same α-β units, so the
-autotuner can weigh "offload the carry and shrink the partition group"
-against the network cost of the bigger group — the §3.1 scale-aware trade
-extended to host memory.  Host transfers are point-to-point, not rings:
-cost one stream of n bytes as ``alpha + n / bandwidth`` (``xfer_time``).
+A third, non-network tier describes the device<->host link (PCIe / DMA)
+in the same α-β units.  Host transfers are point-to-point, not rings: one
+stream of n bytes costs ``alpha + n / bandwidth`` (``xfer_time``).  No
+schedule of the program moves state over it; ``tools/fit_profile.py``
+still fits it from ledgers that carry ``tier='host'`` stages.
 
 This module is dependency-free (no jax) so every layer of the tree can
 import it without cycles.

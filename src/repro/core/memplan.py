@@ -238,7 +238,6 @@ def predict_footprint(
     seq: int = 0,
     boundary: str = "bucketed",
     hop2_bucket_mb: float = 32.0,
-    offload_opt: bool = False,
     kv_pages_tokens: int = 0,
     kv_dtype: str = "bf16",
     decode_batch: int = 0,
@@ -253,16 +252,6 @@ def predict_footprint(
     activation-checkpoint and logits terms; pass 0 to price model states
     and communication buffers only (what ``resolve_config`` does — the
     dry-run passes the real shapes).  All byte counts are per device.
-
-    Host offload shifts bytes out of this budget: with
-    ``gather.carry_offload='host'`` the stored prefetch carry's
-    O(stack x flat_len) residual leaves HBM (only the rotated shard copy
-    and a transient full buffer remain; remat keeps only the latter), and with
-    ``offload_opt=True`` the fp32 ``m``/``v`` shards leave the donated
-    arguments entirely (2 x state shard bytes), replaced by a transient
-    staging term for the shards streamed back during the boundary.  The
-    *time* cost of those streams is priced by the autotuner against the
-    link model's ``host`` tier — this module only accounts bytes.
     """
     p = max(int(topo.partition_size), 1)
     repl = max(int(getattr(topo, "replication_degree", 1)), 1)
@@ -275,10 +264,8 @@ def predict_footprint(
               for name, (stack, _tp, flat_len) in shapes.items()}
     s4 = float(sum(shard4.values()))          # one fp32 state copy / device
 
-    # -- arguments (exact): fp32 params (+ m + v unless host-offloaded)
-    # shards, step scalar, batch --
-    state_copies = 1.0 if offload_opt else 3.0
-    args = state_copies * s4 + 4.0 if train else s4
+    # -- arguments (exact): fp32 params + m + v shards, step scalar, batch --
+    args = 3.0 * s4 + 4.0 if train else s4
     if train and local_batch and seq:
         # tokens + targets (int32) + mask (f32), stacked over micro-steps
         args += micro_steps * local_batch * seq * 12.0
@@ -356,11 +343,9 @@ def predict_footprint(
 
     # -- prefetch-carry backward residual, per pool route (models/lm.py) --
     # remat: one transient re-gathered buffer (its forward reads the
-    # lookahead row in place).  Host offload: that plus the rotated shard
-    # copy its forward scans; the stacked residual streams to host memory.
-    # stored (enc-dec decoder pools, which read the encoder output): the
-    # stacked carried buffer persists at fp32 (observed: the adjoint
-    # accumulation dtype) + the rotated shard copy.
+    # lookahead row in place).  stored (enc-dec decoder pools, which read
+    # the encoder output): the stacked carried buffer persists at fp32
+    # (observed: the adjoint accumulation dtype) + the rotated shard copy.
     from repro.models.lm import train_route
 
     cfg = getattr(model, "cfg", None)
@@ -373,8 +358,6 @@ def predict_footprint(
         rolled = stack * math.ceil(flat_len / p) * 4
         if route == "stored":
             add("prefetch_carry", stack * flat_len * 4 + rolled)
-        elif route == "host":
-            add("prefetch_carry", rolled + flat_len * cb)
         else:
             add("prefetch_carry", flat_len * cb)
 
@@ -407,17 +390,6 @@ def predict_footprint(
     if sync.hop1_wire_dtype == "int8" and p > 1:
         add("qgz_scratch", max_flat * QGZ_SCRATCH_BYTES_PER_ELEM)
 
-    # -- host-offloaded optimizer staging ----------------------------------
-    # The m/v shards of the pool being updated stream back for the AdamW
-    # update (core/schedule.py fetches per pool under the exact clip, per
-    # bucket under approx).  They add NO temp bytes: the fetched moments
-    # land after the boundary's reduced-gradient buffers retire, and XLA's
-    # buffer assigner reuses those slots (verified against
-    # memory_analysis() in tests/memplan_harness.py::offload_lowers_peak —
-    # pricing a 2x-max-shard staging term there overshoots the compiled
-    # temps by exactly that amount), so offload_opt only shrinks the
-    # argument bytes above.
-
     return MemPlan(components=comp, args_bytes=args, mode=mode)
 
 
@@ -446,23 +418,18 @@ def min_partition_size(
     seq: int = 0,
     boundary: str = "bucketed",
     hop2_bucket_mb: float = 32.0,
-    carries: tuple = ("remat",),
-    offload_opt: bool = False,
     extra_replication: int = 1,
-) -> tuple[int, str, MemPlan]:
+) -> tuple[int, MemPlan]:
     """The paper's scale-aware partitioning rule, analytically.
 
     Walks partition-group sizes ascending (divisors of ``data_extent`` —
     the mesh axis the partition group is carved from) and returns the
-    first ``(p, carry, plan)`` whose predicted per-device footprint fits
-    ``hbm_budget_gb`` GiB — the *minimal* group that fits, trying each
-    entry of ``carries`` in order at every size (``"remat"``, the
-    training default, and ``"host"``, the carry streamed to host memory,
-    ``GatherPolicy.carry_offload='host'``, skipped when the gather policy
-    does not prefetch).  ``extra_replication`` multiplies the
-    replication degree for data-parallel axes the group cannot span (the
-    pod axis of a multi-pod mesh, the dp2 leftover of tp < model axis) so
-    hop-2 staging is priced even when p == data_extent.  Raises
+    first ``(p, plan)`` whose predicted per-device footprint fits
+    ``hbm_budget_gb`` GiB — the *minimal* group that fits.
+    ``extra_replication`` multiplies the replication degree for
+    data-parallel axes the group cannot span (the pod axis of a multi-pod
+    mesh, the dp2 leftover of tp < model axis) so hop-2 staging is priced
+    even when p == data_extent.  Raises
     :class:`MemoryBudgetError` when even the whole data axis (ZeRO-3
     scale) does not fit — never a silent empty plan.
     """
@@ -472,24 +439,19 @@ def min_partition_size(
         grid = DeviceGrid(
             partition_size=p,
             replication_degree=(data_extent // p) * max(extra_replication, 1))
-        for carry in carries:
-            if carry == "host" and not gather.prefetch:
-                continue
-            g2 = dataclasses.replace(
-                gather, carry_offload="host" if carry == "host" else "none")
-            plan = predict_footprint(
-                model, grid, g2, sync, micro_steps=micro_steps, mode=mode,
-                local_batch=local_batch, seq=seq, boundary=boundary,
-                hop2_bucket_mb=hop2_bucket_mb, offload_opt=offload_opt)
-            if best is None or plan.total_bytes < best[2].total_bytes:
-                best = (p, carry, plan)
-            if plan.total_bytes <= budget:
-                return p, carry, plan
+        plan = predict_footprint(
+            model, grid, gather, sync, micro_steps=micro_steps, mode=mode,
+            local_batch=local_batch, seq=seq, boundary=boundary,
+            hop2_bucket_mb=hop2_bucket_mb)
+        if best is None or plan.total_bytes < best[1].total_bytes:
+            best = (p, plan)
+        if plan.total_bytes <= budget:
+            return p, plan
     assert best is not None
     raise MemoryBudgetError(
         f"no partition group fits hbm_budget_gb={hbm_budget_gb}: the "
-        f"smallest candidate (p={best[0]}, carry={best[1]!r}) "
-        f"needs {best[2].total_gb:.3f} GiB per device "
-        f"(args {best[2].args_bytes / GIB:.3f} + "
-        f"temp {best[2].temp_bytes / GIB:.3f}); raise the budget, shrink "
+        f"smallest candidate (p={best[0]}) "
+        f"needs {best[1].total_gb:.3f} GiB per device "
+        f"(args {best[1].args_bytes / GIB:.3f} + "
+        f"temp {best[1].temp_bytes / GIB:.3f}); raise the budget, shrink "
         f"the model, or grow the mesh")

@@ -89,9 +89,6 @@ class MiCSConfig:
     hop2_bucket_mb: float = 32.0        # fixed-byte hop-2 pipeline bucket
     clip_mode: str = "exact"            # 'exact' global-norm barrier |
     #                                     'approx' one-bucket-stale pipeline
-    carry_offload: str = "none"         # 'none' | 'host' prefetch-carry
-    #                                     d2h/h2d stream (core/hostoffload.py)
-    offload_opt: bool = False           # AdamW m/v shards live in host memory
     hbm_budget_gb: float | None = None  # per-device HBM budget (GiB) the
     #                                     memory planner gates policies on
     kv_dtype: str = "bf16"              # paged-KV block dtype: 'fp32' | 'bf16'
@@ -102,10 +99,7 @@ class MiCSConfig:
     #                                     0 = derive from the memory planner
 
     def __post_init__(self):
-        from repro.core.comm import (
-            CARRY_OFFLOADS, GRAD_ROUNDINGS, HOP1_WIRE_DTYPES,
-            HOP2_WIRE_DTYPES,
-        )
+        from repro.core.comm import GRAD_ROUNDINGS, HOP1_WIRE_DTYPES, HOP2_WIRE_DTYPES
 
         if self.policy not in ("manual", "auto"):
             raise ValueError(f"unknown policy {self.policy!r} "
@@ -124,14 +118,6 @@ class MiCSConfig:
             raise ValueError(
                 "clip_mode='approx' requires boundary_schedule='bucketed' "
                 "(the approximate clip is a property of the bucket pipeline)")
-        if self.carry_offload not in CARRY_OFFLOADS:
-            raise ValueError(
-                f"unknown carry_offload {self.carry_offload!r} "
-                f"(expected one of {CARRY_OFFLOADS})")
-        if self.carry_offload == "host" and not self.prefetch:
-            raise ValueError(
-                "carry_offload='host' requires prefetch=True (it offloads "
-                "the prefetch schedule's carried buffer)")
         if self.hbm_budget_gb is not None and self.hbm_budget_gb <= 0:
             raise ValueError(
                 f"hbm_budget_gb must be > 0, got {self.hbm_budget_gb}")
@@ -165,42 +151,27 @@ class MiCSConfig:
 # state containers + shardings
 # ---------------------------------------------------------------------------
 
-def init_state_shapes(model: ModelDef, *,
-                      offload_opt: bool = False) -> dict[str, Any]:
-    """Global ShapeDtypeStructs for params/m/v/step (no allocation).
-
-    With ``offload_opt=True`` the AdamW moments live in the host stash
-    (core/hostoffload.py), not the device state: ``m``/``v`` are absent.
-    """
+def init_state_shapes(model: ModelDef) -> dict[str, Any]:
+    """Global ShapeDtypeStructs for params/m/v/step (no allocation)."""
     shapes = model.global_flat_shapes()
     flat = {
         name: jax.ShapeDtypeStruct(shape, jnp.float32)
         for name, shape in shapes.items()
     }
-    out = {
-        "params": flat,
-        "step": jax.ShapeDtypeStruct((), jnp.int32),
-    }
-    if not offload_opt:
-        out["m"], out["v"] = dict(flat), dict(flat)
-    return out
+    return {"params": flat, "m": dict(flat), "v": dict(flat),
+            "step": jax.ShapeDtypeStruct((), jnp.int32)}
 
 
-def state_pspecs(model: ModelDef, topo: MiCSTopology, *,
-                 offload_opt: bool = False) -> dict[str, Any]:
+def state_pspecs(model: ModelDef, topo: MiCSTopology) -> dict[str, Any]:
     pool_spec = P(None, MODEL_AXIS, topo.partition_axes)
     flat = {name: pool_spec for name in model.global_flat_shapes()}
-    out = {"params": flat, "step": P()}
-    if not offload_opt:
-        out["m"], out["v"] = dict(flat), dict(flat)
-    return out
+    return {"params": flat, "m": dict(flat), "v": dict(flat), "step": P()}
 
 
-def state_shardings(model: ModelDef, topo: MiCSTopology, *,
-                    offload_opt: bool = False):
+def state_shardings(model: ModelDef, topo: MiCSTopology):
     return jax.tree.map(
         lambda spec: NamedSharding(topo.mesh, spec),
-        state_pspecs(model, topo, offload_opt=offload_opt),
+        state_pspecs(model, topo),
         is_leaf=lambda x: isinstance(x, P),
     )
 
@@ -220,8 +191,7 @@ def batch_pspecs(model: ModelDef, topo: MiCSTopology, *, micro: bool = True):
     return base
 
 
-def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0, *,
-               offload_opt: bool = False):
+def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0):
     """Materialize sharded fp32 state (for runnable-scale models).
 
     The init is computed on a single device and distributed with
@@ -239,7 +209,7 @@ def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0, *,
     are zeros and are made in place: zeros are exact under any partitioning.
     """
     shapes = model.global_flat_shapes()
-    shardings = state_shardings(model, topo, offload_opt=offload_opt)
+    shardings = state_shardings(model, topo)
 
     def _init(key):
         import zlib
@@ -261,17 +231,12 @@ def init_state(model: ModelDef, topo: MiCSTopology, seed: int = 0, *,
     params = {name: jax.block_until_ready(
         jax.device_put(drawn.pop(name), shardings["params"][name]))
         for name in list(drawn)}
-    out = {"params": params,
-           "step": jax.device_put(jnp.int32(0), shardings["step"])}
-    if not offload_opt:
-        # Offloaded moments zero-init lazily in the host stash instead
-        # (HostStash.get(..., or_zeros=True) on first boundary).
-        zeros = jax.jit(
-            lambda: {name: jnp.zeros(shape, jnp.float32)
-                     for name, shape in shapes.items()},
-            out_shardings=shardings["params"])
-        out["m"], out["v"] = zeros(), zeros()
-    return out
+    zeros = jax.jit(
+        lambda: {name: jnp.zeros(shape, jnp.float32)
+                 for name, shape in shapes.items()},
+        out_shardings=shardings["params"])
+    return {"params": params, "m": zeros(), "v": zeros(),
+            "step": jax.device_put(jnp.int32(0), shardings["step"])}
 
 
 # ---------------------------------------------------------------------------
@@ -337,7 +302,7 @@ def build_train_step(
         # identical either way (tests/schedule_harness.py).
         new_params, new_m, new_v, gnorm = apply_boundary(
             boundary, comm, model, topo, oc, state, grads, denom,
-            seed=state["step"], offload_opt=mcfg.offload_opt)
+            seed=state["step"])
         step = state["step"]
 
         # The boundary's one other reduction: the step's mean loss.
@@ -349,12 +314,11 @@ def build_train_step(
                 **{k: lax.pmean(v / s, topo.data_axes)
                    for k, v in count_sums.items()},
             }
-        new_state = {"params": new_params, "step": step + 1}
-        if not mcfg.offload_opt:
-            new_state["m"], new_state["v"] = new_m, new_v
+        new_state = {"params": new_params, "m": new_m, "v": new_v,
+                     "step": step + 1}
         return new_state, metrics
 
-    st_specs = state_pspecs(model, topo, offload_opt=mcfg.offload_opt)
+    st_specs = state_pspecs(model, topo)
     b_specs = batch_pspecs(model, topo)
     m_specs = {k: P() for k in ("loss", "aux", "grad_norm", *counters)}
     sharded = shard_map(

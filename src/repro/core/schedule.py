@@ -66,16 +66,6 @@ grows; a tiny-LM convergence smoke (tests/schedule_harness.py) bounds the
 end-to-end effect — final loss within ``APPROX_CLIP_LOSS_RTOL`` of the
 exact reference with clipping engaged.
 
-**Host-offloaded optimizer shards** (``offload_opt=True``).  The AdamW
-``m``/``v`` shards are touched exactly once per boundary, so both
-schedules can stream them from host memory around the update
-(core/hostoffload.py: ordered-io_callback d2h/h2d stash, lazily
-zero-initialized) instead of keeping them HBM-resident — the state dict
-then carries only ``params``/``step`` and the memory planner subtracts
-``2 × 4`` bytes/element from the per-device footprint.  The params
-trajectory is bitwise unchanged (the fetched moments are bitwise the
-stored ones).
-
 **The int8 decompress leg** (qgZ follow-on).  With
 ``SyncPolicy.hop2_wire_dtype='int8'`` each hop-2 payload runs as a
 block-quantized all-reduce (``collectives.quantized_all_reduce``: int8 +
@@ -97,7 +87,6 @@ from jax import lax
 
 from repro import scopes
 from repro.core.flat_param import partition_buckets
-from repro.core.hostoffload import TAG_M, TAG_V
 from repro.core.topology import MODEL_AXIS, MiCSTopology
 from repro.optim.adamw import OptConfig, adamw_shard_update
 
@@ -292,7 +281,7 @@ def _bucket_masks(pool, ref: BucketRef, shard_coord, shard_len: int):
 
 
 def _apply_boundary_approx(plan, comm, model, topo, oc, state, grads,
-                           denom, seed, offload_opt):
+                           denom, seed):
     """The approximate-clip software pipeline (module docstring).
 
     Per plan-order bucket *i*: issue bucket *i*'s hop-2, then (while it is
@@ -310,19 +299,18 @@ def _apply_boundary_approx(plan, comm, model, topo, oc, state, grads,
     shard_coord = comm.partition_coord()
     pools = {p.name: p for p in model.all_pools()}
     norm_axes = topo.partition_axes + (MODEL_AXIS,)
-    stash = comm.host_stash if offload_opt else None
 
     flat_state = {}
     for name in plan.shard_elems:
         flat_state[name] = {
             "p": state["params"][name].reshape(-1),
-            "m": None if offload_opt else state["m"][name].reshape(-1),
-            "v": None if offload_opt else state["v"][name].reshape(-1),
+            "m": state["m"][name].reshape(-1),
+            "v": state["v"][name].reshape(-1),
             "shard_len": grads[name].shape[-1],
         }
     out = {name: {"p": [], "m": [], "v": []} for name in plan.shard_elems}
 
-    def update(i, ref, g_bucket, running_sq):
+    def update(ref, g_bucket, running_sq):
         """Bucket ``ref``'s AdamW with the clip factor from ``running_sq``
         (the stale prefix norm — or the complete one at the drain)."""
         fs = flat_state[ref.pool]
@@ -330,57 +318,38 @@ def _apply_boundary_approx(plan, comm, model, topo, oc, state, grads,
         clip = jnp.minimum(1.0, oc.clip_norm / jnp.maximum(gnorm_i, 1e-12))
         grad_scale = clip / denom
         p_in = lax.slice_in_dim(fs["p"], ref.lo, ref.hi, axis=0)
-        if offload_opt:
-            m_in = stash.get(TAG_M, i, (ref.elems,), jnp.float32,
-                             or_zeros=True, ordered=False)
-            v_in = stash.get(TAG_V, i, (ref.elems,), jnp.float32,
-                             or_zeros=True, ordered=False)
-        else:
-            m_in = lax.slice_in_dim(fs["m"], ref.lo, ref.hi, axis=0)
-            v_in = lax.slice_in_dim(fs["v"], ref.lo, ref.hi, axis=0)
+        m_in = lax.slice_in_dim(fs["m"], ref.lo, ref.hi, axis=0)
+        v_in = lax.slice_in_dim(fs["v"], ref.lo, ref.hi, axis=0)
         dm, pm = _bucket_masks(pools[ref.pool], ref, shard_coord,
                                fs["shard_len"])
         p_new, m_new, v_new = adamw_shard_update(
             p_in, g_bucket, m_in, v_in, state["step"], oc,
             decay_mask=dm, pad_mask=pm, grad_scale=grad_scale)
         out[ref.pool]["p"].append(p_new)
-        if offload_opt:
-            # Unordered: the put operand depends on the get via the AdamW
-            # update, so dataflow already sequences the pair; ordered
-            # callbacks here deadlock against the hop-2 psum rendezvous on
-            # the multi-device CPU runtime.  The tokens MUST reach the
-            # computation's outputs (folded into gnorm below): a put whose
-            # token is dropped stalls the runtime the same way.
-            put_toks.append(stash.put(TAG_M, i, m_new, ordered=False))
-            put_toks.append(stash.put(TAG_V, i, v_new, ordered=False))
-        else:
-            out[ref.pool]["m"].append(m_new)
-            out[ref.pool]["v"].append(v_new)
+        out[ref.pool]["m"].append(m_new)
+        out[ref.pool]["v"].append(v_new)
 
     running_sq = jnp.float32(0.0)
     sq_local = jnp.float32(0.0)   # exact path's canonical left-fold — the
     #                               returned metric is bitwise identical to
     #                               the exact schedule's grad_norm
-    put_toks = []
-    pending = None  # (bucket index, BucketRef, in-flight reduced bucket)
+    pending = None  # (BucketRef, in-flight reduced bucket)
     for i, ref in enumerate(plan.buckets):
         raw = lax.slice_in_dim(flat_grads[ref.pool], ref.lo, ref.hi, axis=0)
         in_flight = comm.hop2_bucketed(raw, salt=i, seed=seed)
         if pending is not None:
-            j, pref, pbucket = pending
-            update(j, pref, pbucket, running_sq)   # stale: through bucket j-1
+            pref, pbucket = pending
+            update(pref, pbucket, running_sq)   # stale: through bucket i-2
             running_sq = running_sq + lax.psum(_sq(pbucket), norm_axes)
             sq_local = sq_local + _sq(pbucket)
-        pending = (i, ref, in_flight)
+        pending = (ref, in_flight)
     if pending is not None:  # drain: complete norm for the final bucket
-        j, pref, pbucket = pending
+        pref, pbucket = pending
         running_sq = running_sq + lax.psum(_sq(pbucket), norm_axes)
         sq_local = sq_local + _sq(pbucket)
-        update(j, pref, pbucket, running_sq)
+        update(pref, pbucket, running_sq)
 
     gnorm = jnp.sqrt(lax.psum(sq_local, norm_axes)) / denom
-    if put_toks:    # keep the d2h puts live (value is always 0)
-        gnorm = gnorm + sum(put_toks).astype(jnp.float32) * 0.0
 
     new_params, new_m, new_v = {}, {}, {}
     for name in plan.shard_elems:
@@ -392,14 +361,12 @@ def _apply_boundary_approx(plan, comm, model, topo, oc, state, grads,
 
         if not out[name]["p"]:         # empty pool: nothing to update
             new_params[name] = state["params"][name]
-            if not offload_opt:
-                new_m[name] = state["m"][name]
-                new_v[name] = state["v"][name]
+            new_m[name] = state["m"][name]
+            new_v[name] = state["v"][name]
             continue
         new_params[name] = cat(out[name]["p"])
-        if not offload_opt:
-            new_m[name] = cat(out[name]["m"])
-            new_v[name] = cat(out[name]["v"])
+        new_m[name] = cat(out[name]["m"])
+        new_v[name] = cat(out[name]["v"])
     return new_params, new_m, new_v, gnorm
 
 
@@ -414,7 +381,6 @@ def apply_boundary(
     grads: dict,
     denom: float,
     seed=None,
-    offload_opt: bool = False,
 ):
     """Run one gradient-accumulation boundary under ``plan``.
 
@@ -427,14 +393,11 @@ def apply_boundary(
     ``'approx'`` pipelines each bucket's update under the next bucket's
     hop-2 with a one-bucket-stale clip factor (module docstring).  ``seed``
     (the traced step counter) feeds the int8 hop-2 wire's stochastic-
-    rounding dither; float wires ignore it.  ``offload_opt=True`` streams
-    the AdamW ``m``/``v`` shards through the host stash (lazy zero-init)
-    instead of the state dict — ``new_m``/``new_v`` come back empty and the
-    params trajectory is bitwise unchanged.
+    rounding dither; float wires ignore it.
     """
     if plan.mode == "bucketed" and plan.clip_mode == "approx":
         return _apply_boundary_approx(plan, comm, model, topo, oc, state,
-                                      grads, denom, seed, offload_opt)
+                                      grads, denom, seed)
     flat_grads = {
         name: grads[name].reshape(-1) for name in plan.shard_elems
     }
@@ -454,35 +417,17 @@ def apply_boundary(
 
     # ---- AdamW on fp32 shards, clip scale folded in -----------------------
     shard_coord = comm.partition_coord()
-    stash = comm.host_stash if offload_opt else None
     new_params, new_m, new_v = {}, {}, {}
-    put_toks = []
-    for pool_idx, pool in enumerate(model.all_pools()):
+    for pool in model.all_pools():
         name = pool.name
         g = reduced[name].reshape(grads[name].shape)
         shard_len = g.shape[-1]
         start = shard_coord * shard_len
         dm = pool.layout.decay_mask_for_shard(start, shard_len)
         pm = pool.layout.padding_mask_for_shard(start, shard_len)
-        if offload_opt:
-            m_in = stash.get(TAG_M, pool_idx, g.shape, jnp.float32,
-                             or_zeros=True, ordered=False)
-            v_in = stash.get(TAG_V, pool_idx, g.shape, jnp.float32,
-                             or_zeros=True, ordered=False)
-        else:
-            m_in, v_in = state["m"][name], state["v"][name]
         p, m, v = adamw_shard_update(
-            state["params"][name], g, m_in, v_in,
+            state["params"][name], g, state["m"][name], state["v"][name],
             state["step"], oc, decay_mask=dm, pad_mask=pm,
             grad_scale=grad_scale)
-        new_params[name] = p
-        if offload_opt:
-            # Unordered: dataflow (get -> AdamW -> put) sequences the pair;
-            # tokens fold into gnorm to stay live (_apply_boundary_approx).
-            put_toks.append(stash.put(TAG_M, pool_idx, m, ordered=False))
-            put_toks.append(stash.put(TAG_V, pool_idx, v, ordered=False))
-        else:
-            new_m[name], new_v[name] = m, v
-    if put_toks:
-        gnorm = gnorm + sum(put_toks).astype(jnp.float32) * 0.0
+        new_params[name], new_m[name], new_v[name] = p, m, v
     return new_params, new_m, new_v, gnorm

@@ -7,7 +7,7 @@ collective census (roofline inputs) to artifacts/dryrun/<cell>.json.
 
 Communication policy: all collectives run through the CommEngine
 (core/comm.py).  The manual flags (--gather-order, --quant-gather,
---prefetch, --carry-offload, ...) map 1:1 onto its
+--prefetch, --clip-mode, ...) map 1:1 onto its
 GatherPolicy/SyncPolicy; ``--policy auto`` instead hands the choice to the
 link-model autotuner (core/autotune.py), which prints the ranked candidate
 table for the ``--link-profile`` and records the chosen plan — plus a
@@ -19,8 +19,7 @@ footprint next to XLA's compiled ``memory_analysis()``
 (plan-vs-compiled, core/memplan.py).  ``--hbm-budget-gb`` additionally
 applies the paper's §3.1 rule — the minimal partition group whose
 predicted footprint fits — when no --partition-size is pinned, and gates
-``--policy auto`` candidates on feasibility (with the host-offloaded
-carry joining the grid).  Training cells
+``--policy auto`` candidates on feasibility.  Training cells
 additionally record the boundary scheduler's bucket plan
 (``--boundary-schedule`` / ``--hop2-bucket-mb``, core/schedule.py) with
 the link model's predicted exposed-vs-hidden hop-2 time and the measured
@@ -122,18 +121,16 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
             and not zero3:
         # the paper's §3.1 rule, analytically: minimal partition group
         # whose predicted per-device footprint fits the budget
-        # (core/memplan.py); the chosen carry rides along.
+        # (core/memplan.py).
         sizing_model = build_model(cfg, tp=tp or 16)
         # the partition group is carved from the 16-wide data axis; pods
         # and the dp2 leftover of a narrow tp replicate on top of it
         extra_repl = (2 if multi_pod else 1) * (16 // (tp or 16))
-        partition_size, carry, scale_plan = resolve_scale(
+        partition_size, scale_plan = resolve_scale(
             sizing_model, mcfg, data_extent=16,
             mode="train" if spec["kind"] == "train" else "serve",
             extra_replication=extra_repl)
-        mcfg = dataclasses.replace(
-            mcfg, carry_offload="host" if carry == "host" else "none")
-        print(f"memplan: p={partition_size} carry={carry} "
+        print(f"memplan: p={partition_size} "
               f"({scale_plan.total_gb:.2f} GiB predicted vs budget "
               f"{mcfg.hbm_budget_gb:g} GiB)", flush=True)
     topo = make_mics_topology(
@@ -211,7 +208,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
     if spec["kind"] == "train":
         step = build_train_step(model, topo, mcfg,
                                 OptConfig(total_steps=1000))
-        state = init_state_shapes(model, offload_opt=mcfg.offload_opt)
+        state = init_state_shapes(model)
         batch = mics_train_inputs(model, spec["seq"], spec["global_batch"])
         lowered = step.lower(state, batch)
     elif spec["kind"] == "prefill":
@@ -254,8 +251,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, mcfg: MiCSConfig,
         mode="train" if spec["kind"] == "train" else "serve",
         local_batch=lb, seq=spec["seq"],
         boundary=mcfg.boundary_schedule,
-        hop2_bucket_mb=mcfg.hop2_bucket_mb,
-        offload_opt=mcfg.offload_opt)
+        hop2_bucket_mb=mcfg.hop2_bucket_mb)
     record["memplan"] = mem_plan.describe()
     record["memplan"]["hbm_budget_gb"] = mcfg.hbm_budget_gb
     if scale_plan is not None:
@@ -363,17 +359,6 @@ def main():
                     help="1 = double-buffered lookahead gathers (layer i+1 "
                          "gathered during layer i's compute; the default), "
                          "0 = serial reference schedule")
-    ap.add_argument("--carry-offload", default="none",
-                    choices=["none", "host"],
-                    help="'host' keeps each layer's gathered buffer for the "
-                         "backward in host memory over the link model's "
-                         "host tier (d2h forward / h2d backward, "
-                         "core/hostoffload.py) instead of re-gathering it "
-                         "in the backward; 'none' re-gathers")
-    ap.add_argument("--offload-opt", action="store_true",
-                    help="host-offload the AdamW m/v shards around the "
-                         "boundary update: the on-device state keeps only "
-                         "params+step (memplan subtracts 8 bytes/element)")
     ap.add_argument("--clip-mode", default="exact",
                     choices=["exact", "approx"],
                     help="boundary clip: 'exact' = barriered global-norm "
@@ -418,8 +403,6 @@ def main():
         compress_hop2=(False if args.compress_hop2 == "off"
                        else args.compress_hop2),
         prefetch=bool(args.prefetch),
-        carry_offload=args.carry_offload,
-        offload_opt=args.offload_opt,
         clip_mode=args.clip_mode,
         policy=args.policy,
         link_profile=args.link_profile,

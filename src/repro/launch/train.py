@@ -93,13 +93,12 @@ def build_training(cfg: ArchConfig, topo: MiCSTopology, mcfg: MiCSConfig, *,
     mem = memplan.predict_footprint(
         model, topo, gp, sp, micro_steps=mcfg.micro_steps, mode="train",
         local_batch=lb, seq=seq, boundary=mcfg.boundary_schedule,
-        hop2_bucket_mb=mcfg.hop2_bucket_mb, offload_opt=mcfg.offload_opt)
+        hop2_bucket_mb=mcfg.hop2_bucket_mb)
     routes = " ".join(f"{name}={route}" for name, route
                       in lm.train_routes(model, gp).items())
     paths = lm.attention_paths(model, seq)
     print(f"memplan: {mem.total_gb:.3f} GiB predicted per device "
-          f"(carry_offload={mcfg.carry_offload}, "
-          f"offload_opt={mcfg.offload_opt}) routes: {routes} "
+          f"routes: {routes} "
           f"attention: kernel={paths['kernel']} jnp={paths['jnp']}")
     oc = OptConfig(lr_max=lr, total_steps=steps,
                    warmup_steps=max(steps // 20, 1))
@@ -149,18 +148,6 @@ def main():
     ap.add_argument("--prefetch", type=int, default=1,
                     help="1 = double-buffered lookahead gathers (default), "
                          "0 = serial reference schedule")
-    ap.add_argument("--carry-offload", default="none",
-                    choices=["none", "host"],
-                    help="'host' keeps each layer's gathered buffer for the "
-                         "backward in host memory (d2h in the forward, h2d "
-                         "in the backward, core/hostoffload.py) instead of "
-                         "re-gathering it in the backward; priced on the "
-                         "link model's host tier")
-    ap.add_argument("--offload-opt", action="store_true",
-                    help="host-offload the AdamW m/v shards: the state dict "
-                         "keeps only params+step, moments stream through "
-                         "the host stash around the boundary update "
-                         "(bitwise-identical params trajectory)")
     ap.add_argument("--clip-mode", default="exact",
                     choices=["exact", "approx"],
                     help="boundary global-norm clip: 'exact' is the "
@@ -171,9 +158,8 @@ def main():
                          "permits rather than forces approx)")
     ap.add_argument("--hbm-budget-gb", type=float, default=0,
                     help="per-device HBM budget in GiB: the memory planner "
-                         "gates --policy auto candidates on it and falls "
-                         "back to the remat carry when the stored one "
-                         "does not fit; 0 = no budget")
+                         "gates --policy auto candidates on it; 0 = no "
+                         "budget")
     ap.add_argument("--boundary-schedule", default="bucketed",
                     choices=["serial", "bucketed"],
                     help="gradient-accumulation boundary: bucketed hop-2 "
@@ -196,8 +182,6 @@ def main():
                       quant_gather=args.quant_gather,
                       hop1_wire_dtype=args.hop1_wire_dtype,
                       prefetch=bool(args.prefetch),
-                      carry_offload=args.carry_offload,
-                      offload_opt=args.offload_opt,
                       clip_mode=args.clip_mode,
                       policy=args.policy,
                       link_profile=args.link_profile,

@@ -37,14 +37,6 @@ A pool's route (:func:`pool_route`) follows from what it can observe:
   remat VJP cannot run take it: serving pools (caches, no backward) and
   enc-dec decoder pools (their encoder output carries gradient that a
   custom VJP closure would drop).
-* ``'host'`` (``GatherPolicy.carry_offload='host'``,
-  :func:`_apply_pool_prefetch_offload`) — the stored carry's schedule,
-  with each layer's gathered buffer streamed down to host memory
-  (core/hostoffload.py) as soon as the next layer's gather is in flight
-  and back right before that layer's recompute: no re-gather, no
-  O(layers x flat_len) HBM residual, at the price of 2 x layers x
-  flat_len bytes over the host link per micro-step (the ``host`` tier of
-  the link model, core/linkmodel.py).
 * ``'serial'`` — no prefetch, or a one-layer pool.
 
 Why training re-gathers rather than keeping the stored residual: on a
@@ -131,20 +123,18 @@ def _row(x, idx=(0,)):
 def pool_route(stack: int, comm, *, serving: bool = False,
                enc_out: bool = False) -> str:
     """The schedule :func:`_apply_pool` runs a pool of ``stack`` layers
-    on: ``'serial'``, ``'stored'``, ``'remat'`` or ``'host'`` (the module
-    docstring says what each keeps for the backward).
+    on: ``'serial'``, ``'stored'`` or ``'remat'`` (the module docstring
+    says what each keeps for the backward).
 
-    ``comm`` is the CommEngine or its GatherPolicy (``prefetch``,
-    ``carry_offload``); ``serving`` says the pool scans its caches (no
-    backward), ``enc_out`` that it reads the encoder output (gradient a
-    custom VJP closure would drop).  Both keep the stored carry.
+    ``comm`` is the CommEngine or its GatherPolicy (``prefetch``);
+    ``serving`` says the pool scans its caches (no backward), ``enc_out``
+    that it reads the encoder output (gradient a custom VJP closure would
+    drop).  Both keep the stored carry.
     """
     if not (getattr(comm, "prefetch", False) and stack > 1):
         return "serial"
     if serving or enc_out:
         return "stored"
-    if getattr(comm, "carry_offload", "none") == "host":
-        return "host"
     return "remat"
 
 
@@ -219,8 +209,6 @@ def _apply_pool(
     """
     route = pool_route(pool.stack, comm, serving=caches is not None,
                        enc_out=ctx.enc_out is not None)
-    if route == "host":
-        return _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm)
     if route == "remat":
         return _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm)
     if route == "stored":
@@ -396,102 +384,6 @@ def _apply_pool_prefetch_remat(pool, flat_rows, x, ctx, comm):
         with jax.named_scope(scopes.CARRY):
             ct_x, d_rows = lax.scan(body, ct_x, (flat_rows, x_ins),
                                     reverse=True)
-        return ct_x, d_rows
-
-    scan_fn.defvjp(scan_fwd, scan_bwd)
-    x, aux = scan_fn(x, flat_rows)
-    return x, aux, None
-
-
-def _apply_pool_prefetch_offload(pool, flat_rows, x, ctx, comm):
-    """Double-buffered prefetch whose stored carry lives in HOST memory
-    (``GatherPolicy.carry_offload='host'``).
-
-    The forward is the *same* double-buffered scan as
-    :func:`_apply_pool_prefetch` — same gathers on the same shards in the
-    same order, bitwise-identical losses — but each layer's carried
-    gathered buffer is streamed down to the host stash
-    (core/hostoffload.py) right after the next layer's gather is issued,
-    so the backward residual kept on device is only the stacked layer
-    inputs (the activation checkpoint every schedule keeps).  The backward
-    is a hand-rolled reverse scan that streams each buffer back up
-    (h2d), re-linearizes the layer under ``jax.checkpoint`` from the
-    *identical* bytes the forward computed, and pushes the full-buffer
-    cotangent through :meth:`CommEngine.gather_flat_adjoint` — the exact
-    same staged hop-1 reduce-scatter adjoint the stored schedule's VJP
-    runs, so gradients too are bitwise identical to the stored carry's.
-
-    Versus the alternatives: no re-gather per layer (unlike ``'remat'``),
-    no O(layers x flat_len) HBM residual (unlike ``'stored'``); the cost
-    is 2 x layers x flat_len bytes over the host link per micro-step,
-    priced by the autotuner as the link model's ``host`` tier.
-    """
-    seed = ctx.step_seed
-    stash = comm.host_stash
-    tag = comm.carry_tag(pool.name)
-    s_local = jax.tree.leaves(flat_rows)[0].shape[-1]
-    full_len = s_local * comm.partition_size
-    full_dtype = comm.gather_out_dtype()
-
-    @jax.checkpoint
-    def layer_from_full(full, x_in):
-        """One layer from its restored gathered buffer (no collective)."""
-        tensors = comm.unflatten(pool, full)
-        (x_out, aux), _ = pool.apply(tensors, x_in, ctx, None)
-        return x_out, aux
-
-    def fwd_scan(x, flat_rows, store):
-        with jax.named_scope(scopes.CARRY):
-            nxt_rows = jax.tree.map(
-                lambda a: jnp.roll(a, -1, axis=0), flat_rows)
-        cur0 = comm.gather_flat(_row(flat_rows, (0, 0)), seed=seed)
-
-        def body(carry, xs):
-            i, nxt_row = xs
-            xc, aux_tot, cur, tok = carry
-            nxt = comm.gather_flat(_row(nxt_row), seed=seed)  # layer i+1
-            if store:
-                tok = tok + stash.put(tag, i, cur)            # d2h stream
-            tensors = comm.unflatten(pool, cur)
-            (x_out, aux), _ = pool.apply(tensors, xc, ctx, None)
-            return (x_out, aux_tot + aux, nxt, tok), xc       # stash input
-
-        with jax.named_scope(scopes.CARRY):
-            (x_out, aux, _, tok), x_ins = lax.scan(
-                body, (x, _aux0(pool), cur0, jnp.int32(0)),
-                (jnp.arange(pool.stack), nxt_rows))
-        return (x_out, aux), tok, x_ins
-
-    @jax.custom_vjp
-    def scan_fn(x, flat_rows):
-        # Primal-only calls never populate the stash (store=False): with no
-        # backward pass there is no consumer to pop the buffers.
-        return fwd_scan(x, flat_rows, store=False)[0]
-
-    def scan_fwd(x, flat_rows):
-        # The summed put token MUST ride the residuals and feed the
-        # backward's gets: custom_vjp's partial-eval DCEs even ordered
-        # io_callbacks whose outputs escape nowhere (observed on the CPU
-        # backend), so an unthreaded token means no d2h puts at all.
-        out, tok, x_ins = fwd_scan(x, flat_rows, store=True)
-        return out, (tok, x_ins)
-
-    def scan_bwd(res, cts):
-        tok, x_ins = res
-        ct_x, ct_aux = cts
-
-        def body(ct_x, xs):
-            i, x_in = xs
-            full = stash.get(tag, i + 0 * tok,
-                             (full_len,), full_dtype)          # h2d stream
-            _, vjp = jax.vjp(layer_from_full, full, x_in)
-            d_full, d_x = vjp((ct_x, ct_aux))
-            d_row = comm.gather_flat_adjoint(d_full, seed=seed)
-            return d_x, d_row[None, :]
-
-        with jax.named_scope(scopes.CARRY):
-            ct_x, d_rows = lax.scan(
-                body, ct_x, (jnp.arange(pool.stack), x_ins), reverse=True)
         return ct_x, d_rows
 
     scan_fn.defvjp(scan_fwd, scan_bwd)

@@ -120,8 +120,7 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
             try:
                 with TraceAnnotation("train.save"):
                     ckpt.save(state, step, topo=topo_cur, data_cursor=cursor,
-                              blocking=blocking, emergency=emergency,
-                              host_stash=_stash_snapshot(mcfg_cur))
+                              blocking=blocking, emergency=emergency)
                 return True
             except Exception as e:  # noqa: BLE001 - failure domain boundary
                 stats.save_failures += 1
@@ -131,13 +130,11 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
 
     start = ckpt.latest_step()
     if start is not None:
-        state, meta = ckpt.restore(model, topo_cur,
-                                   offload_opt=mcfg_cur.offload_opt)
+        state, meta = ckpt.restore(model, topo_cur)
         cursor = meta["data_cursor"]
         log.info("resumed from step %d", start)
     else:
-        state = init_state(model, topo_cur, seed=lc.seed,
-                           offload_opt=mcfg_cur.offload_opt)
+        state = init_state(model, topo_cur, seed=lc.seed)
         cursor = 0
 
     ewma = None
@@ -188,12 +185,10 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
                     partition_size=topo_cur.partition_size)
                 step_fn = build_train_step(model, topo_cur, mcfg_cur, oc)
                 if ckpt.latest_step() is not None:
-                    state, meta = ckpt.restore(model, topo_cur,
-                                               offload_opt=mcfg_cur.offload_opt)
+                    state, meta = ckpt.restore(model, topo_cur)
                     cursor = meta["data_cursor"]
                 else:
-                    state = init_state(model, topo_cur, seed=lc.seed,
-                                       offload_opt=mcfg_cur.offload_opt)
+                    state = init_state(model, topo_cur, seed=lc.seed)
                     cursor = 0
                 step = int(np.asarray(state["step"]))
                 world = new_world
@@ -218,13 +213,11 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
                 log.warning("step %d failed (%s); rolling back", step, e)
                 prev = ckpt.latest_step()
                 if prev is not None:
-                    state, meta = ckpt.restore(model, topo_cur,
-                                               offload_opt=mcfg_cur.offload_opt)
+                    state, meta = ckpt.restore(model, topo_cur)
                     cursor = meta["data_cursor"]
                     step = int(np.asarray(state["step"]))
                 else:
-                    state = init_state(model, topo_cur, seed=lc.seed,
-                                       offload_opt=mcfg_cur.offload_opt)
+                    state = init_state(model, topo_cur, seed=lc.seed)
                     cursor = 0
                     step = 0
                 continue
@@ -259,15 +252,6 @@ def train(model: ModelDef, topo: MiCSTopology, mcfg: MiCSConfig,
     return stats
 
 
-def _stash_snapshot(mcfg: MiCSConfig):
-    """The offloaded-moment half of the state when ``offload_opt=True``."""
-    if not mcfg.offload_opt:
-        return None
-    from repro.core.hostoffload import export_stash
-
-    return export_stash()
-
-
 def resize_for_world(model, mcfg: MiCSConfig, n_devices: int, *, tp: int = 1,
                      partition_size: int | None = None,
                      local_batch: int = 0, seq: int = 0
@@ -276,7 +260,7 @@ def resize_for_world(model, mcfg: MiCSConfig, n_devices: int, *, tp: int = 1,
 
     The one rebuild path both the in-loop world-change handler and a cold
     :func:`elastic_restart` share, so the two are bitwise-interchangeable:
-    ``autotune.resolve_world`` re-picks partition-group size + carry
+    ``autotune.resolve_world`` re-picks the partition-group size
     (§3.1 re-run on the survivors under ``mcfg.hbm_budget_gb``; without a
     budget the previous ``partition_size`` is kept where it divides), then
     the survivors are re-meshed contiguously
@@ -301,7 +285,6 @@ def elastic_restart(checkpoint_dir: str, cfg, new_topo: MiCSTopology,
     """
     model = build_model(cfg, tp=new_topo.model_size)
     ckpt = Checkpointer(checkpoint_dir)
-    state, meta = ckpt.restore(model, new_topo, step,
-                               offload_opt=mcfg.offload_opt)
+    state, meta = ckpt.restore(model, new_topo, step)
     step_fn = build_train_step(model, new_topo, mcfg, oc)
     return model, state, step_fn, meta

@@ -20,8 +20,7 @@ Checks:
                         gathers, s*stack adjoints), stored, which enc-dec
                         decoder pools take (s*stack + 1 gathers,
                         s*(stack+1) adjoints; forced here by
-                        harness_util.stored_carry), and host
-                        (s*stack + 1 gathers, s*stack adjoints)
+                        harness_util.stored_carry)
   census_match_multi    multi-axis ('pod','shard') partition group: the
                         outer stage is the pod hop, bytes match both stage
                         orders
@@ -91,14 +90,13 @@ def check(name):
 
 
 def _mcfg(topology: str, wire: str, prefetch: bool = False,
-          hop1: str = "fp32", carry_offload: str = "none") -> MiCSConfig:
+          hop1: str = "fp32") -> MiCSConfig:
     return MiCSConfig(
         micro_steps=MICRO,
         hierarchical=topology != "flat",
         gather_order=topology if topology != "flat" else "inner_first",
         prefetch=prefetch,
         hop1_wire_dtype=hop1,
-        carry_offload=carry_offload,
         **_WIRE_MCFG[wire],
     )
 
@@ -116,12 +114,12 @@ def _measure(model, topo, mcfg, *, global_batch=16, seq=16):
 
 
 def _assert_match(model, topo, topology, wire, *, prefetch=False,
-                  hop1="fp32", carry_offload="none", tag=""):
-    mcfg = _mcfg(topology, wire, prefetch, hop1, carry_offload)
+                  hop1="fp32", tag=""):
+    mcfg = _mcfg(topology, wire, prefetch, hop1)
     measured = _measure(model, topo, mcfg)["by_stage"]
     pred = predict_traffic(
         model, topo,
-        GatherPolicy(topology, wire, None, prefetch, carry_offload),
+        GatherPolicy(topology, wire, None, prefetch),
         SyncPolicy(hop1_wire_dtype=hop1),
         micro_steps=MICRO, upcast_float_collectives=True,
     )["by_stage"]
@@ -163,12 +161,11 @@ def _census_single():
 def _census_prefetch():
     model, topo = _single_axis()
     detail = {}
-    for carry in ("remat", "stored", "host"):
+    for carry in ("remat", "stored"):
         with stored_carry() if carry == "stored" \
                 else contextlib.nullcontext():
             detail[carry] = _assert_match(
                 model, topo, "inner_first", "bf16", prefetch=True,
-                carry_offload="host" if carry == "host" else "none",
                 tag=f"prefetch/{carry}")
     RESULTS["census_match_prefetch_detail"] = detail
 

@@ -42,11 +42,9 @@ Checks:
                             and the retried save restores the cadence.
   reshard_roundtrip         save -> restore -> save across p=2 -> p=4 ->
                             p=2 topologies is bitwise lossless.
-  offload_cross_topology    ``offload_opt=True`` restore onto a different
-                            topology resets the host-stashed moments
-                            EXPLICITLY (meta["host_stash"], warning) and
-                            training continues; same-topology restore
-                            re-imports them.
+  reshard_moments           the AdamW moments after two real steps at p=2
+                            (non-zero m and v) restore at p=4 bitwise
+                            equal, gathered, in the p=4 shardings.
 """
 
 import os
@@ -56,7 +54,6 @@ os.environ["XLA_FLAGS"] = (
     + os.environ.get("XLA_FLAGS", "")
 )
 
-import dataclasses
 import hashlib
 import json
 import sys
@@ -74,9 +71,8 @@ from repro.core import memplan as M
 from repro.core.autotune import resolve_scale
 from repro.core.comm import policies_from_config
 from repro.core.faults import FaultPlan
-from repro.core.hostoffload import export_stash, stash_clear, stash_size
 from repro.core.linkmodel import GIB
-from repro.core.mics import MiCSConfig, build_train_step, init_state
+from repro.core.mics import MiCSConfig, build_train_step, init_state, state_shardings
 from repro.core.topology import MiCSTopology, elastic_host_topology, make_host_mesh
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models.build import build_model
@@ -128,28 +124,23 @@ def _tree_equal(a, b, msg=""):
 
 
 # ---------------------------------------------------------------------------
-# budget: picked so the §3.1 rule has a real decision to make — p=2 with the
-# default carry fits in BOTH worlds (8 and 4 devices at tp=2), while p=1
-# overflows under every carry.  Computed from the footprint model, never
-# hardcoded.
+# budget: picked so the §3.1 rule has a real decision to make — p=2 fits in
+# BOTH worlds (8 and 4 devices at tp=2), while p=1 overflows.  Computed
+# from the footprint model, never hardcoded.
 # ---------------------------------------------------------------------------
 
 def _pick_budget(model, mcfg, extents):
     gp, sp = policies_from_config(mcfg)
-    carries = ("remat", "host") if gp.prefetch else ("remat",)
 
-    def fp(p, extent, carry):
-        g2 = dataclasses.replace(
-            gp, carry_offload="host" if carry == "host" else "none")
+    def fp(p, extent):
         grid = M.DeviceGrid(partition_size=p, replication_degree=extent // p)
         return M.predict_footprint(
-            model, grid, g2, sp, micro_steps=mcfg.micro_steps,
+            model, grid, gp, sp, micro_steps=mcfg.micro_steps,
             boundary=mcfg.boundary_schedule,
-            hop2_bucket_mb=mcfg.hop2_bucket_mb,
-            offload_opt=mcfg.offload_opt).total_bytes
+            hop2_bucket_mb=mcfg.hop2_bucket_mb).total_bytes
 
-    need = max(fp(2, e, "remat") for e in extents)           # p=2 must fit
-    cap = min(fp(1, e, c) for e in extents for c in carries)  # p=1 must not
+    need = max(fp(2, e) for e in extents)   # p=2 must fit
+    cap = min(fp(1, e) for e in extents)    # p=1 must not
     assert need < cap, f"no separating budget: p2={need} p1={cap}"
     return (need + cap) / 2 / GIB, need / GIB, cap / GIB
 
@@ -255,9 +246,8 @@ def _repick():
     # a direct resolve_scale call for each world must agree with the loop
     for extent, wc in ((2, CTX["kill_stats"].world_changes[0]),
                        (4, CTX["grow_stats"].world_changes[0])):
-        p, carry, plan = resolve_scale(MODEL2, MCFG_B, data_extent=extent)
+        p, plan = resolve_scale(MODEL2, MCFG_B, data_extent=extent)
         assert p == wc["partition_size"], (extent, p, wc)
-        assert carry == wc["carry"], (extent, carry, wc)
         assert plan.total_bytes <= BUDGET_GB * GIB
     # and the budget genuinely separates the candidates
     assert FP_P2_GIB < BUDGET_GB < FP_P1_GIB
@@ -396,39 +386,31 @@ def _reshard_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-@check("offload_cross_topology")
-def _offload_cross_topology():
-    d = tempfile.mkdtemp(prefix="elastic_offload_")
+@check("reshard_moments")
+def _reshard_moments():
+    # the AdamW moments of a trained state survive p=2 -> p=4 bitwise
+    d = tempfile.mkdtemp(prefix="elastic_moments_")
     model = build_model(CFG, tp=1)
     topo_p2 = elastic_host_topology(4, 2, tp=1)
-    mcfg = MiCSConfig(micro_steps=2, offload_opt=True)
-    stash_clear()
-    state = init_state(model, topo_p2, seed=3, offload_opt=True)
-    step_fn = build_train_step(model, topo_p2, mcfg, OC)
-    state, _ = _run_cold(step_fn, state, range(2))   # populate m/v stash
-    assert stash_size() > 0
-    ck = Checkpointer(d)
-    ck.save(state, 2, topo=topo_p2, data_cursor=2, host_stash=export_stash())
-
-    # same topology: the offloaded moments come back
-    stash_clear()
-    _, meta = ck.restore(model, topo_p2, offload_opt=True)
-    assert meta["host_stash"] == {
-        "present": True, "restored": True, "reset": None}, meta["host_stash"]
-    assert stash_size() > 0
-
-    # different topology: EXPLICIT reset — surfaced in meta, training runs on
-    stash_clear()
     topo_p4 = elastic_host_topology(4, 4, tp=1)
-    state4, meta4 = ck.restore(model, topo_p4, offload_opt=True)
-    hs = meta4["host_stash"]
-    assert hs["present"] and not hs["restored"], hs
-    assert hs["reset"] == "cross-topology", hs
-    step4 = build_train_step(model, topo_p4, mcfg, OC)
-    state4, losses = _run_cold(step4, state4, range(2, 4))
-    assert all(np.isfinite(losses)), losses
-    RESULTS["offload_detail"] = {"same_topo": meta["host_stash"],
-                                 "cross_topo": hs}
+    step_fn = build_train_step(model, topo_p2, MiCSConfig(micro_steps=2), OC)
+    state, _ = _run_cold(step_fn, init_state(model, topo_p2, seed=3),
+                         range(2))
+    saved = {k: jax.device_get(state[k]) for k in ("m", "v")}
+    for k in saved:
+        for name, a in saved[k].items():
+            assert np.any(a), f"{k}[{name}] is still zero after two steps"
+    ck = Checkpointer(d)
+    ck.save(state, 2, topo=topo_p2, data_cursor=2)
+    state4, meta = ck.restore(model, topo_p4)
+    assert meta["mesh_axes"]["shard"] == 2      # provenance: saved at p=2
+    want = state_shardings(model, topo_p4)
+    for k in saved:
+        _tree_equal(saved[k], jax.device_get(state4[k]),
+                    f"{k} changed across p=2 -> p=4")
+        for name, a in state4[k].items():
+            assert a.sharding.is_equivalent_to(want[k][name], a.ndim), \
+                (k, name, a.sharding, want[k][name])
 
 
 # ---------------------------------------------------------------------------

@@ -34,13 +34,6 @@ Checks:
                         remat keeps the double-buffered forward (the
                         residual it drops is what remat_lowers_peak
                         measures)
-  offload_lowers_peak   carry_offload='host' lowers BOTH the predicted and
-                        the XLA-compiled temp bytes vs the stored carry,
-                        and offload_opt=True additionally shrinks the
-                        donated argument bytes (the m/v shards leave HBM)
-                        — with the predicted-vs-compiled contract (args
-                        exact, temp within MEM_RTOL) holding on every
-                        offload cell
 """
 
 import os
@@ -91,9 +84,9 @@ def _build(mesh_dims, part, repl, **mcfg_kw):
     return model, topo, mcfg, step
 
 
-def _compile(model, step, offload_opt=False):
+def _compile(model, step):
     return step.lower(
-        init_state_shapes(model, offload_opt=offload_opt),
+        init_state_shapes(model),
         make_batch_shapes(model, GLOBAL_BATCH, SEQ, MICRO),
     ).compile()
 
@@ -107,7 +100,7 @@ def _carry(carry: str):
 def _footprint_cell(tag, mesh_dims, part, repl, **mcfg_kw):
     """One predicted-vs-compiled cell; returns its ledger row."""
     model, topo, mcfg, step = _build(mesh_dims, part, repl, **mcfg_kw)
-    compiled = _compile(model, step, offload_opt=mcfg.offload_opt)
+    compiled = _compile(model, step)
     ma = compiled.memory_analysis()
     gp, sp = policies_from_config(mcfg)
     n_dev = int(np.prod(mesh_dims))
@@ -115,7 +108,7 @@ def _footprint_cell(tag, mesh_dims, part, repl, **mcfg_kw):
     plan = M.predict_footprint(
         model, topo, gp, sp, micro_steps=MICRO, mode="train",
         local_batch=local_batch, seq=SEQ, boundary=mcfg.boundary_schedule,
-        hop2_bucket_mb=mcfg.hop2_bucket_mb, offload_opt=mcfg.offload_opt)
+        hop2_bucket_mb=mcfg.hop2_bucket_mb)
     args_m = ma.argument_size_in_bytes
     temp_m = ma.temp_size_in_bytes
     row = {
@@ -250,35 +243,6 @@ def _carried_buffer_census():
     # which remat_lowers_peak measures via the compiled temp bytes.
     assert by_carry["remat"]["carried_all_gathers"] > 0
     RESULTS["carried_buffer_census_detail"] = by_carry
-
-
-# ---------------------------------------------------------------------------
-@check("offload_lowers_peak")
-def _offload_lowers_peak():
-    rows = {
-        "host_carry": _footprint_cell(
-            "offload/host_carry", *BASE, carry_offload="host"),
-        "host_carry_opt": _footprint_cell(
-            "offload/host_carry_opt", *BASE, carry_offload="host",
-            offload_opt=True),
-    }
-    with _carry("stored"):
-        rows["stored"] = _footprint_cell("offload/stored", *BASE)
-    s, hc, ho = rows["stored"], rows["host_carry"], rows["host_carry_opt"]
-    # the freed carry residual: predicted AND compiled temp bytes drop
-    assert hc["predicted_temp_bytes"] < s["predicted_temp_bytes"], rows
-    assert hc["measured_temp_bytes"] < s["measured_temp_bytes"], rows
-    # offloaded moments leave the donated args (8 bytes/shard element);
-    # _footprint_cell already asserted predicted args == compiled args
-    assert ho["predicted_args_bytes"] < s["predicted_args_bytes"], rows
-    assert ho["measured_args_bytes"] < s["measured_args_bytes"], rows
-    # and the end-to-end peak (args + temps) shrinks on both ledgers
-    for r in (hc, ho):
-        assert r["predicted_args_bytes"] + r["predicted_temp_bytes"] \
-            < s["predicted_args_bytes"] + s["predicted_temp_bytes"], rows
-        assert r["measured_args_bytes"] + r["measured_temp_bytes"] \
-            < s["measured_args_bytes"] + s["measured_temp_bytes"], rows
-    RESULTS["offload_lowers_peak_detail"] = rows
 
 
 # the memplan suite's matrix cells (one contract cell per named check)

@@ -268,9 +268,8 @@ def test_plan_table_and_describe_serializable():
 
 
 def test_clip_and_carry_axes_ranked():
-    """The approx-clip and host-carry axes join the ranked grid: approx
-    rows on every bucketed train candidate (repl > 1), remat/host carry
-    rows whenever a budget prices the grid — and neither outranks the
+    """The approx-clip axis joins the ranked grid: approx rows on every
+    bucketed train candidate (repl > 1) — and it does not outrank the
     reference numerics without its opt-in."""
     prof = custom_profile("axes-slow", intra_bw=100e9, inter_bw=1e9,
                           node_size=8)
@@ -279,8 +278,6 @@ def test_clip_and_carry_axes_ranked():
     assert {c.clip_mode for c in plan.candidates} == {"exact", "approx"}
     assert all(c.boundary == "bucketed" for c in plan.candidates
                if c.clip_mode == "approx")
-    carries = {c.gather.carry_offload for c in plan.candidates}
-    assert carries == {"none", "host"}
     # pairing each bucketed candidate with its approx twin: pipelining
     # AdamW under hop-2 can only shrink the exposed time, and does shrink
     # it somewhere in the grid
@@ -297,17 +294,16 @@ def test_clip_and_carry_axes_ranked():
                for v in paired)
     # approx changes numerics: ranked, but chosen only under the opt-in
     assert plan.chosen.clip_mode == "exact"
-    assert not plan.chosen.gather.carry_offload == "host"
-    # both axes are visible columns in the ranked table
+    # the axis is a visible column in the ranked table
     txt = plan.table(top=None)
     head = txt.splitlines()[1]
-    assert "clip" in head and "carry" in head
-    assert "approx" in txt and "host" in txt and "remat" in txt
+    assert "clip" in head
+    assert "approx" in txt
 
 
 def test_resolve_roundtrips_clip_and_offload():
     """clip_mode='approx' on an auto config is the approximation opt-in;
-    the resolved config carries the chosen clip/carry/offload fields and
+    the resolved config carries the chosen clip and boundary fields and
     revalidates (approx only rides the bucket pipeline)."""
     prof = custom_profile("rt-axes", intra_bw=100e9, inter_bw=1e9,
                           node_size=8)
@@ -318,7 +314,6 @@ def test_resolve_roundtrips_clip_and_offload():
                                     topo_single(p=8, repl=2))
     assert resolved.policy == "manual"
     assert resolved.clip_mode == plan.chosen.clip_mode
-    assert resolved.carry_offload == plan.chosen.gather.carry_offload
     assert resolved.boundary_schedule == plan.chosen.boundary
     if resolved.clip_mode == "approx":
         assert resolved.boundary_schedule == "bucketed"

@@ -1,16 +1,17 @@
 """Which schedule each layer pool takes (``models.lm.pool_route``).
 
 Training re-gathers in the backward (the remat carry): its step keeps no
-stacked ``[stack, flat_len]`` gathered buffer, and it trains bitwise as
-the stored carry, the host-offloaded carry and the serial schedule do, on
-one device and on a repl=2 x shard=2 mesh (tests/carry_harness.py, in a
-subprocess).  Serving, enc-dec decoder pools, host offload and one-layer
-pools keep their own routes.
+stacked ``[stack, flat_len]`` gathered buffer, calls no host callback, and
+trains bitwise as the stored carry and the serial schedule do, on one
+device and on a repl=2 x shard=2 mesh (tests/carry_harness.py, in a
+subprocess).  Serving, enc-dec decoder pools and one-layer pools keep
+their own routes.
 """
 
 import dataclasses
 import math
 import pathlib
+import re
 
 import jax.numpy as jnp
 import pytest
@@ -31,18 +32,19 @@ from repro.runtime.serving import build_serve_steps
 from tiny_bert import tiny_bert
 
 HARNESS = pathlib.Path(__file__).parent / "carry_harness.py"
-HOST = GatherPolicy(carry_offload="host")
+NO_PREFETCH = GatherPolicy(prefetch=False)
 
 # case -> (policy, stack, what the pool observes, route)
 ROUTES = {
     "train": (GatherPolicy(), 4, {}, "remat"),
     "serve": (GatherPolicy(), 4, {"serving": True}, "stored"),
     "encdec_decoder": (GatherPolicy(), 4, {"enc_out": True}, "stored"),
-    "host_offload": (HOST, 4, {}, "host"),
-    "host_offload_encdec_decoder": (HOST, 4, {"enc_out": True}, "stored"),
-    "host_offload_serve": (HOST, 4, {"serving": True}, "stored"),
     "one_layer": (GatherPolicy(), 1, {}, "serial"),
-    "no_prefetch": (GatherPolicy(prefetch=False), 4, {}, "serial"),
+    "one_layer_serve": (GatherPolicy(), 1, {"serving": True}, "serial"),
+    "no_prefetch": (NO_PREFETCH, 4, {}, "serial"),
+    "no_prefetch_serve": (NO_PREFETCH, 4, {"serving": True}, "serial"),
+    "no_prefetch_encdec_decoder": (NO_PREFETCH, 4, {"enc_out": True},
+                                   "serial"),
 }
 
 
@@ -75,7 +77,9 @@ def test_build_training_prints_routes(capsys, arch, layers, route):
                    checkpoint_dir="unused", checkpoint_every=0)
     line = [ln for ln in capsys.readouterr().out.splitlines()
             if ln.startswith("memplan:")]
-    assert len(line) == 1 and line[0].endswith(f"routes: {route}"), line
+    assert len(line) == 1 and re.fullmatch(
+        r"memplan: \d+\.\d{3} GiB predicted per device routes: "
+        + re.escape(route), line[0]), line
 
 
 @pytest.fixture(scope="module")
@@ -159,6 +163,15 @@ def test_carries_train_alike(trained, mesh):
     runs = trained[mesh]
     assert all(math.isfinite(v) for row in runs["default"]["steps"]
                for v in row), runs["default"]
-    for carry in ("serial", "stored", "host"):
+    for carry in ("serial", "stored"):
         assert runs[carry] == runs["default"], (carry, runs)
+
+
+@pytest.mark.parametrize("case", [
+    "tiny_bert_one_device", "tiny_bert_repl2_shard2",
+    "dsv2lite_smoke_one_device"])
+def test_train_step_has_no_host_callback(trained, case):
+    """The compiled default train step calls nothing on the host: a host
+    callback would stall the device every step."""
+    assert trained["host_callbacks"][case] == [], trained["host_callbacks"]
 

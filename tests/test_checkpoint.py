@@ -1,14 +1,13 @@
 """Checkpoint roundtrip, crash-safe atomicity (incl. the injected
 crash-mid-save writer kill), fault-tolerant train loop with injected
 failures, data-pipeline determinism/seekability and exact restart-boundary
-continuity, and the offload_opt cross-topology host-stash reset contract.
-The multi-device save->restore->save reshard roundtrip (p=2 -> p=4 -> p=2)
-is pinned from the elastic harness run (tests/elastic_harness.py)."""
+continuity.  The multi-device save->restore->save reshard roundtrip
+(p=2 -> p=4 -> p=2) is pinned from the elastic harness run
+(tests/elastic_harness.py)."""
 
 import dataclasses
 import hashlib
 import json
-import logging
 
 import jax
 import numpy as np
@@ -18,7 +17,6 @@ import repro.runtime.train_loop as TL
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_config, smoke_variant
 from repro.core.faults import CrashDuringSaveError, FaultPlan
-from repro.core.hostoffload import CKPT_NAMESPACE, export_stash, stash_clear
 from repro.core.mics import MiCSConfig, init_state
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models.build import build_model
@@ -116,56 +114,6 @@ def test_async_save_failure_surfaces_at_wait(tmp_path, topo1):
     with pytest.raises(CrashDuringSaveError):
         ck.wait()                                        # ...surfaced here
     ck.wait()   # the failure is re-raised once, not forever
-
-
-def test_offload_opt_cross_topology_restore_resets_stash_explicitly(
-        tmp_path, topo1, caplog):
-    cfg = smoke_variant(get_config("llama3.2-1b"))
-    model = build_model(cfg, tp=1)
-    state = init_state(model, topo1, seed=4, offload_opt=True)
-    stash_clear()
-    ck = Checkpointer(tmp_path)
-    # fabricate one offloaded-moment shard (tag=TAG_M, slot 0, device 0)
-    ck.save(state, step=5, topo=topo1, data_cursor=5,
-            host_stash={(0, 1, 0, 0): np.arange(4.0)})
-
-    # same topology: the stash comes back under the checkpoint namespace
-    restored, meta = ck.restore(model, topo1, offload_opt=True)
-    assert meta["host_stash"] == {
-        "present": True, "restored": True, "reset": None}
-    stash = export_stash(CKPT_NAMESPACE)
-    assert list(stash) == [(CKPT_NAMESPACE, 1, 0, 0)]
-
-    # tamper the manifest into a different source topology: the restore
-    # must WARN, surface the reset in meta, and purge the stale entries
-    mpath = tmp_path / "step_00000005" / "manifest.json"
-    m = json.loads(mpath.read_text())
-    m["mesh_axes"]["shard"] = 2
-    mpath.write_text(json.dumps(m))
-    with caplog.at_level(logging.WARNING, logger="repro.checkpoint"):
-        restored, meta = ck.restore(model, topo1, offload_opt=True)
-    assert meta["host_stash"] == {
-        "present": True, "restored": False, "reset": "cross-topology"}
-    assert any("do not reshard" in r.message for r in caplog.records)
-    assert export_stash(CKPT_NAMESPACE) == {}   # stale entries purged
-    # params/step still restore exactly either way
-    assert meta["step"] == 5
-    jax.tree.map(
-        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
-        state, restored)
-
-
-def test_offload_opt_restore_without_stash_blob_warns(tmp_path, topo1, caplog):
-    cfg = smoke_variant(get_config("llama3.2-1b"))
-    model = build_model(cfg, tp=1)
-    state = init_state(model, topo1, offload_opt=True)
-    ck = Checkpointer(tmp_path)
-    ck.save(state, step=1, topo=topo1)   # no host_stash passed
-    with caplog.at_level(logging.WARNING, logger="repro.checkpoint"):
-        _, meta = ck.restore(model, topo1, offload_opt=True)
-    assert meta["host_stash"] == {
-        "present": False, "restored": False, "reset": "missing"}
-    assert any("no host stash" in r.message for r in caplog.records)
 
 
 def test_train_loop_recovers_from_injected_fault(tmp_path, topo1):

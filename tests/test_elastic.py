@@ -118,7 +118,7 @@ ELASTIC_CHECKS = [
     "straggler_flagged",
     "crash_mid_save",
     "reshard_roundtrip",
-    "offload_cross_topology",
+    "reshard_moments",
 ]
 
 

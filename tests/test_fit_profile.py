@@ -124,9 +124,8 @@ def test_snippet_fallback_for_unconstrained_tier():
 
 
 def test_fit_recovers_host_tier():
-    """A ledger whose policies exercise carry_offload='host' stages (the
-    ``tier='host'`` fit rows benchmarks/comm_bench.py emits) constrains the
-    device<->host (α, β) alongside the network tiers."""
+    """A ledger with ``tier='host'`` stages constrains the device<->host
+    (α, β) alongside the network tiers."""
     fit = fit_tiers(_synthetic(10, tiers=("intra", "inter", "host")))
     tf = fit.tiers["host"]
     assert tf.constrained and not tf.clamped

@@ -223,9 +223,9 @@ def test_min_partition_size_picks_minimal():
         SyncPolicy()).total_bytes for p in (1, 2, 4, 8, 16)}
     budget_gb = (need[4] + 1) / GIB
     assert need[2] > need[4] + 1  # the budget really excludes p=2
-    p, carry, plan = min_partition_size(
+    p, plan = min_partition_size(
         model, data_extent=16, hbm_budget_gb=budget_gb)
-    assert p == 4 and carry == "remat"
+    assert p == 4
     assert plan.total_bytes <= budget_gb * GIB
 
 
@@ -240,14 +240,14 @@ def test_min_partition_size_remat_rescues_smaller_group():
     with stored_carry():
         stored4 = predict_footprint(
             model, DeviceGrid(4, 4), gp, sp).total_bytes
-        p_stored_only, _, _ = min_partition_size(
+        p_stored_only, _ = min_partition_size(
             model, data_extent=16, hbm_budget_gb=(stored4 - 1) / GIB)
     remat4 = predict_footprint(model, DeviceGrid(4, 4), gp, sp).total_bytes
     assert remat4 < stored4
     budget_gb = (remat4 + stored4) / 2 / GIB
-    p, carry, _plan = min_partition_size(
+    p, _plan = min_partition_size(
         model, data_extent=16, hbm_budget_gb=budget_gb)
-    assert (p, carry) == (4, "remat")
+    assert p == 4
     assert p_stored_only > p
 
 
@@ -270,8 +270,6 @@ def test_rank_policies_prices_memory():
     assert all(c.mem_bytes > 0 for c in plan.candidates)
     assert "mem_GB" in plan.table()
     assert plan.hbm_budget_gb is None
-    # without a budget every row re-gathers (no host-offloaded rows)
-    assert {c.gather.carry_offload for c in plan.candidates} == {"none"}
 
 
 def test_rank_policies_budget_filters_and_falls_back_to_remat():
@@ -282,13 +280,11 @@ def test_rank_policies_budget_filters_and_falls_back_to_remat():
     # slower (one extra gather per layer) but fits
     remat_plan = rank_policies(model, topo, "v5e", micro_steps=2,
                                hbm_budget_gb=1e6)  # effectively unlimited
-    remat_rows = [c for c in remat_plan.candidates
-                  if c.gather.carry_offload == "none"]
+    remat_rows = list(remat_plan.candidates)
     assert remat_rows, "budgeted ranking must include the remat rows"
     budget_gb = (min(c.mem_bytes for c in remat_rows) + 1) / GIB
     gated = rank_policies(model, topo, "v5e", micro_steps=2,
                           hbm_budget_gb=budget_gb)
-    assert gated.chosen.gather.carry_offload == "none"
     assert gated.chosen.mem_bytes <= budget_gb * GIB
     assert stored_best.mem_bytes > budget_gb * GIB
     assert gated.chosen.t_comm_s >= stored_best.t_comm_s
@@ -304,19 +300,17 @@ def test_resolve_config_applies_budget(topo1):
     model, topo = StubModel(), topo_single(p=4, repl=2)
     remat_plan = rank_policies(model, topo, "v5e", micro_steps=2,
                                hbm_budget_gb=1e6)
-    remat_rows = [c for c in remat_plan.candidates
-                  if c.gather.carry_offload == "none"]
-    budget_gb = (min(c.mem_bytes for c in remat_rows) + 1) / GIB
+    budget_gb = (min(c.mem_bytes for c in remat_plan.candidates) + 1) / GIB
     mcfg = MiCSConfig(micro_steps=2, policy="auto", link_profile="v5e",
                       hbm_budget_gb=budget_gb)
     resolved, plan = resolve_config(mcfg, model, topo)
     assert plan.hbm_budget_gb == budget_gb
-    assert resolved.carry_offload == "none"
+    assert plan.chosen.mem_bytes <= budget_gb * GIB
     # and the resolved config reconstructs the chosen policy end to end
     from repro.core.comm import CommEngine
 
     eng = CommEngine.from_config(topo1, resolved)
-    assert eng.gather_policy.carry_offload == "none"
+    assert eng.gather_policy == plan.chosen.gather
 
 
 def test_resolve_scale_minimal_group():
@@ -325,8 +319,8 @@ def test_resolve_scale_minimal_group():
         model, DeviceGrid(4, 4), GatherPolicy(prefetch=True),
         SyncPolicy()).total_bytes
     mcfg = MiCSConfig(micro_steps=1, hbm_budget_gb=(need4 + 1) / GIB)
-    p, carry, plan = resolve_scale(model, mcfg, data_extent=16)
-    assert p == 4 and carry == "remat"
+    p, plan = resolve_scale(model, mcfg, data_extent=16)
+    assert p == 4
     with pytest.raises(ValueError):
         resolve_scale(model, MiCSConfig(), data_extent=16)
     with pytest.raises(MemoryBudgetError):
@@ -336,11 +330,7 @@ def test_resolve_scale_minimal_group():
 
 def test_config_validation():
     with pytest.raises(ValueError):
-        MiCSConfig(carry_offload="offload")
-    with pytest.raises(ValueError):
         MiCSConfig(hbm_budget_gb=0.0)
-    with pytest.raises(ValueError):
-        GatherPolicy(carry_offload="nvme")
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +344,7 @@ def harness_results():
 
 CHECKS = [
     "footprint_match", "footprint_degenerate", "remat_lowers_peak",
-    "census_match_remat", "carried_buffer_census", "offload_lowers_peak",
+    "census_match_remat", "carried_buffer_census",
 ]
 
 
@@ -385,20 +375,6 @@ def test_remat_saving_is_the_carry(harness_results):
                   - det["inner_first/remat"]["components"]["prefetch_carry"])
     assert saving > 0
     assert abs(pred_delta - saving) <= 0.5 * saving
-
-
-def test_offload_peak_accounting(harness_results):
-    """carry_offload='host' + offload_opt shrink the compiled peak the way
-    the planner predicts: temps lose the carry residual, args lose the
-    fp32 m/v shards (2/3 of the 3x-fp32 state), args stay exact."""
-    det = harness_results["offload_lowers_peak_detail"]
-    s, hc, ho = det["stored"], det["host_carry"], det["host_carry_opt"]
-    for row in (s, hc, ho):
-        assert row["predicted_args_bytes"] == row["measured_args_bytes"]
-    assert hc["measured_temp_bytes"] < s["measured_temp_bytes"]
-    # m/v leave the donated args: the drop is ~2/3 of the state bytes
-    drop = s["measured_args_bytes"] - ho["measured_args_bytes"]
-    assert drop > 0.5 * s["measured_args_bytes"], det
 
 
 def test_expert_model_pools_are_priced_and_routed():

@@ -6,7 +6,7 @@ the HLO-census evidence that hop-2 runs at bucket granularity interleaved
 with boundary compute, the approximate-clip pipeline's degenerate/bounded
 -divergence guarantees (clip-inactive equivalence, zero-grad, int8 hop-2
 composition, convergence within APPROX_CLIP_LOSS_RTOL, AdamW census
-interleave), and the host-offload cells' bitwise equivalence."""
+interleave)."""
 
 import pathlib
 
@@ -48,11 +48,6 @@ def test_clip_offload_config_validated():
     with pytest.raises(ValueError):   # approx needs the bucket pipeline
         MiCSConfig(clip_mode="approx", boundary_schedule="serial")
     MiCSConfig(clip_mode="approx", boundary_schedule="bucketed")
-    with pytest.raises(ValueError):
-        MiCSConfig(carry_offload="nvme")
-    with pytest.raises(ValueError):   # host carry offloads the stored carry
-        MiCSConfig(carry_offload="host", prefetch=False)
-    MiCSConfig(carry_offload="host", prefetch=True)
     with pytest.raises(ValueError):
         BoundaryPlan(mode="bucketed", bucket_mb=1.0, shard_elems={},
                      buckets=(), clip_mode="stale")
@@ -127,7 +122,7 @@ CHECKS = [
     "bitwise_compress", "census_interleave",
     "approx_clip_inactive", "approx_zero_grad",
     "approx_clip_active_bounded", "approx_int8_hop2",
-    "approx_census_interleave", "offload_host_bitwise",
+    "approx_census_interleave",
 ]
 
 
@@ -166,8 +161,3 @@ def test_approx_convergence_bound(harness_results):
     assert detail["final_approx"] < 6.0  # it actually learned
 
 
-def test_offload_stash_accounting(harness_results):
-    detail = harness_results.get("offload_detail")
-    assert detail is not None
-    assert detail["stash_entries"] > 0
-    assert detail["stash_entries"] % 2 == 0  # an m and a v per slot

@@ -16,12 +16,11 @@ that system per tier and emits a ready-to-paste
 autotuner can rank policies over.
 
 The ``host`` tier (device<->host DMA, core/linkmodel.py) joins the same
-solve: the bench's offload cells ledger their d2h/h2d stream as
-``tier='host'`` stages (one α-event per transfer, point-to-point bytes),
-so a ledger that exercises ``carry_offload='host'`` or ``offload_opt``
-constrains the host (α, β) alongside the network tiers.  Ledgers without
-offload cells leave it unconstrained — the snippet then omits
-``host_bw`` and the profile falls back to ``DEFAULT_HOST_LINK``.
+solve: a ledger whose stages carry ``tier='host'`` (one α-event per
+transfer, point-to-point bytes) constrains the host (α, β) alongside the
+network tiers.  Ledgers without such stages leave it unconstrained — the
+snippet then omits ``host_bw`` and the profile falls back to
+``DEFAULT_HOST_LINK``.
 
 Usage:
   PYTHONPATH=src python tools/fit_profile.py artifacts/benchmarks/BENCH_comm.json \
